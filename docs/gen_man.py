@@ -98,7 +98,7 @@ def render_page(name: str, section: int, desc: str, help_text: str) -> str:
 def main(argv=None) -> int:
     outdir = (argv or sys.argv[1:] or ["docs/man"])[0]
     os.makedirs(outdir, exist_ok=True)
-    env = dict(os.environ, JUBATUS_TPU_PLATFORM="cpu", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     for module, name, section, desc in PAGES:
         r = subprocess.run(
             [sys.executable, "-m", module, "--help"],

@@ -17,7 +17,7 @@ import json
 import sys
 from typing import List, Optional
 
-from jubatus_tpu.cmd import apply_platform_override, resolve_coordinator
+from jubatus_tpu.cmd import compute_on_cpu, resolve_coordinator
 from jubatus_tpu.coord import create_coordinator, membership
 from jubatus_tpu.framework.idl import ENGINES
 
@@ -65,10 +65,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                     return 1
                 # full semantic validation: dry-construct the driver, like
                 # the servers' --config-test (the reference validates via
-                # jsonconfig before writing, jubaconfig.cpp validate_config).
-                # Override BEFORE the factory import touches jax; env/import
-                # failures must not masquerade as config rejection.
-                apply_platform_override()
+                # jsonconfig before writing, jubaconfig.cpp validate_config)
+                # — on the CPU, set before the factory import touches jax:
+                # the tables are built whole, and the chip is the server's.
+                # Env/import failures must not masquerade as config
+                # rejection.
+                compute_on_cpu()
                 from jubatus_tpu.server.factory import create_driver
 
                 try:

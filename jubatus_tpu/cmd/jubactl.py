@@ -210,6 +210,11 @@ def _parser() -> argparse.ArgumentParser:
                    type=int, default=10)
     p.add_argument("-R", "--interconnect-timeout", dest="interconnect_timeout",
                    type=int, default=10)
+    p.add_argument("--jax-coordinator", dest="jax_coordinator", default="",
+                   help="[start] host:port rank 0 of the new servers listens "
+                        "on: they join ONE jax world of -N processes (what "
+                        "-X collective_mixer mixes over), ranked visor by "
+                        "visor")
     return p
 
 
@@ -233,10 +238,15 @@ def send2supervisor(coord: Coordinator, cmd: str, engine: str, name: str,
     total = num if num > 0 else len(visors)
     per, extra = divmod(total, len(visors))
     rc = 0
+    rank = 0
     for i, visor in enumerate(visors):
         n = per + (1 if i < extra else 0)
         if n == 0 and cmd == "start":
             continue
+        if argv.get("jax_coordinator"):
+            # one jax world over every visor's children
+            argv = dict(argv, jax_processes=total, jax_process_id=rank)
+            rank += n
         print(f"sending {cmd} / {name} to {visor.name}...", end="", flush=True)
         with RpcClient(visor.host, visor.port, timeout=10.0) as c:
             if cmd == "start":
@@ -1869,6 +1879,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 "interval_count": ns.interval_count,
                 "zookeeper_timeout": ns.zookeeper_timeout,
                 "interconnect_timeout": ns.interconnect_timeout,
+                "jax_coordinator": ns.jax_coordinator,
             } if ns.cmd == "start" else {}
             return send2supervisor(coord, ns.cmd, ns.type, name, ns.num,
                                    server_argv)

@@ -78,12 +78,11 @@ def dump_file(path: str, *, summary: bool = False,
 
     if os.path.isdir(path):
         # offline metadata inspection needs no accelerator, but orbax
-        # queries jax's default backend — pin CPU so the dump works on
-        # hosts without the TPU plugin on PYTHONPATH
-        from jubatus_tpu.cmd import apply_platform_override
+        # queries jax's default backend — and on a serving host that
+        # would open the chip the server holds
+        from jubatus_tpu.cmd import compute_on_cpu
 
-        os.environ.setdefault("JUBATUS_TPU_PLATFORM", "cpu")
-        apply_platform_override()
+        compute_on_cpu()
         from jubatus_tpu.framework.sharded_checkpoint import (
             checkpoint_metadata,
         )

@@ -12,7 +12,12 @@ RPC surface (jubavisor.hpp:36-86, wire names identical):
   (the reference ignores N and stops all, jubavisor.hpp:47-49).
 
 Children are ``python -m jubatus_tpu.server <engine> ...`` subprocesses
-given ports from a pool [port+1, port+max] (jubavisor.cpp port_pool_); a
+given ports from a pool [port+1, port+max] (jubavisor.cpp port_pool_).
+One ``start`` of N > 1 children binds child k to chip k of this host
+(``cmd.tpu_process_env``), so the first child does not open every chip;
+with ``jax_processes`` / ``jax_coordinator`` in the argv map the children
+also join one jax world (``--mixer collective_mixer``), ranked from
+``jax_process_id`` upward. A
 reaper thread collects exits and recycles ports (≙ SIGCHLD handler);
 ``stop_all`` runs at exit (atexit_ kill-all). Registers ephemerally under
 /jubatus/supervisors so jubactl can find it (membership.cpp).
@@ -30,7 +35,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from jubatus_tpu.cmd import resolve_coordinator
+from jubatus_tpu.cmd import resolve_coordinator, tpu_process_env
 from jubatus_tpu.coord import create_coordinator, membership
 from jubatus_tpu.framework.idl import ENGINES
 from jubatus_tpu.rpc.server import RpcServer
@@ -49,6 +54,8 @@ _FLAG_MAP = {
     "interval_count": "--interval-count",
     "zookeeper_timeout": "--coordinator-timeout",
     "interconnect_timeout": "--interconnect-timeout",
+    "jax_processes": "--jax-processes",
+    "jax_coordinator": "--jax-coordinator",
 }
 
 
@@ -98,24 +105,38 @@ class Jubavisor:
             return -1
         cluster = name.split("/", 1)[1] if "/" in name else name
         argv = argv or {}
+        n = int(n)
         with self._mu:
-            for _ in range(int(n)):
-                if not self._pool:
-                    log.error("port pool exhausted (max children reached)")
-                    return -1
-                port = self._pool.pop(0)
+            if len(self._pool) < n:
+                log.error("port pool exhausted (max children reached)")
+                return -1
+            ports = [self._pool.pop(0) for _ in range(n)]
+            for k, port in enumerate(ports):
                 cmd = [sys.executable, "-m", "jubatus_tpu.server", engine,
                        "-z", self.coordinator, "-n", cluster, "-p", str(port)]
                 for key, flag in _FLAG_MAP.items():
                     if key in argv and argv[key] not in ("", None):
                         cmd += [flag, str(argv[key])]
+                if argv.get("jax_processes"):
+                    cmd += ["--jax-process-id",
+                            str(int(argv.get("jax_process_id") or 0) + k)]
+                env = None
+                if n > 1:
+                    # a chip belongs to one process: child k gets chip k.
+                    # The TPU runtime's mesh ports sit above the rpc pool.
+                    try:
+                        env = dict(os.environ, **tpu_process_env(
+                            k, [p + self.max_children for p in ports]))
+                    except ValueError as e:
+                        log.warning("%s: children are not bound to chips", e)
                 out = (open(self.logfile, "ab") if self.logfile
                        else subprocess.DEVNULL)
                 try:
-                    proc = subprocess.Popen(cmd, stdout=out, stderr=out)
+                    proc = subprocess.Popen(cmd, stdout=out, stderr=out,
+                                            env=env)
                 except OSError as e:
                     log.error("spawn failed: %s", e)
-                    self._pool.insert(0, port)
+                    self._pool[:0] = ports[k:]
                     return -1
                 finally:
                     if out is not subprocess.DEVNULL:
@@ -129,8 +150,7 @@ class Jubavisor:
     def stop_procs(self, name: str, _n: int = 0) -> int:
         with self._mu:
             children = self._children.pop(name, [])
-        for c in children:
-            self._kill(c)
+        self._kill(children)
         log.info("stopped %d process(es) of %s", len(children), name)
         return 0
 
@@ -138,21 +158,27 @@ class Jubavisor:
         with self._mu:
             everything = [c for lst in self._children.values() for c in lst]
             self._children.clear()
-        for c in everything:
-            self._kill(c)
+        self._kill(everything)
 
-    def _kill(self, child: _Child) -> None:
-        try:
-            child.proc.terminate()
+    def _kill(self, children: List[_Child]) -> None:
+        # members of one jax world leave together (the runtime's shutdown
+        # is a barrier): signal all of them before waiting for any
+        for child in children:
             try:
-                child.proc.wait(timeout=5.0)
-            except subprocess.TimeoutExpired:
-                child.proc.kill()
-                child.proc.wait(timeout=5.0)
-        except OSError:
-            pass
-        with self._mu:
-            self._pool.append(child.port)
+                child.proc.terminate()
+            except OSError:
+                pass
+        for child in children:
+            try:
+                try:
+                    child.proc.wait(timeout=5.0)
+                except subprocess.TimeoutExpired:
+                    child.proc.kill()
+                    child.proc.wait(timeout=5.0)
+            except OSError:
+                pass
+            with self._mu:
+                self._pool.append(child.port)
 
     def _reap_loop(self) -> None:
         """Collect dead children, recycle their ports (≙ SIGCHLD reaping)."""

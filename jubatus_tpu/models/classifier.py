@@ -466,14 +466,18 @@ class ClassifierDriver(DriverBase):
     def shard_stats(self) -> Dict[str, Any]:
         """Feature-shard layout gauges (shard.* catalog rows,
         OBSERVABILITY.md §7): shard count + per-device weight-state
-        bytes. Empty when unsharded."""
+        bytes, and where each addressable shard of the weight table
+        lives. Empty when unsharded."""
         if self._mesh is None:
             return {}
         n = self._mesh.shape[self._mesh_axis]
         total = sum(int(a.nbytes) for a in self.state)
+        shards = self.state.w.addressable_shards
         return {"count": n, "rows": self.capacity,
                 "bytes_in_use": total,
-                "bytes_per_shard": total // n}
+                "bytes_per_shard": total // n,
+                "devices": [str(s.device) for s in shards],
+                "shard_shape": list(shards[0].data.shape)}
 
     @locked
     def clear(self) -> None:

@@ -20,6 +20,8 @@ Surface:
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -27,42 +29,55 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+log = logging.getLogger(__name__)
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
 BUILD_DIR = os.path.join(NATIVE_DIR, "build")
-LIB_PATH = os.path.join(BUILD_DIR, "libjt_native.so")
+_CXX = ("g++", "-O3", "-Wall", "-fPIC", "-std=c++17", "-pthread", "-shared")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _compile(src: str, out: str) -> bool:
+def build(stem: str) -> Optional[str]:
+    """``native/<stem>.cpp`` compiled to ``native/build/lib<stem>-<key>.so``
+    and the path returned; None when the source or toolchain is missing
+    or the compile fails (logged). ``key`` hashes the source bytes and the
+    compiler command, so a library is reused exactly when it was built
+    from this source with these flags — file times say nothing after a
+    checkout or a copy to another machine."""
+    src = os.path.join(NATIVE_DIR, stem + ".cpp")
     try:
-        os.makedirs(os.path.dirname(out), exist_ok=True)
-        res = subprocess.run(
-            ["g++", "-O3", "-Wall", "-fPIC", "-std=c++17", "-pthread",
-             "-shared", "-o", out, src],
-            capture_output=True, timeout=120,
-        )
-        return res.returncode == 0
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-
-
-def _stale(src: str, out: str) -> bool:
-    return (not os.path.exists(out)
-            or os.path.getmtime(out) < os.path.getmtime(src))
-
-
-def ensure_built() -> Optional[str]:
-    """Compile-on-demand; None when the toolchain/source is unavailable."""
-    src = os.path.join(NATIVE_DIR, "jt_native.cpp")
-    if not os.path.exists(src):
+        with open(src, "rb") as f:
+            body = f.read()
+    except OSError:
         return None
-    if _stale(src, LIB_PATH) and not _compile(src, LIB_PATH):
+    key = hashlib.sha256(" ".join(_CXX).encode() + b"\0" + body).hexdigest()[:16]
+    out = os.path.join(BUILD_DIR, f"lib{stem}-{key}.so")
+    if os.path.exists(out):
+        return out
+    # compile beside the target and rename: replicas booting together
+    # each build their own copy and the last rename wins, whole
+    tmp = f"{out}.{os.getpid()}.tmp"
+    try:
+        os.makedirs(BUILD_DIR, exist_ok=True)
+        res = subprocess.run([*_CXX, "-o", tmp, src],
+                             capture_output=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        log.warning("native build of %s did not run: %s", stem, e)
         return None
-    return LIB_PATH
+    if res.returncode != 0:
+        log.warning("native build of %s failed:\n%s", stem,
+                    res.stderr.decode("utf-8", "replace")[-2000:])
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
+        return None
+    os.replace(tmp, out)
+    return out
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -71,7 +86,7 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        path = os.environ.get("JUBATUS_TPU_NATIVE_LIB") or ensure_built()
+        path = os.environ.get("JUBATUS_TPU_NATIVE_LIB") or build("jt_native")
         if not path or not os.path.exists(path):
             return None
         try:

@@ -24,9 +24,6 @@ _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
-_SRC = os.path.join(nb.NATIVE_DIR, "fast_ingest.cpp")
-_OUT = os.path.join(nb.BUILD_DIR, "libfast_ingest.so")
-
 
 class _Out(ctypes.Structure):
     _fields_ = [
@@ -49,12 +46,11 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SRC):
-            return None
-        if nb._stale(_SRC, _OUT) and not nb._compile(_SRC, _OUT):
+        out = nb.build("fast_ingest")
+        if out is None:
             return None
         try:
-            lib = ctypes.CDLL(_OUT)
+            lib = ctypes.CDLL(out)
         except OSError:
             return None
         lib.jt_ingest_create.restype = ctypes.c_void_p

@@ -86,8 +86,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jubatus_tpu.parallel._compat import shard_map
 from jubatus_tpu.parallel.mesh import HostTopology, host_mesh, host_topology
 from jubatus_tpu.utils import faults
 
@@ -479,7 +479,7 @@ def _quant_reduce_fn(mesh: Mesh, celems: int, block: int):
       all-gather around the ring; EVERY replica — owner included —
       dequantizes the same int8+scale representation on readback, so
       the output is bit-identical everywhere (shard_map cannot prove
-      that: check_rep=False).
+      that: check_vma=False).
 
     World of 1 degenerates to the pure quantize round trip (ship quant
     → dequant → total requant) with both residual chains active — the
@@ -496,7 +496,7 @@ def _quant_reduce_fn(mesh: Mesh, celems: int, block: int):
         shard_map(body, mesh=mesh,
                   in_specs=(P("replica"), P("replica"), P("replica")),
                   out_specs=(P(), P("replica")),
-                  check_rep=False),
+                  check_vma=False),
         out_shardings=(NamedSharding(mesh, P()),
                        NamedSharding(mesh, P("replica"))),
         # only the quantized buffer is donated: the residual input must
@@ -549,11 +549,11 @@ def _hier_fns(mesh: Mesh, celems: int, dtype_str: str, mode: str):
     # shared _dev_zeros cache and must survive the call
     intra_j = jax.jit(
         shard_map(intra, mesh=mesh, in_specs=_SPEC2, out_specs=_SPEC2,
-                  check_rep=False),
+                  check_vma=False),
         out_shardings=NamedSharding(mesh, _SPEC2))
     inter_j = jax.jit(
         shard_map(inter, mesh=mesh, in_specs=_SPEC2, out_specs=P(),
-                  check_rep=False),
+                  check_vma=False),
         out_shardings=NamedSharding(mesh, P()),
         donate_argnums=_donate())
     return intra_j, inter_j
@@ -591,11 +591,11 @@ def _hier_quant_fns(mesh: Mesh, celems: int, block: int):
     # the residual input must survive a failed round
     intra_j = jax.jit(
         shard_map(intra, mesh=mesh, in_specs=(_SPEC2, _SPEC2),
-                  out_specs=(_SPEC2, _SPEC2, _SPEC2), check_rep=False),
+                  out_specs=(_SPEC2, _SPEC2, _SPEC2), check_vma=False),
         out_shardings=(NamedSharding(mesh, _SPEC2),) * 3)
     inter_j = jax.jit(
         shard_map(inter, mesh=mesh, in_specs=(_SPEC2, _SPEC2, _SPEC2),
-                  out_specs=(P(), _SPEC2), check_rep=False),
+                  out_specs=(P(), _SPEC2), check_vma=False),
         out_shardings=(NamedSharding(mesh, P()),
                        NamedSharding(mesh, _SPEC2)),
         donate_argnums=_donate())

@@ -31,8 +31,8 @@ from typing import Any, Dict, List, Optional, Protocol, Sequence, runtime_checka
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jubatus_tpu.parallel._compat import shard_map
 
 
 @runtime_checkable

@@ -24,33 +24,26 @@ from typing import Optional
 import jax
 
 from jubatus_tpu.coord.base import Coordinator
-from jubatus_tpu.parallel._compat import distributed_is_initialized
 
 log = logging.getLogger(__name__)
 
 JAX_COORD_PATH = "/jubatus/jax_coordinator"
 
 
-def enable_cpu_collectives() -> bool:
+def enable_cpu_collectives() -> None:
     """Select the gloo cross-process collectives backend for CPU worlds.
 
-    On jax builds of this era the CPU backend refuses multiprocess
-    computations outright ("Multiprocess computations aren't implemented
-    on the CPU backend") unless ``jax_cpu_collectives_implementation``
-    is switched to gloo BEFORE the backend initializes — without it,
-    every CPU-world psum raises, members ack failure, and the collective
-    mix silently degrades to broken rounds. gloo also carries the
-    collective_permute the int8 quantized transport's scatter/gather
-    ring rides (parallel/collective._quant_chunk_fn), so one switch
-    covers every wire mode. Must be called before anything touches the
-    XLA backend; returns True if the option was set. No-op (False) on
-    jax versions without the option (their CPU collectives work out of
-    the box)."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        return True
-    except Exception:  # noqa: BLE001 — option renamed/removed upstream
-        return False
+    The CPU backend refuses multiprocess computations outright
+    ("Multiprocess computations aren't implemented on the CPU backend")
+    unless ``jax_cpu_collectives_implementation`` is switched to gloo
+    BEFORE the backend initializes — without it, every CPU-world psum
+    raises, members ack failure, and the collective mix silently degrades
+    to broken rounds. gloo also carries the collective_permute the int8
+    quantized transport's scatter/gather ring rides
+    (parallel/collective._quant_chunk_fn), so one switch covers every
+    wire mode. Only the CPU backend reads the option: a TPU world's
+    collectives ride the interconnect whatever it says."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def collective_capabilities() -> dict:
@@ -68,7 +61,7 @@ def collective_capabilities() -> dict:
     collective_permute once the world is up — CPU via gloo, TPU
     natively — so this tracks ``distributed`` or a world of one).
     Surfaced in the collective mixer's get_status."""
-    init = distributed_is_initialized()
+    init = jax.distributed.is_initialized()
     world = jax.process_count() if init else 1
     local = len(jax.local_devices())
     backend = jax.default_backend()
@@ -76,14 +69,8 @@ def collective_capabilities() -> dict:
     if backend == "cpu" and world > 1:
         # a CPU world that skipped enable_cpu_collectives() has no
         # cross-process collectives AT ALL — psum and the int8 ring's
-        # collective_permute both raise at dispatch. config.read is the
-        # only access path this option supports on this jax (attribute
-        # access returns nothing for it).
-        try:
-            impl = jax.config.read("jax_cpu_collectives_implementation")
-        except Exception:  # noqa: BLE001 — option renamed/removed upstream
-            impl = None
-        quantized = impl == "gloo"
+        # collective_permute both raise at dispatch
+        quantized = jax.config.jax_cpu_collectives_implementation == "gloo"
     return {
         "backend": backend,
         "distributed": init,
@@ -112,7 +99,7 @@ def initialize(
     ``jax.process_count()``/``jax.devices()`` would do that, which is why
     the already-initialized check uses ``jax.distributed.is_initialized``.
     """
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return False
     if not num_processes or num_processes <= 1:
         return False  # single-host: never poll or raise

@@ -31,8 +31,8 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jubatus_tpu.parallel._compat import axis_size, shard_map
 
 from jubatus_tpu.parallel.sharded_knn import shard_table as shard_rows  # noqa: F401
 
@@ -47,7 +47,7 @@ def ring_scan(step_fn: Callable, carry, block, axis: str):
     (XLA schedules the collective-permute async on TPU), which is the
     whole point of the ring shape: the wire hides behind the scan.
     """
-    s = axis_size(axis)
+    s = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     perm = [(i, (i + 1) % s) for i in range(s)]
 

@@ -33,10 +33,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from jubatus_tpu.ops.ivf import candidate_sig_distances, pairwise_sq_dists
-from jubatus_tpu.parallel._compat import shard_map
 from jubatus_tpu.parallel.sharded_knn import merge_topk
 
 

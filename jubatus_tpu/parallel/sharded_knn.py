@@ -30,8 +30,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jubatus_tpu.parallel._compat import shard_map
 
 
 def shard_table(mesh: Mesh, table, axis: str = "shard"):

@@ -42,6 +42,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jubatus_tpu.ops.classifier import (
@@ -49,7 +50,6 @@ from jubatus_tpu.ops.classifier import (
     ClassifierState,
     decide_updates,
 )
-from jubatus_tpu.parallel._compat import shard_map
 
 DEFAULT_AXIS = "shard"
 

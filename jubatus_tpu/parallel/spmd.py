@@ -25,8 +25,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jubatus_tpu.parallel._compat import shard_map
 
 from jubatus_tpu.ops.classifier import (
     CONFIDENCE_METHODS,

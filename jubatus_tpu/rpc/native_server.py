@@ -60,11 +60,8 @@ def _load_lib() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None:
             return _lib
-        src = os.path.join(native_build.NATIVE_DIR, "rpc_frontend.cpp")
-        out = os.path.join(native_build.BUILD_DIR, "librpc_frontend.so")
-        if not os.path.exists(src):
-            return None
-        if native_build._stale(src, out) and not native_build._compile(src, out):
+        out = native_build.build("rpc_frontend")
+        if out is None:
             return None
         try:
             lib = ctypes.CDLL(out)
@@ -99,6 +96,8 @@ def available() -> bool:
 
 class NativeRpcServer:
     """RpcServer drop-in over the C++ transport."""
+
+    transport = "native"
 
     def __init__(self, timeout: float = 10.0,
                  trace: Optional[Registry] = None,

@@ -214,6 +214,9 @@ class RpcServer:
     rpc_server.hpp): ``serve_background()`` is listen+start, ``stop()`` is end.
     """
 
+    #: which transport this is, as get_status / get_proxy_status report it
+    transport = "python"
+
     def __init__(self, timeout: float = 10.0,
                  trace: Optional[Registry] = None,
                  legacy_wire: bool = False,

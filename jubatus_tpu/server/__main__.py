@@ -15,9 +15,9 @@ from jubatus_tpu.server.base import EngineServer
 
 
 def main(argv=None) -> int:
-    from jubatus_tpu.cmd import apply_platform_override
+    from jubatus_tpu.utils.compile_cache import configure as configure_cache
 
-    apply_platform_override()
+    configure_cache()
     args = parse_server_args(argv)
     from jubatus_tpu.utils.logger import install_sighup_reload, setup
 

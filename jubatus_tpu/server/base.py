@@ -1366,9 +1366,14 @@ class EngineServer:
         for nm, co in self.coalescers.items():
             st.update({f"microbatch.{nm}.{k}": v
                        for k, v in co.stats().items()})
+        # which transport and parser serve: both quietly downgrade on a
+        # host without a compiler, and nothing else tells them apart
+        st["rpc.transport"] = self.rpc.transport
+        ingest = getattr(self, "ingest_stats", None)
+        st["ingest.native"] = ingest is not None
         # dense-submatrix (uniform key schema) plan engagement counters
         # (service.py populates when the native fast path is registered)
-        for k, v in (getattr(self, "ingest_stats", None) or {}).items():
+        for k, v in (ingest or {}).items():
             st[f"ingest.{k}"] = v
         st.update({f"driver.{k}": v for k, v in self.driver.get_status().items()})
         if self.mixer is not None:

@@ -1262,6 +1262,7 @@ class Proxy:
         for k, v in self.retry_budget.status().items():
             st[f"retry_budget.{k}"] = v
         st.update(self.args.flags_status())
+        st["rpc.transport"] = self.rpc.transport
         # span histograms + counters (same registry /metrics exposes) —
         # the proxy hop's rpc.* quantiles and trace ids sit next to the
         # backends' in a merged get_status view

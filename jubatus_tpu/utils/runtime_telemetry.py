@@ -7,11 +7,14 @@ one daemon thread per process that periodically samples:
 
 - **process**: RSS/VIRT (``/proc/self/statm``), open FDs, thread count,
   GC generation counts + total collections;
-- **JAX/XLA signals**: cumulative jit compile count and wall-ms (via
-  ``jax.monitoring`` duration listeners — the runtime's own
-  instrumentation, zero polling cost), jit cache size (pjit C++ caches),
-  live ``jax.Array`` count, and live device memory when the backend
-  reports it (``Device.memory_stats`` — TPU/GPU; CPU returns nothing);
+- **JAX/XLA signals**: cumulative jit compile count and wall-ms and the
+  persistent compilation cache's hits and misses (via ``jax.monitoring``
+  listeners — the runtime's own instrumentation, zero polling cost);
+  and, only in a process that has initialised a backend of its own
+  accord: platform, device kind and count, the devices holding live
+  arrays, jit cache size (pjit C++ caches), live ``jax.Array`` count,
+  and live device memory when the backend reports it
+  (``Device.memory_stats`` — TPU/GPU; CPU returns nothing);
 - **forensics depth**: the owning registry's slow-log ring depth.
 
 Every sample lands as gauges in the owning tracing ``Registry``
@@ -50,6 +53,8 @@ _jax_stats: Dict[str, float] = {
     "compile_ms": 0.0,      # cumulative backend compile wall-ms
     "trace_ms": 0.0,        # cumulative jaxpr trace wall-ms
     "lower_ms": 0.0,        # cumulative jaxpr->MLIR lowering wall-ms
+    "cache_hits": 0.0,      # persistent compilation cache: loaded from disk
+    "cache_misses": 0.0,    # ... compiled, then written to disk
 }
 
 #: jax.monitoring event suffixes -> stat keys (duration events)
@@ -59,6 +64,20 @@ _DURATION_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": ("trace_ms", None),
     "/jax/core/compile/jaxpr_to_mlir_module_duration": ("lower_ms", None),
 }
+
+
+#: jax.monitoring plain events -> stat keys
+_COUNT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+
+
+def _on_event(event: str, **_kw: Any) -> None:
+    key = _COUNT_EVENTS.get(event)
+    if key is not None:
+        with _jax_lock:
+            _jax_stats[key] += 1
 
 
 def _on_duration(event: str, duration_secs: float, **_kw: Any) -> None:
@@ -87,6 +106,7 @@ def install_jax_hooks() -> bool:
         if _jax_hooked:
             return True
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
         _jax_hooked = True
     return True
 
@@ -125,19 +145,46 @@ def _proc_sample() -> Dict[str, Any]:
     return out
 
 
+def jax_backend_initialized() -> bool:
+    """True once THIS process has initialised a jax backend of its own
+    accord. Never imports jax and never initialises one: a chip belongs
+    to one process, and a proxy or a CLI that opened it by asking would
+    take it from the server."""
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    return bridge is not None and bridge.backends_are_initialized()
+
+
 def _jax_sample() -> Dict[str, Any]:
-    """JAX signals — only when jax is ALREADY imported (a telemetry
-    thread must never pay, or trigger, the jax import in a process that
-    doesn't use it: jubactl, jubadump, coordd)."""
+    """JAX signals. The listener counters are always safe to read; the
+    device, array and memory figures are read only when
+    :func:`jax_backend_initialized` — ``jax.live_arrays()`` and
+    ``jax.local_devices()`` would otherwise initialise a backend in a
+    process that never computes (proxy, jubactl, jubadump, coordd)."""
+    out: Dict[str, Any] = {
+        "jax_backend_initialized": jax_backend_initialized()}
     if "jax" not in sys.modules:
-        return {}
-    out: Dict[str, Any] = {}
+        return out
+    import jax  # already imported: costs nothing, initialises nothing
+
     for k, v in jax_compile_stats().items():
         out[f"jax_{k}"] = round(v, 3) if k.endswith("_ms") else int(v)
+    out["jax_compilation_cache_dir"] = \
+        jax.config.jax_compilation_cache_dir or ""
+    if not out["jax_backend_initialized"]:
+        return out
     try:
-        import jax
-
-        out["jax_live_arrays"] = len(jax.live_arrays())
+        devs = jax.devices()
+        out["jax_platform"] = devs[0].platform
+        out["jax_device_kind"] = devs[0].device_kind
+        out["jax_device_count"] = len(devs)
+        live = jax.live_arrays()
+        out["jax_live_arrays"] = len(live)
+        # in a server the live arrays are the model state (plus a few
+        # temporaries), so this is where the model lives; a collective's
+        # global arrays span other processes' devices and are left out
+        out["jax_array_devices"] = sorted(
+            {str(d) for a in live if a.is_fully_addressable
+             for d in a.devices()})
         in_use = 0
         have = False
         for d in jax.local_devices():

@@ -65,9 +65,8 @@ def test_converter_convert_same_with_and_without_native(monkeypatch):
 
 @pytest.fixture(scope="module")
 def sample_splitter_so(tmp_path_factory):
-    src = os.path.join(native.NATIVE_DIR, "sample_ngram_splitter.cpp")
-    out = os.path.join(native.BUILD_DIR, "libsample_ngram_splitter.so")
-    if native._stale(src, out) and not native._compile(src, out):
+    out = native.build("sample_ngram_splitter")
+    if out is None:
         pytest.skip("cannot build sample splitter")
     return out
 

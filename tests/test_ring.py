@@ -12,8 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from jubatus_tpu.parallel._compat import shard_map
 
 from jubatus_tpu.ops import knn
 from jubatus_tpu.parallel.mesh import grid_mesh
