@@ -1,0 +1,14 @@
+"""Ingest: share of the window's train flushes that took the sparse plan:
+``ingest.sparse_flushes`` over all the plans' flush counters, which are
+all stamped at the same stage (the coalescer's own flush count is stamped
+a stage later, so it can differ by the one flush in the pipeline)."""
+
+from harness import reading
+
+NAME = "ingest.sparse_flush_share"
+
+
+def read(run):
+    by_plan = [reading.counter(run, f"ingest.{p}_flushes")
+               for p in ("sparse", "schema", "combo")]
+    return 100.0 * by_plan[0] / sum(by_plan) if sum(by_plan) > 0 else None
