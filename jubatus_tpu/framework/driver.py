@@ -11,9 +11,10 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Any, Dict, List
+from typing import Any, ContextManager, Dict, List
 
 from jubatus_tpu.parallel.mix import Mixable
+from jubatus_tpu.utils.tracing import span_in
 
 
 def locked(fn):
@@ -45,6 +46,13 @@ class DriverBase:
         #: participant's lock for the round (parallel/mix.py), so a background
         #: mix can never interleave with train/classify on the same model.
         self.lock = threading.RLock()
+        #: tracing Registry the owning server hands over (its own): the
+        #: step's phase spans and counters go there. A driver used
+        #: without a server (bench.py, unit tests) records nothing.
+        self.trace: Any = None
+
+    def _span(self, name: str) -> ContextManager:
+        return span_in(self.trace, name)
 
     # -- mix plane ----------------------------------------------------------
     def get_mixables(self) -> Dict[str, Mixable]:

@@ -18,7 +18,7 @@ array.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -215,33 +215,40 @@ class ClassifierDriver(DriverBase):
         buckets as the converter path), padding, and the device step. Both
         hashed entry points funnel here so their semantics cannot drift."""
         bsz = _bucket(b, 16)
-        if bsz != b:
-            idx = np.pad(idx, ((0, bsz - b), (0, 0)))
-            val = np.pad(val, ((0, bsz - b), (0, 0)))
-        slots_arr = np.zeros(bsz, dtype=np.int32)
-        slots_arr[:b] = slots
-        if self._mesh is not None and self.train_mode == "parallel":
-            # shard_map path: batch routed by column range, one psum for
-            # the logits — weight state never moves (ISSUE 13 tentpole)
-            from jubatus_tpu.parallel import sharded_model as _sm
+        with self._span("step.train.stage"):
+            if bsz != b:
+                idx = np.pad(idx, ((0, bsz - b), (0, 0)))
+                val = np.pad(val, ((0, bsz - b), (0, 0)))
+            slots_arr = np.zeros(bsz, dtype=np.int32)
+            slots_arr[:b] = slots
+            didx, dval = jnp.asarray(idx), jnp.asarray(val)
+            dslots, mask = jnp.asarray(slots_arr), self._mask()
+        with self._span("step.train.dispatch"):
+            if self._mesh is not None and self.train_mode == "parallel":
+                # shard_map path: batch routed by column range, one psum
+                # for the logits — weight state never moves (ISSUE 13)
+                from jubatus_tpu.parallel import sharded_model as _sm
 
-            self.state = _sm.train_batch(
-                self._mesh, self.state, jnp.asarray(idx), jnp.asarray(val),
-                jnp.asarray(slots_arr), self._mask(), self.param,
-                method=self.method, axis=self._mesh_axis)
-        else:
-            # sequential mode keeps GSPMD partitioning of the placed state
-            self.state = ops.train_batch(
-                self.state,
-                jnp.asarray(idx),
-                jnp.asarray(val),
-                jnp.asarray(slots_arr),
-                self._mask(),
-                self.param,
-                method=self.method,
-                mode=self.train_mode,
-            )
+                self.state = _sm.train_batch(
+                    self._mesh, self.state, didx, dval, dslots, mask,
+                    self.param, method=self.method, axis=self._mesh_axis)
+            else:
+                # sequential mode keeps GSPMD partitioning of the placed
+                # state
+                self.state = ops.train_batch(
+                    self.state, didx, dval, dslots, mask, self.param,
+                    method=self.method, mode=self.train_mode)
+        return self._trained(b, bsz)
+
+    def _trained(self, b: int, bsz: int) -> int:
+        """What every train plan does once its step is dispatched: the
+        update event, and (under a server) the rows asked for and the
+        rows the compiled bucket ran."""
         self.event_model_updated(b)
+        trace = self.trace
+        if trace is not None:
+            trace.count("step.train.rows", b)
+            trace.count("step.train.rows_padded", bsz)
         return b
 
     @locked
@@ -305,20 +312,16 @@ class ClassifierDriver(DriverBase):
             return self._train_slots(
                 slots, np.broadcast_to(uidx, (b, uidx.shape[0])), val, b)
         bsz = _bucket(b, 16)
-        if bsz != b:  # zero rows are no-ops (x2 = 0 → alpha 0)
-            val = np.pad(val, ((0, bsz - b), (0, 0)))
-            slots = np.pad(slots, (0, bsz - b))
-        self.state = ops.train_batch_schema(
-            self.state,
-            jnp.asarray(uidx),
-            jnp.asarray(val),
-            jnp.asarray(slots),
-            self._mask(),
-            self.param,
-            method=self.method,
-        )
-        self.event_model_updated(b)
-        return b
+        with self._span("step.train.stage"):
+            if bsz != b:  # zero rows are no-ops (x2 = 0 → alpha 0)
+                val = np.pad(val, ((0, bsz - b), (0, 0)))
+                slots = np.pad(slots, (0, bsz - b))
+            staged = (jnp.asarray(uidx), jnp.asarray(val),
+                      jnp.asarray(slots), self._mask())
+        with self._span("step.train.dispatch"):
+            self.state = ops.train_batch_schema(
+                self.state, *staged, self.param, method=self.method)
+        return self._trained(b, bsz)
 
     @locked
     def train_indexed_combo(self, uniq_labels: Sequence[str],
@@ -348,69 +351,91 @@ class ClassifierDriver(DriverBase):
             return self._train_slots(
                 slots, np.broadcast_to(uidx, (b, uidx.shape[0])), full, b)
         bsz = _bucket(b, 16)
-        if bsz != b:  # zero base rows expand to zero slots — still no-ops
-            base_val = np.pad(base_val, ((0, bsz - b), (0, 0)))
-            slots = np.pad(slots, (0, bsz - b))
-        self.state = ops.train_batch_schema_combo(
-            self.state,
-            jnp.asarray(uidx),
-            jnp.asarray(base_val),
-            jnp.asarray(a_idx),
-            jnp.asarray(b_idx),
-            jnp.asarray(mul_mask),
-            jnp.asarray(slots),
-            self._mask(),
-            self.param,
-            method=self.method,
-        )
-        self.event_model_updated(b)
-        return b
+        with self._span("step.train.stage"):
+            if bsz != b:  # zero base rows expand to zero slots — still no-ops
+                base_val = np.pad(base_val, ((0, bsz - b), (0, 0)))
+                slots = np.pad(slots, (0, bsz - b))
+            staged = (jnp.asarray(uidx), jnp.asarray(base_val),
+                      jnp.asarray(a_idx), jnp.asarray(b_idx),
+                      jnp.asarray(mul_mask), jnp.asarray(slots),
+                      self._mask())
+        with self._span("step.train.dispatch"):
+            self.state = ops.train_batch_schema_combo(
+                self.state, *staged, self.param, method=self.method)
+        return self._trained(b, bsz)
+
+    def _scored_rows(self, n: int, stage: Callable[[int], tuple],
+                     score: Callable[..., Any]
+                     ) -> List[List[Tuple[str, float]]]:
+        """The read side's one path, whatever the plan: ``stage(pad)``
+        pads ``pad`` zero rows on and uploads, ``score(state, *staged,
+        mask)`` enqueues the plan's program.
+
+        Dispatch-under-lock, wait-unlocked: the scores computation is
+        ENQUEUED while the driver lock guarantees no train step can
+        donate the state buffers first (train_batch donates for in-place
+        scatters — dispatching against an already-donated Array raises
+        "Array has been deleted"); once enqueued, the runtime keeps the
+        buffers alive for the pending read, so the device round trip and
+        result wait run unlocked and concurrent queries overlap instead
+        of serializing. ≙ the reference's JRLOCK_ shared reads. H2D
+        transfers touch no driver state: staged unlocked, so the
+        critical section is just the enqueue."""
+        b = _bucket(n, 16)
+        with self._span("step.classify.stage"):
+            staged = stage(b - n)
+        with self._span("step.classify.lock_wait"):
+            self.lock.acquire()
+        try:
+            if not self.label_slots:
+                return [[] for _ in range(n)]
+            slots = list(self.label_slots.items())
+            with self._span("step.classify.dispatch"):
+                pending = score(self.state, *staged, self._mask())
+        finally:
+            self.lock.release()
+        with self._span("step.classify.wait"):
+            sc = np.asarray(pending)[:n]
+        trace = self.trace
+        if trace is not None:
+            trace.count("step.classify.rows", n)
+            trace.count("step.classify.rows_padded", b)
+        # (label, score) pairs are the answer as it goes on the wire: the
+        # packer writes a tuple as it writes a list, so the service hands
+        # these rows on as they are
+        with self._span("classify.encode"):
+            return [[(lab, float(row[slot]))
+                     for lab, slot in slots] for row in sc]
 
     def classify_hashed_combo(self, uidx: np.ndarray, base_val: np.ndarray,
                               a_idx: np.ndarray, b_idx: np.ndarray,
                               mul_mask: np.ndarray
                               ) -> List[List[Tuple[str, float]]]:
-        """classify_hashed_schema with device-side combo expansion —
-        same lock discipline (enqueue under the lock, wait unlocked)."""
+        """classify_hashed_schema with device-side combo expansion."""
         n = base_val.shape[0]
         if n == 0:
             return []
-        b = _bucket(n, 16)
-        if b != n:
-            base_val = np.pad(base_val, ((0, b - n), (0, 0)))
-        duidx, dval = jnp.asarray(uidx), jnp.asarray(base_val)
-        da, db = jnp.asarray(a_idx), jnp.asarray(b_idx)
-        dm = jnp.asarray(mul_mask)
-        with self.lock:
-            if not self.label_slots:
-                return [[] for _ in range(n)]
-            slots = list(self.label_slots.items())
-            pending = ops.scores_schema_combo(
-                self.state, duidx, dval, da, db, dm, self._mask())
-        sc = np.asarray(pending)[:n]
-        return [[(lab, float(row[slot]))
-                 for lab, slot in slots] for row in sc]
+
+        def stage(pad: int) -> tuple:
+            val = np.pad(base_val, ((0, pad), (0, 0))) if pad else base_val
+            return (jnp.asarray(uidx), jnp.asarray(val), jnp.asarray(a_idx),
+                    jnp.asarray(b_idx), jnp.asarray(mul_mask))
+
+        return self._scored_rows(n, stage, ops.scores_schema_combo)
 
     def classify_hashed_schema(self, uidx: np.ndarray,
                                val: np.ndarray) -> List[List[Tuple[str, float]]]:
         """classify_hashed for a uniform-schema batch (ops.scores_schema:
-        K descriptors + one matmul). Same lock discipline as
-        classify_hashed: enqueue under the lock, wait unlocked."""
+        K descriptors + one matmul)."""
         n = val.shape[0]
         if n == 0:
             return []
-        b = _bucket(n, 16)
-        if b != n:
-            val = np.pad(val, ((0, b - n), (0, 0)))
-        duidx, dval = jnp.asarray(uidx), jnp.asarray(val)
-        with self.lock:
-            if not self.label_slots:
-                return [[] for _ in range(n)]
-            slots = list(self.label_slots.items())
-            pending = ops.scores_schema(self.state, duidx, dval, self._mask())
-        sc = np.asarray(pending)[:n]
-        return [[(lab, float(row[slot]))
-                 for lab, slot in slots] for row in sc]
+
+        def stage(pad: int) -> tuple:
+            v = np.pad(val, ((0, pad), (0, 0))) if pad else val
+            return jnp.asarray(uidx), jnp.asarray(v)
+
+        return self._scored_rows(n, stage, ops.scores_schema)
 
     def classify(self, data: Sequence[Datum]) -> List[List[Tuple[str, float]]]:
         # deliberately NOT @locked: batch conversion touches no driver
@@ -428,40 +453,25 @@ class ClassifierDriver(DriverBase):
     def classify_hashed(self, idx: np.ndarray,
                         val: np.ndarray) -> List[List[Tuple[str, float]]]:
         """Classify pre-hashed features (native ingest fast path); same
-        output shape as classify().
-
-        Dispatch-under-lock, wait-unlocked: the scores computation is
-        ENQUEUED while the driver lock guarantees no train step can
-        donate the state buffers first (train_batch donates for in-place
-        scatters — dispatching against an already-donated Array raises
-        "Array has been deleted"); once enqueued, the runtime keeps the
-        buffers alive for the pending read, so the device round trip and
-        result wait run unlocked and concurrent queries overlap instead
-        of serializing. ≙ the reference's JRLOCK_ shared reads."""
+        output shape as classify()."""
         n = idx.shape[0]
         if n == 0:
             return []
-        b = _bucket(n, 16)
-        if b != n:
-            idx = np.pad(idx, ((0, b - n), (0, 0)))
-            val = np.pad(val, ((0, b - n), (0, 0)))
-        # H2D transfers touch no driver state: stage them unlocked so the
-        # critical section is just the enqueue
-        didx, dval = jnp.asarray(idx), jnp.asarray(val)
-        with self.lock:
-            if not self.label_slots:
-                return [[] for _ in range(n)]
-            slots = list(self.label_slots.items())
-            if self._mesh is not None:
-                from jubatus_tpu.parallel import sharded_model as _sm
 
-                pending = _sm.scores(self._mesh, self.state, didx, dval,
-                                     self._mask(), axis=self._mesh_axis)
-            else:
-                pending = ops.scores(self.state, didx, dval, self._mask())
-        sc = np.asarray(pending)[:n]
-        return [[(lab, float(row[slot]))
-                 for lab, slot in slots] for row in sc]
+        def stage(pad: int) -> tuple:
+            i, v = idx, val
+            if pad:
+                i = np.pad(i, ((0, pad), (0, 0)))
+                v = np.pad(v, ((0, pad), (0, 0)))
+            return jnp.asarray(i), jnp.asarray(v)
+
+        if self._mesh is None:
+            return self._scored_rows(n, stage, ops.scores)
+        from jubatus_tpu.parallel import sharded_model as _sm
+
+        return self._scored_rows(
+            n, stage, lambda state, didx, dval, mask: _sm.scores(
+                self._mesh, state, didx, dval, mask, axis=self._mesh_axis))
 
     def shard_stats(self) -> Dict[str, Any]:
         """Feature-shard layout gauges (shard.* catalog rows,

@@ -147,11 +147,14 @@ def scores(state: ClassifierState, idx: jax.Array, val: jax.Array,
     row gather costs the same ~75 ms/2M as a [D] element gather, while L
     separate gathers scale linearly).
     """
-    eff = (state.w + state.dw).T  # [D, L]
-    g = jnp.take(eff, idx.reshape(-1), axis=0)       # [B*K, L]
-    g = g.reshape(idx.shape + (eff.shape[1],))       # [B, K, L]
-    s = jnp.einsum("bkl,bk->bl", g, val)
-    return jnp.where(label_mask[None, :], s, _NEG)
+    # the scope is the program's own word beside XLA's op names in a
+    # device capture: metadata only
+    with jax.named_scope("scores"):
+        eff = (state.w + state.dw).T  # [D, L]
+        g = jnp.take(eff, idx.reshape(-1), axis=0)       # [B*K, L]
+        g = g.reshape(idx.shape + (eff.shape[1],))       # [B, K, L]
+        s = jnp.einsum("bkl,bk->bl", g, val)
+        return jnp.where(label_mask[None, :], s, _NEG)
 
 
 def _alpha_and_prec(method: str, param: float, margin, loss, x2, v, x2_vec):
@@ -237,61 +240,67 @@ def train_batch_parallel(
     # gathers cost ~101 ms; the packed single gather ~75 ms for the same
     # data — gather cost is per descriptor, not per element — for a
     # bit-exact 1.20x on the whole step (docs/PERF_NOTES.md).
-    eff = w + dw                                                   # [L, D]
-    if confidence:
-        packed = jnp.concatenate([eff, prec + dprec], axis=0).T    # [D, 2L]
-    else:
-        packed = eff.T                                             # [D, L]
-    g = jnp.take(packed, idx.reshape(-1), axis=0)
-    g = g.reshape(idx.shape + (packed.shape[1],))                  # [B, K, *]
-    eff_g = jnp.moveaxis(g[..., :num_labels], -1, 0)               # [L, B, K]
-    s = jnp.einsum("lbk,bk->bl", eff_g, val)
-    x2_vec = val * val                                             # [B, K]
-    x2 = jnp.sum(x2_vec, axis=1)                                   # [B]
+    # The scopes (pack, gather, margin, scatter) are the phases' own
+    # words beside XLA's op names in a device capture: metadata only.
+    with jax.named_scope("pack"):
+        eff = w + dw                                               # [L, D]
+        if confidence:
+            packed = jnp.concatenate([eff, prec + dprec], axis=0).T    # [D, 2L]
+        else:
+            packed = eff.T                                         # [D, L]
+    with jax.named_scope("gather"):
+        g = jnp.take(packed, idx.reshape(-1), axis=0)
+        g = g.reshape(idx.shape + (packed.shape[1],))              # [B, K, *]
+        eff_g = jnp.moveaxis(g[..., :num_labels], -1, 0)           # [L, B, K]
+    with jax.named_scope("margin"):
+        s = jnp.einsum("lbk,bk->bl", eff_g, val)
+        x2_vec = val * val                                         # [B, K]
+        x2 = jnp.sum(x2_vec, axis=1)                               # [B]
 
-    if confidence:
-        p_g = jnp.moveaxis(g[..., num_labels:], -1, 0)             # [L, B, K]
-        p_c = jnp.take_along_axis(p_g, labels[None, :, None], axis=0)[0]  # [B,K]
-        sig_c = 1.0 / p_c
-    else:
-        sig_c = jnp.ones_like(val)
+        if confidence:
+            p_g = jnp.moveaxis(g[..., num_labels:], -1, 0)         # [L, B, K]
+            p_c = jnp.take_along_axis(p_g, labels[None, :, None], axis=0)[0]  # [B,K]
+            sig_c = 1.0 / p_c
+        else:
+            sig_c = jnp.ones_like(val)
 
-    # v needs sigma of the *wrong* row, which needs the scores first; compute
-    # the margin decision with a provisional v=0 only for non-confidence
-    # methods (their alpha ignores v).
-    if confidence:
-        # first pass for `wrong` (alpha ignored), then exact v
-        wrong0, _, _, _ = decide_updates(
-            s, labels, label_mask, x2, jnp.zeros_like(x2), x2_vec, param,
-            method=method,
+        # v needs sigma of the *wrong* row, which needs the scores first; compute
+        # the margin decision with a provisional v=0 only for non-confidence
+        # methods (their alpha ignores v).
+        if confidence:
+            # first pass for `wrong` (alpha ignored), then exact v
+            wrong0, _, _, _ = decide_updates(
+                s, labels, label_mask, x2, jnp.zeros_like(x2), x2_vec, param,
+                method=method,
+            )
+            p_w = jnp.take_along_axis(p_g, wrong0[None, :, None], axis=0)[0]
+            # no rival label → `wrong0` points at a dead/arbitrary row; the
+            # nonexistent rival carries the unit precision prior, not that
+            # row's (possibly trained) precision
+            no_rival = jnp.sum(label_mask) < 2
+            sig_w = jnp.where(no_rival, 1.0, 1.0 / p_w)
+            v = jnp.sum((sig_c + sig_w) * x2_vec, axis=1)          # [B]
+        else:
+            sig_w = jnp.ones_like(val)
+            v = jnp.zeros_like(x2)
+
+        wrong, alpha, alpha_w, dp = decide_updates(
+            s, labels, label_mask, x2, v, x2_vec, param, method=method
         )
-        p_w = jnp.take_along_axis(p_g, wrong0[None, :, None], axis=0)[0]
-        # no rival label → `wrong0` points at a dead/arbitrary row; the
-        # nonexistent rival carries the unit precision prior, not that
-        # row's (possibly trained) precision
-        no_rival = jnp.sum(label_mask) < 2
-        sig_w = jnp.where(no_rival, 1.0, 1.0 / p_w)
-        v = jnp.sum((sig_c + sig_w) * x2_vec, axis=1)              # [B]
-    else:
-        sig_w = jnp.ones_like(val)
-        v = jnp.zeros_like(x2)
 
-    wrong, alpha, alpha_w, dp = decide_updates(
-        s, labels, label_mask, x2, v, x2_vec, param, method=method
-    )
-
-    # NB: a single fused [2B, K] scatter (concat correct+wrong updates) was
-    # measured numerically equivalent but throughput-neutral on v5e; two
-    # plain scatters stay for simplicity
-    up_c = alpha[:, None] * sig_c * val                            # [B, K]
-    up_w = alpha_w[:, None] * sig_w * val
-    dw = dw.at[labels[:, None], idx].add(up_c)
-    dw = dw.at[wrong[:, None], idx].add(-up_w)
-    if confidence:
-        dprec = dprec.at[labels[:, None], idx].add(dp)
-        dprec = dprec.at[wrong[:, None], idx].add(
-            jnp.where((alpha_w > 0.0)[:, None], dp, 0.0)
-        )
+    with jax.named_scope("scatter"):
+        # NB: a single fused [2B, K] scatter (concat correct+wrong updates) was
+        # measured numerically equivalent but throughput-neutral on v5e; two
+        # plain scatters stay for simplicity
+        up_c = alpha[:, None] * sig_c * val                        # [B, K]
+        up_w = alpha_w[:, None] * sig_w * val
+        dw = dw.at[labels[:, None], idx].add(up_c)
+        dw = dw.at[wrong[:, None], idx].add(-up_w)
+        if confidence:
+            dprec = dprec.at[labels[:, None], idx].add(dp)
+            dprec = dprec.at[wrong[:, None], idx].add(
+                jnp.where((alpha_w > 0.0)[:, None], dp, 0.0)
+            )
     return ClassifierState(w, dw, prec, dprec)
 
 

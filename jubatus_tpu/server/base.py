@@ -91,6 +91,14 @@ class EngineServer:
             timeout=self.args.timeout,
             legacy_wire=getattr(self.args, "legacy_wire", False),
             wire_detect=not getattr(self.args, "modern_wire", False))
+        # one timeline (ISSUE 24): this process owns the chip, so its
+        # registry's spans also open profiler annotations (they land in a
+        # profile_device capture beside the device's operations), and the
+        # driver records its step phases into the same registry
+        import jax.profiler
+
+        self.rpc.trace.annotate = jax.profiler.TraceAnnotation
+        self.driver.trace = self.rpc.trace
         # forensics plane (ISSUE 4): slow-request ring tuning off the
         # --slowlog-* flags, and the runtime telemetry sampler thread
         self.rpc.trace.slowlog.configure(

@@ -29,9 +29,10 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Callable, List, Sequence
+from typing import Any, Callable, ContextManager, List, Sequence
 
 from jubatus_tpu.rpc import principal as principals
+from jubatus_tpu.utils.tracing import current_trace, span_in
 
 __all__ = ["Coalescer", "PipelinedCoalescer"]
 
@@ -39,14 +40,17 @@ __all__ = ["Coalescer", "PipelinedCoalescer"]
 #: by the coalescer tuner's Little's-law target and the capacity model
 #: in utils/usage.py — ~10 flushes of memory, newest weighted heaviest
 FLUSH_EWMA_ALPHA = 0.2  # knob-ok — the smoothing weight, not a depth
+#: ``flushes_fill_<k>`` in ``stats()``: flushes by floor(8 x rows /
+#: max_batch), k = 0..8 (8,000 rows of 8,192 is k = 7)
+FILL_BUCKETS = 8
 
 
 class _Ticket:
     __slots__ = ("event", "result", "error", "count", "weight",
-                 "principal", "enq", "claimed")
+                 "principal", "enq", "claimed", "ctx")
 
     def __init__(self, count: int, weight: int,
-                 principal: str | None = None, enq: float = 0.0) -> None:
+                 principal: str | None = None) -> None:
         self.event = threading.Event()
         self.result: Any = None
         self.error: BaseException | None = None
@@ -58,8 +62,12 @@ class _Ticket:
         #: flush time — plus the enqueue/claim stamps queue residency
         #: derives from
         self.principal = principal
-        self.enq = enq
+        self.enq = time.perf_counter()
         self.claimed = 0.0
+        #: the submitting request's trace context, for the same reason:
+        #: the flusher records this ticket's queue_wait / flush_wait
+        #: spans under the ticket's trace id, not its own
+        self.ctx = current_trace()
 
 
 class Coalescer:
@@ -76,7 +84,8 @@ class Coalescer:
     def __init__(self, flush_fn: Callable[[List[Any]], Any],
                  max_batch: int = 8192,
                  weigher: Callable[[Any], int] | None = None,
-                 split_results: bool = False) -> None:
+                 split_results: bool = False,
+                 trace: Any = None, name: str = "") -> None:
         """``weigher(item) -> examples`` lets one item represent a whole
         request's batch (the native fast path queues per-REQUEST array
         triples — far less Python object churn than per-example rows);
@@ -85,9 +94,19 @@ class Coalescer:
         ``split_results``: QUERY-plane mode — ``flush_fn`` must return a
         sequence with one entry per submitted item, and each submitter
         receives exactly its own slice (train flushes return one shared
-        scalar instead, the default)."""
+        scalar instead, the default).
+
+        ``trace`` (a tracing Registry) and ``name`` (the coalescer's key
+        in ``server.coalescers``): where and under what prefix the phase
+        spans go — per ticket ``microbatch.<name>.queue_wait`` (enqueue
+        to claim) and ``.flush_wait`` (claim to answer) under the
+        ticket's trace id, per turn ``.flusher_turn`` (how long a
+        submitter's thread served as the flusher), per flush
+        ``.device_stage``. Without a registry nothing is recorded."""
         if max_batch < 1:
             raise ValueError("max_batch must be >= 1")
+        self._trace = trace
+        self._span_prefix = f"microbatch.{name}."
         self._flush = flush_fn
         self._max_batch = max_batch
         self._weigher = weigher
@@ -99,6 +118,9 @@ class Coalescer:
         #: flush invocations / items flushed (observability; get_status)
         self.flush_count = 0
         self.item_count = 0
+        #: claims by how full they were (FILL_BUCKETS): the flush-size
+        #: histogram, stamped at claim
+        self._fill = [0] * (FILL_BUCKETS + 1)
         #: queued-but-unflushed examples (the autoscaler's primary load
         #: signal: arrival outrunning the device drains HERE first) and
         #: the cumulative arrival counter its rate derives from
@@ -145,9 +167,7 @@ class Coalescer:
         ticket = _Ticket(len(items), weight,
                          principal=(principals.current()
                                     if self.usage_hook is not None
-                                    else None),
-                         enq=(time.perf_counter()
-                              if self.usage_hook is not None else 0.0))
+                                    else None))
         with self._lock:
             self._pending_items.extend(items)
             self._pending_tickets.append(ticket)
@@ -157,7 +177,8 @@ class Coalescer:
             if i_flush:
                 self._active = True
         if i_flush:
-            self._drain()
+            with self._span("flusher_turn"):
+                self._drain()
         if not ticket.event.wait(timeout):
             with self._lock:
                 if ticket in self._pending_tickets:
@@ -179,6 +200,27 @@ class Coalescer:
         if ticket.error is not None:
             raise ticket.error
         return ticket.result
+
+    def _span(self, what: str) -> ContextManager:
+        return span_in(self._trace, self._span_prefix + what)
+
+    def _record_tickets(self, what: str, tickets: List[_Ticket]) -> None:
+        """One span per ticket under the ticket's own trace context:
+        ``queue_wait`` from its enqueue to its claim, ``flush_wait`` from
+        its claim to now. Called outside the queue lock (the registry has
+        its own; submitters must not wait for it). Never raises — it
+        runs ahead of the tickets' events and of the device slot's
+        release, and a record must not cost a flush its answers."""
+        trace = self._trace
+        if trace is None:
+            return
+        now = time.perf_counter()
+        try:
+            trace.record_each(self._span_prefix + what, [
+                (t.claimed - t.enq if what == "queue_wait"
+                 else now - t.claimed, t.ctx) for t in tickets])
+        except Exception:  # broad-ok — tracing is best-effort, as _bill
+            pass
 
     def _claim(self):
         """Pop the next batch (items + tickets + weight) under the lock;
@@ -205,10 +247,11 @@ class Coalescer:
             batch.extend(self._pending_items[:t.count])
             del self._pending_items[:t.count]
         self._pending_weight -= batch_weight
-        if self.usage_hook is not None:
-            now = time.perf_counter()
-            for t in tickets:
-                t.claimed = now
+        self._fill[min(FILL_BUCKETS, FILL_BUCKETS * batch_weight
+                       // self._max_batch)] += 1
+        now = time.perf_counter()
+        for t in tickets:
+            t.claimed = now
         return batch, tickets, batch_weight
 
     def _bill(self, tickets: List[_Ticket], batch_weight: int,
@@ -223,7 +266,7 @@ class Coalescer:
         for t in tickets:
             share = (device_dt * t.weight / batch_weight
                      if batch_weight else 0.0)
-            queued = max(0.0, t.claimed - t.enq) if t.enq else 0.0
+            queued = max(0.0, t.claimed - t.enq)
             try:
                 hook(t.principal, t.weight, queued, share)
             except Exception:  # broad-ok — billing is best-effort
@@ -258,9 +301,12 @@ class Coalescer:
                 if claimed is None:
                     return
                 batch, tickets, batch_weight = claimed
+            self._record_tickets("queue_wait", tickets)
             t0 = time.perf_counter()
             try:
-                result = self._flush(batch)
+                # single-stage flush: the whole flush IS the device step
+                with self._span("device_stage"):
+                    result = self._flush(batch)
                 if self._split:
                     if len(result) != len(batch):
                         raise RuntimeError(
@@ -282,8 +328,8 @@ class Coalescer:
                     self.flush_count += 1
                     self.item_count += batch_weight  # examples, not items
                 self._note_flush_ms(dt)
-                # single-stage flush: the whole flush IS the device step
                 self._bill(tickets, batch_weight, dt)
+                self._record_tickets("flush_wait", tickets)
                 for t in tickets:
                     t.event.set()
 
@@ -314,7 +360,9 @@ class Coalescer:
             depth = self._pending_weight
             flush_ms = self._flush_ms_ewma
             max_batch = self._max_batch
+            fill = list(self._fill)
         return {
+            **{f"flushes_fill_{k}": n for k, n in enumerate(fill)},
             "flush_count": flushes,
             "item_count": items,
             "avg_batch": (items / flushes if flushes else 0.0),
@@ -339,8 +387,8 @@ class PipelinedCoalescer(Coalescer):
     stage-2 error fails them when the device stage completes.
 
     Span stamping: when ``trace`` (a tracing Registry) is given, stage 1
-    records ``fv.convert`` and stage 2 ``fv.upload`` — the featurize vs
-    device split in ``jubactl -c trace``/get_status.
+    records ``fv.convert`` and stage 2 ``microbatch.<name>.device_stage``
+    — the featurize vs device split in ``jubactl -c trace``/get_status.
 
     Overlap accounting: ``stats()`` adds prep/device seconds and
     ``overlap_fraction`` — the share of host featurize time that ran
@@ -350,10 +398,10 @@ class PipelinedCoalescer(Coalescer):
                  flush_fn: Callable[[Any], Any],
                  max_batch: int = 8192,
                  weigher: Callable[[Any], int] | None = None,
-                 trace: Any = None) -> None:
-        super().__init__(flush_fn, max_batch=max_batch, weigher=weigher)
+                 trace: Any = None, name: str = "") -> None:
+        super().__init__(flush_fn, max_batch=max_batch, weigher=weigher,
+                         trace=trace, name=name)
         self._prep = prep_fn
-        self._trace = trace
         self._dev_lock = threading.Lock()
         self._dev_ready = threading.Condition(self._dev_lock)
         self._dev_queue: List[tuple] = []      # at most 1 prepared batch
@@ -380,6 +428,7 @@ class PipelinedCoalescer(Coalescer):
             self.flush_count += 1
             self.item_count += batch_weight
         self._bill(tickets, batch_weight, device_dt)
+        self._record_tickets("flush_wait", tickets)
         for t in tickets:
             t.event.set()
 
@@ -399,10 +448,7 @@ class PipelinedCoalescer(Coalescer):
             with self._busy_lock:
                 self._dev_busy_since = time.perf_counter()
             try:
-                if self._trace is not None:
-                    with self._trace.span("fv.upload"):
-                        result = self._flush(prepared)
-                else:
+                with self._span("device_stage"):
                     result = self._flush(prepared)
                 for t in tickets:
                     t.result = result
@@ -429,6 +475,7 @@ class PipelinedCoalescer(Coalescer):
                 if claimed is None:
                     return
                 batch, tickets, batch_weight = claimed
+            self._record_tickets("queue_wait", tickets)
             # stage 1 in THIS thread: overlaps whatever batch the device
             # worker is currently consuming
             t0 = time.perf_counter()
@@ -436,10 +483,7 @@ class PipelinedCoalescer(Coalescer):
             err: BaseException | None = None
             prepared = None
             try:
-                if self._trace is not None:
-                    with self._trace.span("fv.convert"):
-                        prepared = self._prep(batch)
-                else:
+                with span_in(self._trace, "fv.convert"):
                     prepared = self._prep(batch)
             except BaseException as e:  # noqa: BLE001 — deliver to callers
                 err = e

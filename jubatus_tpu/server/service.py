@@ -106,20 +106,30 @@ def bind_engine(rpc: RpcServer, server: Any) -> None:
     _BINDERS[server.engine](rpc, server)
 
 
-def _updating(server: Any, fn: Callable, count: Callable[[Any], int] = lambda r: 1):
+def _updating(server: Any, fn: Callable, count: Callable[[Any], int] = lambda r: 1,
+              lock_span: str = ""):
     """Wrap an update method: driver lock + event_model_updated (the
     reference's JWLOCK_ + serv-side bookkeeping). Most driver methods bump
     the counter themselves; the wrapper only adds the event when the driver
-    didn't, so updates are never double-counted."""
+    didn't, so updates are never double-counted. ``lock_span``: the span
+    that times the wait for the lock (the train steps name theirs)."""
 
     def wrapped(*args):
-        with server.driver.lock:
+        lock = server.driver.lock
+        if lock_span:
+            with server.rpc.trace.span(lock_span):
+                lock.acquire()
+        else:
+            lock.acquire()
+        try:
             before = server.driver.update_count
             result = fn(*args)
             if server.driver.update_count == before:
                 n = count(result)
                 if n:
                     server.driver.event_model_updated(n)
+        finally:
+            lock.release()
         return result
 
     return wrapped
@@ -342,10 +352,11 @@ def _register_train(rpc: RpcServer, server: Any, decode_pair,
     Drivers exposing the featurize/apply split (``featurize_train`` +
     ``train_hashed``) ride the two-stage PipelinedCoalescer: batch N+1
     featurizes on the flusher's host thread (span ``fv.convert``) while
-    the device consumes batch N (span ``fv.upload``) — the feature
-    pipeline's host/device overlap."""
+    the device consumes batch N (span ``microbatch.train.device_stage``)
+    — the feature pipeline's host/device overlap."""
     max_batch = getattr(server.args, "microbatch_max", 8192)
-    flush = _updating(server, train_fn, count=lambda r: r)
+    flush = _updating(server, train_fn, count=lambda r: r,
+                      lock_span="step.train.lock_wait")
     if not max_batch:
         def train_direct(name, data):
             pairs = [decode_pair(p) for p in data]
@@ -362,13 +373,15 @@ def _register_train(rpc: RpcServer, server: Any, decode_pair,
 
         device_step = _updating(
             server, lambda prepared: apply_fn(*prepared),
-            count=lambda r: r)
+            count=lambda r: r, lock_span="step.train.lock_wait")
         co = PipelinedCoalescer(featurize, device_step,
-                                max_batch=max_batch, trace=rpc.trace)
+                                max_batch=max_batch, trace=rpc.trace,
+                                name="train")
     else:
         from jubatus_tpu.server.microbatch import Coalescer
 
-        co = Coalescer(flush, max_batch=max_batch)
+        co = Coalescer(flush, max_batch=max_batch, trace=rpc.trace,
+                       name="train")
     server.coalescers["train"] = co
     co.usage_hook = _usage_batch_hook(server, "train")
 
@@ -557,14 +570,16 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
 
     max_batch = getattr(server.args, "microbatch_max", 8192)
     wait_s = server.args.timeout * 6 if server.args.timeout > 0 else None
-    device_step = _updating(server, apply_prepared, count=lambda r: r)
+    device_step = _updating(server, apply_prepared, count=lambda r: r,
+                            lock_span="step.train.lock_wait")
     if max_batch:
         from jubatus_tpu.server.microbatch import (Coalescer,
                                                    PipelinedCoalescer)
 
         co = PipelinedCoalescer(
             prep_requests, device_step, max_batch=max_batch,
-            weigher=lambda item: item[2].shape[0], trace=rpc.trace)
+            weigher=lambda item: item[2].shape[0], trace=rpc.trace,
+            name="train_raw")
         server.coalescers["train_raw"] = co
         co.usage_hook = _usage_batch_hook(server, "train")
     trace = rpc.trace
@@ -659,7 +674,7 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
 
         qco = Coalescer(query_flush, max_batch=max_batch,
                         weigher=lambda it: it[0].shape[0],
-                        split_results=True)
+                        split_results=True, trace=trace, name=name)
         server.coalescers[name] = qco
         # bill under the wire method ("classify"), not the coalescer key
         qco.usage_hook = _usage_batch_hook(
@@ -699,26 +714,22 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
             plan, val = out
             if plan is None:
                 return []
-            rows = driver.classify_hashed_combo(
+            return driver.classify_hashed_combo(
                 plan.uidx, val, plan.a_idx, plan.b_idx, plan.mul_mask)
-            return [_scored(r) for r in rows]
 
         rpc.register_raw("classify", classify_combo_raw)
     elif not numeric and hasattr(driver, "classify_hashed"):
         if max_batch:
             schema_cls = getattr(driver, "classify_hashed_schema", None)
             rpc.register_raw("classify", _query_coalescer(
-                "classify_raw",
-                lambda i, v: [_scored(r)
-                              for r in driver.classify_hashed(i, v)],
-                schema_score=None if schema_cls is None else
-                (lambda u, v: [_scored(r) for r in schema_cls(u, v)])))
+                "classify_raw", driver.classify_hashed,
+                schema_score=schema_cls))
         else:
             def classify_raw(raw_params: bytes):
                 parsed = _parse_datums(raw_params)
                 if parsed is None:
                     return RAW_FALLBACK
-                return [_scored(r) for r in driver.classify_hashed(*parsed)]
+                return driver.classify_hashed(*parsed)
 
             rpc.register_raw("classify", classify_raw)
 

@@ -26,8 +26,11 @@ the process*. Three capture modes:
   ``profile_device`` RPC wraps ``jax.profiler.trace()`` for a bounded
   duration into a capped artifacts directory (``--profile-dir``), so
   XLA compile/execute/HBM time on a real TPU is one
-  ``jubactl -c profile --device`` away. Old captures are pruned —
-  the artifacts dir can never grow without bound.
+  ``jubactl -c profile --device`` away. The Python tracer is off in a
+  capture: its host plane holds the program's own spans (the tracing
+  Registry opens a profiler annotation per span) and the runtime's
+  events, on the device's clock. Old captures are pruned — the
+  artifacts dir can never grow without bound.
 - **Tail-triggered snapshots**: when utils/slowlog.py sees K breaches
   of the same span inside a window (``--profile-trigger-*``), it calls
   :meth:`SamplingProfiler.tail_snapshot`, which folds the last few
@@ -156,7 +159,15 @@ class SamplingProfiler:
     # -- sampling ------------------------------------------------------------
     def sample_once(self) -> int:
         """Take one sample of every live thread (except the sampler
-        itself); returns the number of stacks folded."""
+        itself); returns the number of stacks folded. Opens a profiler
+        annotation (no histogram), so that its holds on the GIL stay
+        visible in a device capture."""
+        if self.registry is None:
+            return self._sample()
+        with self.registry.annotation("profiler.sample_once"):
+            return self._sample()
+
+    def _sample(self) -> int:
         t0 = time.perf_counter()
         me = threading.get_ident()
         frames = sys._current_frames()
@@ -431,7 +442,13 @@ class DeviceCapture:
                 os.makedirs(path, exist_ok=True)
                 import jax
 
-                with jax.profiler.trace(path):
+                # no hook on every Python call of every thread: the host
+                # plane holds the program's own annotations (Registry.span)
+                # and the runtime's events; Python-frame questions are the
+                # sampling profiler's (get_profile)
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                with jax.profiler.trace(path, profiler_options=options):
                     time.sleep(seconds)
             except Exception as e:  # noqa: BLE001 — backend quirks degrade
                 log.warning("device capture failed", exc_info=True)

@@ -13,7 +13,7 @@ one daemon thread per process that periodically samples:
   and, only in a process that has initialised a backend of its own
   accord: platform, device kind and count, the devices holding live
   arrays, jit cache size (pjit C++ caches), live ``jax.Array`` count,
-  and live device memory when the backend reports it
+  and live and peak device memory when the backend reports them
   (``Device.memory_stats`` — TPU/GPU; CPU returns nothing);
 - **forensics depth**: the owning registry's slow-log ring depth.
 
@@ -185,15 +185,16 @@ def _jax_sample() -> Dict[str, Any]:
         out["jax_array_devices"] = sorted(
             {str(d) for a in live if a.is_fully_addressable
              for d in a.devices()})
-        in_use = 0
-        have = False
-        for d in jax.local_devices():
-            ms = d.memory_stats() if hasattr(d, "memory_stats") else None
-            if ms and "bytes_in_use" in ms:
-                in_use += int(ms["bytes_in_use"])
-                have = True
-        if have:
-            out["jax_device_bytes_in_use"] = in_use
+        # live bytes now, and the allocator's high-water mark since the
+        # process started: what it handed out between two samples (a
+        # flush's inputs, a mix round's buffers). A compiled step's
+        # temporaries are in neither: memory_stats() leaves them out
+        stats = [d.memory_stats() or {} for d in jax.local_devices()
+                 if hasattr(d, "memory_stats")]
+        for key in ("bytes_in_use", "peak_bytes_in_use"):
+            if any(key in ms for ms in stats):
+                out[f"jax_device_{key}"] = sum(
+                    int(ms.get(key, 0)) for ms in stats)
     except Exception:  # noqa: BLE001 — backend quirks must not kill sampling
         pass
     try:  # pjit C++ jit caches (internal API — best-effort by design)

@@ -25,10 +25,13 @@ Three layers:
   slow-request capture rides the same record path: a span at/above a
   configurable quantile of its own histogram lands in the slow-log ring
   (utils/slowlog.py) and stamps a Prometheus exemplar on its bucket.
-- **XLA device traces** (opt-in): ``device_trace()`` wraps
-  ``jax.profiler.trace`` when ``JUBATUS_TPU_TRACE_DIR`` is set (or a dir
-  is passed), capturing TensorBoard-viewable TPU timelines of the jitted
-  update/mix kernels. A no-op otherwise — zero cost in production.
+- **The profiler's clock** (ISSUE 24): ``Registry.span`` also opens a
+  profiler annotation for its duration, through the factory the server
+  hands the registry at start (``annotate``; this module imports no jax:
+  clients import it). With no capture running an annotation costs a
+  branch; in a ``profile_device`` capture (utils/profiler.py
+  DeviceCapture) every span of the program lands in the trace's host
+  plane beside the device's operations, on one clock.
 """
 
 from __future__ import annotations
@@ -40,7 +43,8 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import (Any, Callable, ContextManager, Dict, Iterator, List,
+                    Optional, Tuple)
 
 from jubatus_tpu.utils.events import EventJournal
 from jubatus_tpu.utils.slowlog import SlowLog
@@ -270,20 +274,49 @@ def new_root() -> TraceContext:
 _SPAN_RING = 512
 
 
-class _SpanHandle:
-    """Yielded by ``Registry.span``: ``seconds`` is the measured duration
-    (set at scope exit), ``cancel()`` suppresses the record — the raw
-    fast path's RAW_FALLBACK must not double-count with the generic
-    handler's own span."""
+#: ``record(..., ctx=)`` default: file the span under the calling thread's
+#: own context (``None`` means under no trace at all)
+_CURRENT: Any = object()
+_NO_ANNOTATION = contextlib.nullcontext()
 
-    __slots__ = ("cancelled", "seconds")
 
-    def __init__(self) -> None:
+class _Span:
+    """``Registry.span``'s scope: opens the registry's profiler
+    annotation (if it was handed a factory), times the block, records
+    the span on the way out. ``seconds`` is the measured duration (set
+    at scope exit), ``cancel()`` suppresses the record — the raw fast
+    path's RAW_FALLBACK must not double-count with the generic handler's
+    own span. A plain class, not a generator: every RPC and every phase
+    of every flush passes through here."""
+
+    __slots__ = ("_registry", "_name", "_annotation", "_t0", "cancelled",
+                 "seconds")
+
+    def __init__(self, registry: "Registry", name: str) -> None:
+        self._registry = registry
+        self._name = name
+        annotate = registry.annotate
+        self._annotation = annotate(name) if annotate is not None else None
         self.cancelled = False
         self.seconds = 0.0
 
     def cancel(self) -> None:
         self.cancelled = True
+
+    def __enter__(self) -> "_Span":
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        try:
+            if not self.cancelled:
+                self._registry.record(self._name, self.seconds)
+        finally:
+            if self._annotation is not None:
+                self._annotation.__exit__(*exc)
 
 
 class Registry:
@@ -318,69 +351,105 @@ class Registry:
         #: to the dispatch thread's principal. Called OUTSIDE the
         #: registry lock (the sink takes its own).
         self.usage_sink: Optional[Callable[[str, float], None]] = None
+        #: profiler annotation factory (``jax.profiler.TraceAnnotation``),
+        #: handed over by the server that owns the chip; None in clients,
+        #: proxies and tests, whose spans then open none
+        self.annotate: Optional[Callable[[str], ContextManager]] = None
 
-    @contextlib.contextmanager
-    def span(self, name: str) -> Iterator[_SpanHandle]:
-        t0 = time.perf_counter()
-        h = _SpanHandle()
-        try:
-            yield h
-        finally:
-            h.seconds = time.perf_counter() - t0
-            if not h.cancelled:
-                self.record(name, h.seconds)
+    def annotation(self, name: str) -> ContextManager:
+        """A profiler annotation named ``name`` and no histogram record:
+        what ``span`` opens, and all that code which must not bill itself
+        as a span (the stack sampler) opens."""
+        annotate = self.annotate
+        return annotate(name) if annotate is not None else _NO_ANNOTATION
+
+    def span(self, name: str) -> _Span:
+        return _Span(self, name)
 
     def set_forensics(self, enabled: bool) -> None:
         """Toggle the span store + slow log (histograms/counters stay on)."""
         self._forensics = bool(enabled)
 
-    def record(self, name: str, seconds: float) -> None:
-        ctx = getattr(_tls, "ctx", None)
-        slow_thr: Optional[float] = None
+    def record(self, name: str, seconds: float,
+               ctx: Optional[TraceContext] = _CURRENT) -> None:
+        """``ctx``: the request the span belongs to when it was measured
+        on another request's thread (a ticket's queue wait, measured by
+        the flusher), so that the spans of one request share its
+        trace_id in the span store."""
+        if ctx is _CURRENT:
+            ctx = getattr(_tls, "ctx", None)
         with self._lock:
-            h = self._hists.get(name)
-            if h is None:
-                h = self._hists[name] = Histogram()
-            h.record(seconds)
-            forensics = self._forensics
+            slow_thr = self._record_locked(name, seconds, ctx)
+        self._recorded(name, seconds, ctx, slow_thr)
+
+    def record_each(self, name: str,
+                    spans: List[Tuple[float, Optional[TraceContext]]]
+                    ) -> None:
+        """``record`` for several ``(seconds, ctx)`` of one name under ONE
+        hold of the registry's lock: a flusher files a span per ticket
+        while the tickets' own threads record theirs, and every collision
+        on this lock costs the loser a wait for the interpreter lock."""
+        with self._lock:
+            thrs = [self._record_locked(name, seconds, ctx)
+                    for seconds, ctx in spans]
+        for (seconds, ctx), slow_thr in zip(spans, thrs):
+            self._recorded(name, seconds, ctx, slow_thr)
+
+    def _record_locked(self, name: str, seconds: float,
+                       ctx: Optional[TraceContext]) -> Optional[float]:
+        """The part of a record under the lock: histogram, span store;
+        returns the slow-log threshold the span reached, if any."""
+        slow_thr: Optional[float] = None
+        h = self._hists.get(name)
+        if h is None:
+            h = self._hists[name] = Histogram()
+        h.record(seconds)
+        forensics = self._forensics
+        if forensics:
+            sl = self.slowlog
+            if sl.capacity > 0 and h.count >= sl.min_count:
+                # cached threshold: a 109-bucket quantile walk per
+                # record would tax the dispatch hot path; refresh
+                # every 64 samples tracks the distribution closely
+                # enough for tail capture
+                thr = h.slow_threshold_s
+                if thr is None or (h.count & 63) == 0:
+                    thr = h.slow_threshold_s = h.quantile(sl.quantile)
+                if thr is not None and seconds >= thr:
+                    slow_thr = thr
+                    h.exemplars[bucket_index(seconds)] = (
+                        ctx.trace_id if ctx is not None else "",
+                        seconds, time.time())
+        if ctx is not None:
+            h.last_trace_id = ctx.trace_id
             if forensics:
-                sl = self.slowlog
-                if sl.capacity > 0 and h.count >= sl.min_count:
-                    # cached threshold: a 109-bucket quantile walk per
-                    # record would tax the dispatch hot path; refresh
-                    # every 64 samples tracks the distribution closely
-                    # enough for tail capture
-                    thr = h.slow_threshold_s
-                    if thr is None or (h.count & 63) == 0:
-                        thr = h.slow_threshold_s = h.quantile(sl.quantile)
-                    if thr is not None and seconds >= thr:
-                        slow_thr = thr
-                        h.exemplars[bucket_index(seconds)] = (
-                            ctx.trace_id if ctx is not None else "",
-                            seconds, time.time())
-            if ctx is not None:
-                h.last_trace_id = ctx.trace_id
-                if forensics:
-                    if len(self._spans) >= self._span_cap:
-                        old = self._spans.popleft()
-                        lst = self._by_trace.get(old["trace_id"])
-                        if lst:
-                            if lst[0] is old:
-                                lst.pop(0)
-                            else:  # defensive; eviction is FIFO per trace
-                                try:
-                                    lst.remove(old)
-                                except ValueError:
-                                    pass
-                            if not lst:
-                                del self._by_trace[old["trace_id"]]
-                    rec = {
-                        "trace_id": ctx.trace_id, "span_id": ctx.span_id,
-                        "parent_id": ctx.parent_id, "name": name,
-                        "duration_ms": round(seconds * 1e3, 3),
-                        "ts": time.time() - seconds}
-                    self._spans.append(rec)
-                    self._by_trace.setdefault(ctx.trace_id, []).append(rec)
+                if len(self._spans) >= self._span_cap:
+                    old = self._spans.popleft()
+                    lst = self._by_trace.get(old["trace_id"])
+                    if lst:
+                        if lst[0] is old:
+                            lst.pop(0)
+                        else:  # defensive; eviction is FIFO per trace
+                            try:
+                                lst.remove(old)
+                            except ValueError:
+                                pass
+                        if not lst:
+                            del self._by_trace[old["trace_id"]]
+                rec = {
+                    "trace_id": ctx.trace_id, "span_id": ctx.span_id,
+                    "parent_id": ctx.parent_id, "name": name,
+                    "duration_ms": round(seconds * 1e3, 3),
+                    "ts": time.time() - seconds}
+                self._spans.append(rec)
+                self._by_trace.setdefault(ctx.trace_id, []).append(rec)
+        return slow_thr
+
+    def _recorded(self, name: str, seconds: float,
+                  ctx: Optional[TraceContext],
+                  slow_thr: Optional[float]) -> None:
+        """The part of a record outside the lock: slow-log capture and
+        the usage ledger's tap."""
         if slow_thr is not None:
             self._capture_slow(name, seconds, slow_thr, ctx)
         sink = self.usage_sink
@@ -403,6 +472,17 @@ class Registry:
         rem = _deadline_remaining()
         if rem is not None:
             rec["deadline_remaining_ms"] = round(rem * 1e3, 3)
+        if ctx is not None and name.startswith("rpc."):
+            # the span ring turns over in under a second of traffic: keep
+            # the request's phases with the record that outlives it
+            phases: Dict[str, float] = {}
+            with self._lock:
+                for r in self._by_trace.get(ctx.trace_id, ()):
+                    if r["name"] != name:
+                        phases[r["name"]] = round(
+                            phases.get(r["name"], 0.0) + r["duration_ms"], 3)
+            if phases:
+                rec["phases"] = phases
         self.slowlog.add(rec)
 
     def count(self, name: str, n: int = 1) -> None:
@@ -570,6 +650,12 @@ def span(name: str):
     return _default.span(name)
 
 
+def span_in(registry: Optional[Registry], name: str) -> ContextManager:
+    """``registry.span(name)``, or no span at all where the caller was
+    handed no registry (a coalescer or a driver without a server)."""
+    return registry.span(name) if registry is not None else _NO_ANNOTATION
+
+
 def record(name: str, seconds: float) -> None:
     _default.record(name, seconds)
 
@@ -584,17 +670,3 @@ def trace_status(prefix: str = "trace") -> Dict[str, Any]:
 
 def reset() -> None:
     _default.reset()
-
-
-@contextlib.contextmanager
-def device_trace(trace_dir: Optional[str] = None) -> Iterator[None]:
-    """XLA/TPU profiler capture around a block — TensorBoard format.
-    No-op unless a directory is given or JUBATUS_TPU_TRACE_DIR is set."""
-    trace_dir = trace_dir or os.environ.get("JUBATUS_TPU_TRACE_DIR", "")
-    if not trace_dir:
-        yield
-        return
-    import jax
-
-    with jax.profiler.trace(trace_dir):
-        yield

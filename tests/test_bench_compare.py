@@ -371,7 +371,7 @@ def test_flatten_collapses_round_envelopes():
     assert flat["value"] == 2.0
     assert flat["nested.k_ms"] == 1.0
     assert "tail" not in flat
-    # flat maps (bench_serving / profile_flush output) pass through
+    # flat maps (bench_serving output) pass through
     assert bc.flatten({"a_ms": 1.5})["a_ms"] == 1.5
 
 

@@ -43,13 +43,16 @@ def test_fire_noop_when_disarmed():
 
 
 def test_armed_scope_and_count_limit():
+    # the fired counts are the process's: another file's test may have
+    # fired this site in the same worker before
+    before = faults.stats().get("x.y", 0)
     with faults.armed("x.y:error@2"):
         with pytest.raises(faults.FaultInjected):
             faults.fire("x.y")
         with pytest.raises(faults.FaultInjected):
             faults.fire("x.y")
         faults.fire("x.y")  # budget exhausted
-        assert faults.stats()["x.y"] == 2
+        assert faults.stats()["x.y"] - before == 2
     faults.fire("x.y")  # disarmed on exit
 
 

@@ -315,7 +315,120 @@ def test_pipelined_stamps_fv_spans():
     assert co.submit([1, 2]) == 2
     status = reg.trace_status()
     assert any(k.startswith("trace.fv.convert.") for k in status)
-    assert any(k.startswith("trace.fv.upload.") for k in status)
+    assert any(k.startswith("trace.microbatch..device_stage.")
+               for k in status)
+
+
+def _piled_up(make, n=6):
+    """``n`` submitters of two items each, every one under a trace
+    context of its own, against a coalescer whose first flush blocks so
+    that the rest pile up. Returns (registry, coalescer, contexts)."""
+    from jubatus_tpu.utils import tracing
+
+    reg = tracing.Registry()
+    gate = threading.Event()
+
+    def flush(batch):
+        if not gate.is_set():
+            gate.set()
+            time.sleep(0.15)
+        return list(batch)
+
+    co = make(flush, reg)
+    ctxs = [tracing.new_root() for _ in range(n)]
+
+    def worker(i):
+        with tracing.use_trace(ctxs[i]):
+            co.submit([i, i])
+
+    threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+        time.sleep(0.01)
+    for t in threads:
+        t.join(30)
+    assert not any(t.is_alive() for t in threads)
+    return reg, co, ctxs
+
+
+@pytest.mark.parametrize("kind", ["single", "pipelined"])
+def test_phase_spans_per_ticket_per_turn_per_flush(kind):
+    """queue_wait and flush_wait once per ticket under the TICKET's
+    trace id (the flusher measures them on its own thread), flusher_turn
+    once per turn under the flusher's, device_stage once per flush."""
+    from jubatus_tpu.server.microbatch import PipelinedCoalescer
+
+    def make(flush, reg):
+        if kind == "single":
+            return Coalescer(flush, max_batch=8, split_results=True,
+                             trace=reg, name="q")
+        return PipelinedCoalescer(lambda items: items, flush, max_batch=8,
+                                  trace=reg, name="q")
+
+    reg, co, ctxs = _piled_up(make)
+    st = reg.trace_status()
+    flushes = co.stats()["flush_count"]
+    assert 1 < flushes < len(ctxs)          # they did pile up
+    assert st["trace.microbatch.q.queue_wait.count"] == len(ctxs)
+    assert st["trace.microbatch.q.flush_wait.count"] == len(ctxs)
+    assert st["trace.microbatch.q.device_stage.count"] == flushes
+    turns = st["trace.microbatch.q.flusher_turn.count"]
+    assert 1 <= turns <= flushes
+    waited = []
+    for ctx in ctxs:
+        spans = {r["name"]: r for r in reg.get_spans(ctx.trace_id)}
+        assert {"microbatch.q.queue_wait",
+                "microbatch.q.flush_wait"} <= set(spans)
+        assert all(r["span_id"] == ctx.span_id for r in spans.values())
+        waited.append(spans["microbatch.q.queue_wait"]["duration_ms"])
+    # the first ticket was claimed at once; those behind its 150 ms flush
+    # waited in the queue for most of it
+    assert waited[0] < 50 and max(waited) > 80
+    # a turn belongs to the submitter whose thread served as the flusher
+    turn_ids = {r["trace_id"] for r in reg.recent_spans()
+                if r["name"] == "microbatch.q.flusher_turn"}
+    assert turn_ids and turn_ids <= {c.trace_id for c in ctxs}
+    if kind == "pipelined":
+        # the device worker's thread has no request of its own
+        assert not any(r["name"] == "microbatch.q.device_stage"
+                       for r in reg.recent_spans())
+
+
+def test_flush_fill_histogram_adds_up_to_flush_count():
+    sizes = iter([1, 100, 4000, 7000, 8000, 8192, 9000])
+    co = Coalescer(lambda b: len(b), max_batch=8192,
+                   weigher=lambda item: item)
+    for n in sizes:
+        co.submit([n])
+    st = co.stats()
+    fill = [st[f"flushes_fill_{k}"] for k in range(9)]
+    assert sum(fill) == st["flush_count"] == 7
+    # floor(8 x rows / max_batch): 8,000 of 8,192 is k = 7; a full claim
+    # and an oversized lone submit are k = 8
+    assert fill == [2, 0, 0, 1, 0, 0, 1, 1, 2]
+
+
+@pytest.mark.parametrize("kind", ["single", "pipelined"])
+def test_a_registry_that_raises_costs_no_answer(kind):
+    """The per-ticket records run ahead of the tickets' events (and of
+    the device slot's release): a registry that fails must not leave a
+    submitter waiting, nor the device worker dead with the slot."""
+    from jubatus_tpu.server.microbatch import PipelinedCoalescer
+    from jubatus_tpu.utils.tracing import Registry
+
+    class Broken(Registry):
+        def record_each(self, name, records):
+            raise RuntimeError("registry on fire")
+
+    if kind == "single":
+        co = Coalescer(lambda b: len(b), max_batch=8, trace=Broken(),
+                       name="q")
+    else:
+        co = PipelinedCoalescer(lambda items: items, lambda p: len(p),
+                                max_batch=8, trace=Broken(), name="q")
+    for n in (1, 2, 3):     # three flushes: the slot came back each time
+        assert co.submit(list(range(n)), timeout=5.0) == n
+    assert co.stats()["flush_count"] == 3
 
 
 def test_pipelined_weigher_bounds_examples():

@@ -12,7 +12,7 @@ is the mechanical version — the perf trajectory's regression gate:
 Inputs may be any of the repo's bench shapes: the round envelope
 (``{"parsed": {"extra": {...}}}``), the full capture
 (``{"extra": {...}, "value": ...}``), or a flat ``{key: number}`` dict
-(bench_serving/profile_flush output) — numeric keys are flattened out of
+(bench_serving output) — numeric keys are flattened out of
 all of them.
 
 Regression direction is inferred per key:
