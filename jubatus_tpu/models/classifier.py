@@ -238,6 +238,10 @@ class ClassifierDriver(DriverBase):
                 self.state = ops.train_batch(
                     self.state, didx, dval, dslots, mask, self.param,
                     method=self.method, mode=self.train_mode)
+                if self.trace is not None and self.train_mode == "parallel":
+                    # which gather the step's shapes settled on
+                    plan = ops.gather_plan(*self.state.w.shape, idx.size)
+                    self.trace.count(f"step.train.plan_{plan}")
         return self._trained(b, bsz)
 
     def _trained(self, b: int, bsz: int) -> int:

@@ -21,6 +21,10 @@ Design (TPU-first, not a port):
   the reference's per-example online semantics (classifier_serv.cpp:137-143)
   while amortizing dispatch; gathers/scatters are XLA dynamic-slice ops on
   TPU. Padding entries (idx 0, val 0) are no-ops by construction.
+- The parallel step and the scores address the resident tables where they
+  lie (_gather_sums, _scatter_add): a step's device time follows its rows,
+  B x K, not the tables, L x D, wherever the tables are large beside the
+  batch.
 
 Update rules (margin m = s_correct - s_best_wrong, loss l = max(0, 1-m),
 x2 = ||x||^2, v = x'(Sigma_c + Sigma_w)x, parameter r/C/phi =
@@ -100,6 +104,80 @@ def grow_labels(state: ClassifierState, new_num_labels: int) -> ClassifierState:
     )
 
 
+# Table elements per gathered index above which the step gathers columns
+# where they lie instead of packing the tables first. Measured on v5e at
+# L = 8, K = 64, D = 2^25 (PERF.md section 6, PR 25): the packed copy is one
+# pass over every table (9.3 ms, 0.035 ns an element) and saves three of
+# four gathers (20 ns a descriptor each); a step on the packed plan took
+# 33.0 ms against 38.6 at B = 4096 and 24.3 against 22.8 at B = 2048, which
+# are 1024 and 2048 elements an index.
+_PACK_UNDER = 1024
+
+
+def gather_plan(num_labels: int, dim: int, n_idx: int) -> str:
+    """The plan _gather_sums takes for [num_labels, dim] tables and n_idx
+    gathered indices: "columns" or "packed". Static shapes only, so it is
+    settled when the program is traced."""
+    return "columns" if num_labels * dim > _PACK_UNDER * n_idx else "packed"
+
+
+def _gather_sums(pairs, idx):
+    """For each (master, diff) pair of [L, D] tables: (master + diff)[:, idx]
+    as [L, B, K]; idx is [B, K]. Both plans add the same two floats, so they
+    agree to the bit.
+
+    columns: gather the columns out of each table as it lies; the L label
+      rows of a column are the sublanes of one tile, so one descriptor
+      fetches them all, and nothing table-sized is made.
+    packed: stack the masters, stack the diffs, add the stacks (one pass:
+      summing each pair first was three), and fetch everything a feature
+      needs out of that [n*L, D] table with a single descriptor: gather
+      cost is per DESCRIPTOR, not per element (docs/PERF_NOTES.md). On the
+      TPU the [D, n*L] transpose is that table's own bytes.
+    """
+    num_labels, dim = pairs[0][0].shape
+    flat = idx.reshape(-1)
+    if gather_plan(num_labels, dim, flat.size) == "columns":
+        with jax.named_scope("gather"):
+            return [(jnp.take(m, flat, axis=1) + jnp.take(d, flat, axis=1))
+                    .reshape((num_labels,) + idx.shape) for m, d in pairs]
+    with jax.named_scope("pack"):
+        packed = (jnp.concatenate([m for m, _ in pairs], axis=0)
+                  + jnp.concatenate([d for _, d in pairs], axis=0)).T
+    with jax.named_scope("gather"):
+        g = jnp.take(packed, flat, axis=0).reshape(idx.shape + (-1,))
+        return [jnp.moveaxis(g[..., i * num_labels:(i + 1) * num_labels], -1, 0)
+                for i in range(len(pairs))]
+
+
+def _scatter_add_tiled(table, rows, idx, up):
+    """_scatter_add on the TPU. An f32 [L, D] array lies there in tiles of
+    8 rows x 128 columns, and XLA lowers ``table.at[rows[:, None], idx]``
+    as a scatter on the ROW-MAJOR flat array: it copied each 1 GiB table
+    into that order and back around the scatters, 65-70 ms of a 140 ms step
+    at D = 2^25 (PERF.md section 6, PR 25). Addressed in the order the
+    tiles lie in, the flat view is the table's own bytes (two bitcasts) and
+    the scatter runs in place."""
+    num_labels, dim = table.shape
+    g = 8 if num_labels % 8 == 0 else num_labels
+    c = 128 if dim % 128 == 0 else dim
+    r = rows[:, None]
+    pos = (((r // g) * (dim // c) + idx // c) * g + r % g) * c + idx % c
+    flat = table.reshape(num_labels // g, g, dim // c, c)
+    flat = flat.transpose(0, 2, 1, 3).reshape(-1).at[pos].add(up)
+    return flat.reshape(num_labels // g, dim // c, g, c).transpose(
+        0, 2, 1, 3).reshape(num_labels, dim)
+
+
+def _scatter_add(table, rows, idx, up):
+    """table[rows[n], idx[n, k]] += up[n, k], duplicates summed; table is
+    [L, D], rows [N], idx and up [N, K]. The platform is settled when the
+    program is lowered, so each gets the scatter that runs in place there."""
+    return jax.lax.platform_dependent(
+        table, rows, idx, up, tpu=_scatter_add_tiled,
+        default=lambda t, r, i, u: t.at[r[:, None], i].add(u))
+
+
 def decide_updates(s, labels, label_mask, x2, v, x2_vec, param, *, method):
     """The shared per-batch update decision — one implementation for the
     single-chip path (train_batch_parallel) and the pod path
@@ -141,19 +219,14 @@ def scores(state: ClassifierState, idx: jax.Array, val: jax.Array,
     idx/val: [B, K] hashed sparse batch; label_mask: [L] bool (live labels).
     Returns [B, L] margins with dead labels at -inf.
 
-    Layout: the gather runs over a transposed [D, L] table so one gather
-    descriptor fetches every label's weight for a feature — TPU gather
-    cost is per DESCRIPTOR, not per element (measured on v5e: a [D, 4]
-    row gather costs the same ~75 ms/2M as a [D] element gather, while L
-    separate gathers scale linearly).
+    The gather takes _gather_sums' plan: columns where the table is large
+    beside the batch, the packed [D, L] copy otherwise.
     """
     # the scope is the program's own word beside XLA's op names in a
     # device capture: metadata only
     with jax.named_scope("scores"):
-        eff = (state.w + state.dw).T  # [D, L]
-        g = jnp.take(eff, idx.reshape(-1), axis=0)       # [B*K, L]
-        g = g.reshape(idx.shape + (eff.shape[1],))       # [B, K, L]
-        s = jnp.einsum("bkl,bk->bl", g, val)
+        (g,) = _gather_sums([(state.w, state.dw)], idx)      # [L, B, K]
+        s = jnp.einsum("lbk,bk->bl", g, val)
         return jnp.where(label_mask[None, :], s, _NEG)
 
 
@@ -231,34 +304,19 @@ def train_batch_parallel(
     """
     confidence = method in CONFIDENCE_METHODS
     w, dw, prec, dprec = state
-    num_labels = w.shape[0]
 
-    # Packed-layout gather: pre-sum the master+diff planes (dense adds are
-    # bandwidth-trivial), interleave them as one [D, 2L] (or [D, L]) table,
-    # and fetch EVERYTHING each feature needs with a single descriptor.
-    # Measured on v5e (B=32k, K=64, D=2^20, AROW): the four element
-    # gathers cost ~101 ms; the packed single gather ~75 ms for the same
-    # data — gather cost is per descriptor, not per element — for a
-    # bit-exact 1.20x on the whole step (docs/PERF_NOTES.md).
     # The scopes (pack, gather, margin, scatter) are the phases' own
     # words beside XLA's op names in a device capture: metadata only.
-    with jax.named_scope("pack"):
-        eff = w + dw                                               # [L, D]
-        if confidence:
-            packed = jnp.concatenate([eff, prec + dprec], axis=0).T    # [D, 2L]
-        else:
-            packed = eff.T                                         # [D, L]
-    with jax.named_scope("gather"):
-        g = jnp.take(packed, idx.reshape(-1), axis=0)
-        g = g.reshape(idx.shape + (packed.shape[1],))              # [B, K, *]
-        eff_g = jnp.moveaxis(g[..., :num_labels], -1, 0)           # [L, B, K]
+    if confidence:                                                 # [L, B, K]
+        eff_g, p_g = _gather_sums([(w, dw), (prec, dprec)], idx)
+    else:
+        (eff_g,) = _gather_sums([(w, dw)], idx)
     with jax.named_scope("margin"):
         s = jnp.einsum("lbk,bk->bl", eff_g, val)
         x2_vec = val * val                                         # [B, K]
         x2 = jnp.sum(x2_vec, axis=1)                               # [B]
 
         if confidence:
-            p_g = jnp.moveaxis(g[..., num_labels:], -1, 0)         # [L, B, K]
             p_c = jnp.take_along_axis(p_g, labels[None, :, None], axis=0)[0]  # [B,K]
             sig_c = 1.0 / p_c
         else:
@@ -289,18 +347,20 @@ def train_batch_parallel(
         )
 
     with jax.named_scope("scatter"):
-        # NB: a single fused [2B, K] scatter (concat correct+wrong updates) was
-        # measured numerically equivalent but throughput-neutral on v5e; two
-        # plain scatters stay for simplicity
+        # the correct row's and the rival's updates go into a table as ONE
+        # scatter of [2B, K]: 11.7 ms for 2 x 524,288 updates against 2 x
+        # 7.5 ms at D = 2^25, and a flush of 2,048 rows reaches the size
+        # from which XLA sorts the updates first (24 against 95 ns each;
+        # PERF.md section 6, PR 25)
         up_c = alpha[:, None] * sig_c * val                        # [B, K]
         up_w = alpha_w[:, None] * sig_w * val
-        dw = dw.at[labels[:, None], idx].add(up_c)
-        dw = dw.at[wrong[:, None], idx].add(-up_w)
+        rows = jnp.concatenate([labels, wrong])                    # [2B]
+        idx2 = jnp.concatenate([idx, idx])                         # [2B, K]
+        dw = _scatter_add(dw, rows, idx2, jnp.concatenate([up_c, -up_w]))
         if confidence:
-            dprec = dprec.at[labels[:, None], idx].add(dp)
-            dprec = dprec.at[wrong[:, None], idx].add(
-                jnp.where((alpha_w > 0.0)[:, None], dp, 0.0)
-            )
+            dp_w = jnp.where((alpha_w > 0.0)[:, None], dp, 0.0)
+            dprec = _scatter_add(dprec, rows, idx2,
+                                 jnp.concatenate([dp, dp_w]))
     return ClassifierState(w, dw, prec, dprec)
 
 

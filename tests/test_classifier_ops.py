@@ -162,3 +162,252 @@ def test_grow_labels_preserves_model(rng):
     mask6 = jnp.concatenate([mask, jnp.array([False, False])])
     acc = accuracy(grown, idx, val, y, mask6)
     assert acc > 0.9
+
+
+# -- the parallel step, flush by flush, against the per-datum rule -----------
+AROW_CONF = {"method": "AROW", "parameter": {"regularization_weight": 1.0},
+             "converter": {"num_rules": [{"key": "*", "type": "num"}]}}
+CAP = 8
+HOT = 7        # the column every row of the "hot_column" flush hits
+
+
+def _fresh(state):
+    """A copy the step may donate."""
+    return C.ClassifierState(*(jnp.array(a) for a in state))
+
+
+def _warm_state(rng, cap, dim, method, live):
+    """A model that has learned something: masters and diffs both non-zero
+    on the ``live`` first label rows, every other row as initialised."""
+    conf = method in C.CONFIDENCE_METHODS
+    mask = jnp.asarray(np.arange(cap) < live)
+    state = C.init_state(cap, dim, conf)
+    for fold in (True, False):
+        idx = jnp.asarray(rng.integers(1, dim, size=(32, 6)), jnp.int32)
+        val = jnp.asarray(rng.normal(size=(32, 6)), jnp.float32)
+        y = jnp.asarray(rng.integers(0, live, size=32), jnp.int32)
+        state = C.train_batch_parallel(state, idx, val, y, mask, 1.0,
+                                       method=method)
+        if fold:
+            state = C.put_diff(state, C.get_diff(state))
+    return state
+
+
+def _flush(rng, scenario, dim, method):
+    """(state, idx, val, labels, mask) of one flush of the scenario."""
+    cap, live, b, k = CAP, 3, 24, 6
+    if scenario == "single_label":
+        live = 1
+    elif scenario == "ragged":          # B*K = 63: no multiple of 128, or of 8
+        b, k = 7, 9
+    elif scenario == "grown_16":
+        live = 10
+    state = _warm_state(rng, CAP, dim, method, min(live, CAP))
+    if scenario == "grown_16":
+        cap = 16
+        state = C.grow_labels(state, cap)
+        assert state.w.shape == (cap, dim)
+    idx = rng.integers(1, dim, size=(b, k)).astype(np.int32)
+    val = rng.normal(size=(b, k)).astype(np.float32)
+    labels = rng.integers(0, live, size=b).astype(np.int32)
+    if scenario == "hot_column":        # many rows of every label, one column
+        idx[:, 0] = HOT
+        labels = (np.arange(b) % live).astype(np.int32)
+    elif scenario == "padding":
+        idx[:, k // 2:] = 0
+        val[:, k // 2:] = 0.0
+    mask = jnp.asarray(np.arange(cap) < live)
+    return state, jnp.asarray(idx), jnp.asarray(val), jnp.asarray(labels), mask
+
+
+def _flush_by_the_per_datum_rule(state, idx, val, labels, mask, method):
+    """What a flush has to leave in (dw, dprec): every row decided by the
+    sequential rule against the model as the flush found it, the rows'
+    updates added up (in float64: only the order of additions is free)."""
+    conf = method in C.CONFIDENCE_METHODS
+    # masters + diffs folded, so that a row's diff IS its update, unrounded
+    snap = C.ClassifierState(
+        state.w + state.dw, jnp.zeros_like(state.dw),
+        state.prec + state.dprec, jnp.zeros_like(state.dprec))
+    dw = np.asarray(state.dw, np.float64)
+    dprec = np.asarray(state.dprec, np.float64)
+    for b in range(idx.shape[0]):
+        one = C.train_batch_sequential(
+            _fresh(snap), idx[b:b + 1], val[b:b + 1], labels[b:b + 1], mask,
+            1.0, method=method)
+        dw += np.asarray(one.dw, np.float64)
+        if conf:
+            dprec += np.asarray(one.dprec, np.float64)
+    return dw, dprec
+
+
+@pytest.mark.parametrize("dim,plan", [(1 << 10, "packed"), (1 << 16, "columns")])
+@pytest.mark.parametrize("scenario", ["hot_column", "single_label", "padding",
+                                      "grown_16", "ragged"])
+@pytest.mark.parametrize("method", C.METHODS)
+def test_a_flush_is_its_rows_by_the_per_datum_rule(method, scenario, dim, plan,
+                                                   rng):
+    state, idx, val, labels, mask = _flush(rng, scenario, dim, method)
+    assert C.gather_plan(state.w.shape[0], dim, idx.size) == plan
+    before = [np.asarray(a).copy() for a in state]
+    want_dw, want_dprec = _flush_by_the_per_datum_rule(
+        state, idx, val, labels, mask, method)
+    got = C.train_batch_parallel(_fresh(state), idx, val, labels, mask, 1.0,
+                                 method=method)
+    got = [np.asarray(a) for a in got]
+    np.testing.assert_allclose(got[1], want_dw, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(got[3], want_dprec, rtol=2e-5, atol=2e-6)
+    assert np.abs(got[1] - before[1]).max() > 0.0      # and it did learn
+    # the masters, the dead label rows and the columns no row names are
+    # the same bytes as before the step
+    dead = ~np.asarray(mask)
+    cold = np.ones(dim, bool)
+    cold[np.asarray(idx).reshape(-1)] = False
+    for was, now in zip(before, got):
+        if was.shape == (1, 1):
+            continue
+        assert now[dead].tobytes() == was[dead].tobytes()
+        assert now[:, cold].tobytes() == was[:, cold].tobytes()
+    assert got[0].tobytes() == before[0].tobytes()
+    assert got[2].tobytes() == before[2].tobytes()
+    if scenario == "padding":
+        # column 0 is the padding slot: (idx 0, val 0) entries leave it alone,
+        # and the flush without them is the same flush
+        assert got[1][:, 0].tobytes() == before[1][:, 0].tobytes()
+        k = idx.shape[1] // 2
+        narrow = C.train_batch_parallel(
+            _fresh(state), idx[:, :k], val[:, :k], labels, mask, 1.0,
+            method=method)
+        np.testing.assert_allclose(got[1], np.asarray(narrow.dw),
+                                   rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("shape", [(8, 1 << 12), (16, 1 << 10), (24, 384),
+                                   (4, 256), (8, 200), (3, 50)])
+def test_the_tpu_scatter_is_the_plain_scatter(shape, rng):
+    """_scatter_add's TPU branch addresses the table in tile order; off the
+    TPU it is still the same sum (B*K = 63, rows and columns repeated)."""
+    rows_n, dim = shape
+    table = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    rows = jnp.asarray(rng.integers(0, rows_n, size=7), jnp.int32)
+    idx = jnp.asarray(rng.integers(0, dim, size=(7, 9)), jnp.int32)
+    idx = idx.at[:, 0].set(5)
+    up = jnp.asarray(rng.normal(size=(7, 9)), jnp.float32)
+    want = table.at[rows[:, None], idx].add(up)
+    got = jax.jit(C._scatter_add_tiled)(table, rows, idx, up)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    hit = np.zeros(shape, bool)
+    hit[np.asarray(rows)[:, None], np.asarray(idx)] = True
+    assert np.asarray(got)[~hit].tobytes() == np.asarray(table)[~hit].tobytes()
+    np.testing.assert_array_equal(
+        np.asarray(C._scatter_add(table, rows, idx, up)), np.asarray(want))
+
+
+@pytest.mark.parametrize("dim,plan", [(1 << 10, "packed"), (1 << 16, "columns")])
+def test_scores_are_the_same_bits_on_either_gather_plan(dim, plan, rng):
+    state = _warm_state(rng, CAP, dim, "AROW", 3)
+    idx = jnp.asarray(rng.integers(0, dim, size=(7, 9)), jnp.int32)
+    val = jnp.asarray(rng.normal(size=(7, 9)), jnp.float32)
+    mask = jnp.asarray(np.arange(CAP) < 3)
+    assert C.gather_plan(CAP, dim, idx.size) == plan
+    got = np.asarray(C.scores(state, idx, val, mask))
+    eff = np.asarray(state.w + state.dw)                      # [L, D]
+    want = np.einsum("lbk,bk->bl", eff[:, np.asarray(idx)], np.asarray(val))
+    np.testing.assert_allclose(got[:, :3], want[:, :3], rtol=1e-5, atol=1e-6)
+    assert (got[:, 3:] == C._NEG).all()
+
+
+def test_diff_and_checkpoint_round_trip_in_the_tables_own_shape(rng):
+    """get_diff -> put_diff and pack -> unpack meet [L, D] tables, as
+    before: the step addresses them where they lie and reshapes nothing."""
+    from jubatus_tpu.models.classifier import ClassifierDriver
+
+    state = _warm_state(rng, CAP, DIM, "AROW", 3)
+    diff = C.get_diff(state)
+    assert diff["dw"].shape == diff["dprec"].shape == (CAP, DIM)
+    eff = np.asarray(state.w + state.dw)
+    eff_p = np.asarray(state.prec + state.dprec)
+    mixed = C.put_diff(state, diff)
+    np.testing.assert_array_equal(np.asarray(mixed.w), eff)
+    np.testing.assert_array_equal(np.asarray(mixed.prec), eff_p)
+    assert not np.asarray(mixed.dw).any() and not np.asarray(mixed.dprec).any()
+
+    a = ClassifierDriver(AROW_CONF, dim_bits=12)
+    b = ClassifierDriver(AROW_CONF, dim_bits=12)
+    idx = rng.integers(1, DIM, size=(20, 5)).astype(np.int32)
+    val = rng.normal(size=(20, 5)).astype(np.float32)
+    a.train_hashed([("x", "y")[i % 2] for i in range(20)], idx, val)
+    packed = a.pack()
+    assert packed["w"].shape == packed["prec"].shape == (a.capacity, DIM)
+    b.unpack(packed)
+    assert a.classify_hashed(idx, val) == b.classify_hashed(idx, val)
+
+
+@pytest.mark.parametrize("dim_bits,plan", [(10, "packed"), (16, "columns")])
+def test_a_flush_is_counted_under_the_plan_its_shapes_settled_on(dim_bits, plan,
+                                                                 rng):
+    from jubatus_tpu.models.classifier import ClassifierDriver
+    from jubatus_tpu.utils import tracing
+
+    d = ClassifierDriver(AROW_CONF, dim_bits=dim_bits)
+    d.trace = reg = tracing.Registry()
+    idx = rng.integers(1, 1 << dim_bits, size=(20, 5)).astype(np.int32)
+    val = rng.normal(size=(20, 5)).astype(np.float32)
+    for _ in range(2):
+        d.train_hashed([("x", "y")[i % 2] for i in range(20)], idx, val)
+    plans = {k: v for k, v in reg.counters().items()
+             if k.startswith("step.train.plan_")}
+    assert plans == {"step.train.plan_" + plan: 2}
+
+
+# -- the compiled programs, for the chip that is described and not attached --
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever the absent compiler raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("rows,plan", [(256, "columns"), (8192, "packed")])
+def test_no_program_relayouts_the_tables(rows, plan, one_chip):
+    """The v5e's compiler, off the chip, at D = 2^22: the step scatters into
+    the tables where they lie (no ``while`` copying a table into the
+    scatter's layout and back, nothing table-sized but the in-place
+    scatters) and the column plan makes no table-sized temporary at all.
+    The relayout came from a one-line indexing choice and can come back
+    the same way."""
+    import re
+
+    dim, k = 1 << 22, 64
+    table = CAP * dim * 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = C.ClassifierState(*[sds((CAP, dim), jnp.float32)] * 4)
+    idx, val = sds((rows, k), jnp.int32), sds((rows, k), jnp.float32)
+    labels, mask = sds((rows,), jnp.int32), sds((CAP,), jnp.bool_)
+    assert C.gather_plan(CAP, dim, rows * k) == plan
+    train = C.train_batch_parallel.lower(
+        state, idx, val, labels, mask, 1.0, method="AROW").compile()
+    scores = C.scores.lower(state, idx, val, mask).compile()
+    for name, prog, pairs in (("train", train, 2), ("scores", scores, 1)):
+        text = prog.as_text()
+        entry = text[text.index("ENTRY"):]
+        assert " while(" not in entry, name
+        relaid = [m[0] for m in re.finditer(r"= f32\[([\d,]+)\]\S* copy\(", entry)
+                  if np.prod([int(d) for d in m[1].split(",")]) >= CAP * dim]
+        assert not relaid, (name, relaid)
+        temp = prog.memory_analysis().temp_size_in_bytes
+        if plan == "columns":
+            assert temp < table // 4, (name, temp)
+        else:       # the packed copy, made in one pass, and nothing more
+            assert temp < pairs * table * 1.05, (name, temp)
+    # the four scatters run in place on the donated diffs
+    assert train.memory_analysis().alias_size_in_bytes == 4 * table
