@@ -339,8 +339,9 @@ class _ComboPlan:
     feature-name schema (which repeats across a feed's datums): slot
     names, hashed indices, gw kinds, and the bilinear terms feeding each
     slot. On a schema hit the whole string/pair stage of _apply_combos is
-    replayed as numpy gathers + multiplies over the batch — the Python
-    mirror of the native parser's combo plan (native/fast_ingest.cpp)."""
+    replayed as numpy gathers + multiplies over the batch (the native
+    parser, native/fast_ingest.cpp, keeps no plan: its pairs' hashes
+    follow from the base features' CRC states)."""
 
     __slots__ = ("slot_idx", "slot_kind", "a_idx", "b_idx", "mul_mask",
                  "t_starts", "slot_names")
@@ -616,7 +617,10 @@ class DatumToFVConverter:
         if miss_names:
             new_idx = self.hasher.index_array(miss_names)
             for p, nm, ix in zip(miss_pos, miss_names, new_idx.tolist()):
-                k = _GW_CODE[_global_weight_kind(nm)]
+                # a combined name ends in its right half's suffix, which
+                # need not be a weight's ("k$v@str#bin/bin&n@num"): what is
+                # no known weight is bin, as convert() has it
+                k = _GW_CODE.get(_global_weight_kind(nm), _GW_BIN)
                 self._memo_put(memo, nm, (ix, k))
                 idx[p] = ix
                 kind[p] = k

@@ -242,6 +242,15 @@ class ClassifierDriver(DriverBase):
                     # which gather the step's shapes settled on
                     plan = ops.gather_plan(*self.state.w.shape, idx.size)
                     self.trace.count(f"step.train.plan_{plan}")
+        if self.trace is not None:
+            # the width's side of step.train.rows / .rows_padded: entries
+            # that carry a feature, entries the rows have at the program's
+            # width, and the bytes the stage put on the device
+            self.trace.count("step.train.entries",
+                             int(np.count_nonzero(idx)))
+            self.trace.count("step.train.entries_padded", b * idx.shape[1])
+            self.trace.count("step.train.upload_bytes",
+                             idx.nbytes + val.nbytes + slots_arr.nbytes)
         return self._trained(b, bsz)
 
     def _trained(self, b: int, bsz: int) -> int:

@@ -14,11 +14,23 @@ from __future__ import annotations
 import ctypes
 import os
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 from jubatus_tpu import native as nb
+
+
+
+class Cross(NamedTuple):
+    """What a parse under combination rules hands back beside the expanded
+    rows (``parse_indexed`` / ``parse_datums`` with ``cross=True``)."""
+
+    base_idx: np.ndarray   # [B, K0] the rows before the cross product
+    base_val: np.ndarray
+    slots: int             # pair features emitted, before the merge by index
+    seconds: float         # spent in the cross product
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -37,6 +49,11 @@ class _Out(ctypes.Structure):
         ("targets", ctypes.POINTER(ctypes.c_float)),
         ("uniq", ctypes.c_int32),
         ("label_idx", ctypes.POINTER(ctypes.c_int32)),
+        ("base_width", ctypes.c_int32),
+        ("base_idx", ctypes.POINTER(ctypes.c_int32)),
+        ("base_val", ctypes.POINTER(ctypes.c_float)),
+        ("cross_slots", ctypes.c_int64),
+        ("cross_ns", ctypes.c_int64),
     ]
 
 
@@ -262,6 +279,10 @@ class IngestParser:
         self.needs_weights = any(
             ln.split("\t")[3] == "idf"
             for ln in spec.split("\n") if ln.startswith("str\t"))
+        #: the spec carries combination rules: a parse can hand back the
+        #: rows before the cross product too (``cross=True``)
+        self.combines = any(ln.startswith("combo\t")
+                            for ln in spec.split("\n"))
         #: deferred-idf mode (from_converter_config sets it for pure-idf
         #: configs): the parse emits RAW sample-weighted values — names
         #: and hashes unchanged — against zeroed df tables (idf factor
@@ -325,8 +346,9 @@ class IngestParser:
         return p
 
     @staticmethod
-    def _idx_val(out: "_Out"):
-        """Copy the [B, K] arrays out of a parse result (one place owns
+    def _idx_val(out: "_Out", base: bool = False):
+        """Copy the [B, K] arrays out of a parse result (``base``: the
+        rows before the cross product of a combination spec; one place owns
         the ctypes-extraction dance: shapes, .copy() before free, and the
         empty-batch dtype fallback). Also the native path's half of the
         ingest hardening (ISSUE 15): the C++ parser never sees the
@@ -335,10 +357,12 @@ class IngestParser:
         entries are zeroed into the padding slot (index 0 — features
         never hash there) and counted, exactly like the converter-path
         rejection."""
-        b, w = out.batch, out.width
-        idx = np.ctypeslib.as_array(out.idx, shape=(b, w)).copy() \
+        b = out.batch
+        w, pi, pv = (out.base_width, out.base_idx, out.base_val) if base \
+            else (out.width, out.idx, out.val)
+        idx = np.ctypeslib.as_array(pi, shape=(b, w)).copy() \
             if b else np.zeros((0, 8), np.int32)
-        val = np.ctypeslib.as_array(out.val, shape=(b, w)).copy() \
+        val = np.ctypeslib.as_array(pv, shape=(b, w)).copy() \
             if b else np.zeros((0, 8), np.float32)
         bad = ~np.isfinite(val)
         if bad.any():
@@ -426,8 +450,13 @@ class IngestParser:
         except Exception:  # noqa: BLE001 — any wire oddity: generic path
             return None
 
-    def parse_indexed(self, raw: bytes, weights=None):
-        """Raw train params msgpack -> (labels, idx [B,K] i32, val [B,K] f32).
+    def _cross(self, out: "_Out") -> Cross:
+        bidx, bval = self._idx_val(out, base=True)
+        return Cross(bidx, bval, int(out.cross_slots), out.cross_ns * 1e-9)
+
+    def parse_indexed(self, raw: bytes, weights=None, cross: bool = False):
+        """Raw train params msgpack -> (labels, idx [B,K] i32, val [B,K] f32),
+        and with ``cross`` (combination specs) a :class:`Cross` as fourth.
 
         ``labels`` is a float32 array for regression targets, or — for
         string labels — a ``(uniq_labels, label_idx)`` pair: the DISTINCT
@@ -484,6 +513,8 @@ class IngestParser:
                     out.label_idx, shape=(b,)).copy() if b else \
                     np.zeros(0, np.int32)
                 labels = (uniq, lidx)
+            if cross:
+                return labels, idx, val, self._cross(out)
         finally:
             self._lib.jt_ingest_free_out(ctypes.byref(out))
         return labels, idx, val
@@ -500,9 +531,10 @@ class IngestParser:
             labels = [uniq[i] for i in lidx]
         return labels, idx, val
 
-    def parse_datums(self, raw: bytes, weights=None):
+    def parse_datums(self, raw: bytes, weights=None, cross: bool = False):
         """Raw classify/estimate params msgpack ([name, [datum, ...]]) ->
-        (idx [B,K] i32, val [B,K] f32), or None when the wire shape is
+        (idx [B,K] i32, val [B,K] f32), with ``cross`` (combination specs)
+        a :class:`Cross` as third, or None when the wire shape is
         not a datum list. For idf specs, ``weights`` is read (NOT
         observed — queries never record documents; caller holds the
         lock)."""
@@ -527,6 +559,8 @@ class IngestParser:
         if rc != 0:
             return None
         try:
+            if cross:
+                return self._idx_val(out) + (self._cross(out),)
             return self._idx_val(out)
         finally:
             self._lib.jt_ingest_free_out(ctypes.byref(out))

@@ -140,16 +140,18 @@ def _updating(server: Any, fn: Callable, count: Callable[[Any], int] = lambda r:
 
 class _ComboPlanCache:
     """Device-expansion plans for combination-rule configs, keyed by the
-    base index row (the feature schema). The C++ base parser ships only
-    the [B, K0] base columns; the plan carries the full base+slot index
-    vector and the (a, b, op) bilinear terms the device expands
-    (ops._expand_combo). Slot hashes and pair structure come from the
-    Python converter's own combo plan (core/fv/converter.py) — the
-    single owner of combination semantics — validated against the C++
-    row by hashing a sample datum's base names. Schemas the plan cannot
-    serve exactly (hash collisions, idf/user weights, multi-term slots)
-    are declined and the request falls back to the generic
-    batch-converter path with identical semantics."""
+    base index row (the feature schema). The native parser hands back the
+    [B, K0] rows before the cross product beside the expanded ones
+    (native/ingest.py ``Cross``); where every row of a request shares its
+    base index row, the plan carries the full base+slot index vector and
+    the (a, b, op) bilinear terms the device expands (ops._expand_combo),
+    and only the base columns are shipped. Slot hashes and pair structure
+    come from the Python converter's own combo plan
+    (core/fv/converter.py) — the single owner of combination semantics —
+    validated against the C++ row by hashing a sample datum's base names.
+    Requests the plan cannot serve exactly (rows of differing schemas,
+    hash collisions, idf/user weights, multi-term slots) keep the rows the
+    same parse expanded on the host: nothing is parsed twice."""
 
     _MISS = object()
 
@@ -162,22 +164,19 @@ class _ComboPlanCache:
             self.b_idx = b_idx
             self.mul_mask = mul_mask
 
-    def __init__(self, conv: dict, converter) -> None:
-        self._conv = conv or {}
+    def __init__(self, converter) -> None:
         self._converter = converter  # the driver's full converter
         self._plans: Dict[bytes, Any] = {}
 
-    def make_base_parser(self, dim_bits: int):
-        """The C++ parser for the config SANS combination rules (base
-        features only); None when that subset is not native-expressible."""
-        from jubatus_tpu.native.ingest import IngestParser
-
-        base_conv = {k: v for k, v in self._conv.items()
-                     if k != "combination_rules"}
-        try:
-            return IngestParser.from_converter_config(base_conv, dim_bits)
-        except Exception:  # broad-ok — plan mode is strictly optional
+    def plan_for(self, base_idx, raw_params: bytes, with_labels: bool):
+        """The plan for a request's rows before the cross product, or None
+        where they do not share one index row or no exact plan exists."""
+        if base_idx.shape[0] == 0:
             return None
+        row0 = base_idx[0]
+        if base_idx.shape[0] > 1 and not (base_idx == row0).all():
+            return None  # mixed schemas in one request
+        return self._plan_for(row0, raw_params, with_labels)
 
     def _plan_for(self, row0, raw_params: bytes, with_labels: bool):
         key = row0.tobytes()
@@ -227,46 +226,6 @@ class _ComboPlanCache:
         uidx = np.concatenate([row0.astype(np.int32), cplan.slot_idx])
         return self.Plan(uidx, cplan.a_idx, cplan.b_idx,
                          cplan.mul_mask.astype(bool))
-
-    def parse_train(self, base_parser, raw_params: bytes):
-        """Raw train params -> a coalescer item riding the device-
-        expansion plan, or RAW_FALLBACK (generic path, same semantics)."""
-        from jubatus_tpu.rpc.server import RAW_FALLBACK
-
-        parsed = base_parser.parse_indexed(raw_params)
-        if parsed is None:
-            return RAW_FALLBACK
-        labels, idx, val = parsed
-        if isinstance(labels, np.ndarray):
-            return RAW_FALLBACK  # numeric labels on a classifier wire
-        b = idx.shape[0]
-        if b == 0:
-            return RAW_FALLBACK
-        row0 = idx[0]
-        if b > 1 and not (idx == row0).all():
-            return RAW_FALLBACK  # mixed schemas in one request
-        plan = self._plan_for(row0, raw_params, with_labels=True)
-        if plan is None:
-            return RAW_FALLBACK
-        return (("combo", plan), labels, idx, val)
-
-    def parse_query(self, base_parser, raw_params: bytes):
-        """Raw datum-list params -> (plan, base_val) or RAW_FALLBACK."""
-        from jubatus_tpu.rpc.server import RAW_FALLBACK
-
-        parsed = base_parser.parse_datums(raw_params)
-        if parsed is None:
-            return RAW_FALLBACK
-        idx, val = parsed
-        if idx.shape[0] == 0:
-            return (None, val)
-        row0 = idx[0]
-        if idx.shape[0] > 1 and not (idx == row0).all():
-            return RAW_FALLBACK
-        plan = self._plan_for(row0, raw_params, with_labels=False)
-        if plan is None:
-            return RAW_FALLBACK
-        return (plan, val)
 
 
 def _quality_observe_pairs(server: Any, pairs) -> None:
@@ -387,11 +346,16 @@ def _register_train(rpc: RpcServer, server: Any, decode_pair,
 
     # -t 0 conventionally means "no timeout" — map to an unbounded wait
     wait_s = server.args.timeout * 6 if server.args.timeout > 0 else None
+    combines = bool(driver.converter.config.combination_rules)
 
     def train(name, data):
         pairs = [decode_pair(p) for p in data]
         if not pairs:
             return 0
+        if combines:
+            # the cross product in the Python converter: what the native
+            # parser declined, or a server without it
+            rpc.trace.count("fv.combine.generic")
         # test-then-train: prequential scoring sees the pre-update model
         _quality_observe_pairs(server, pairs)
         co.submit(pairs, timeout=wait_s)
@@ -475,19 +439,28 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
     weights = driver.converter.weights \
         if (parser.needs_weights or deferred) else None
 
-    # combo device plan (classifier combo configs): parse only the BASE
-    # features in C++, expand the cross product ON DEVICE
-    # (ops.train_batch_schema_combo) — the (K0+S)-wide row never crosses
-    # the host/device wire. None when ineligible; requests the plan
-    # cannot serve fall back to the generic batch-converter path.
+    # combination configs: the parser expands the cross product on the
+    # host and hands back the base rows too, so one parse serves whichever
+    # plan the request's rows allow. Uniform-schema requests (classifier)
+    # ship only the base columns and expand ON DEVICE
+    # (ops.train_batch_schema_combo); all others keep the expanded rows.
     combo_ctx = None
-    if combo_train is not None and not numeric \
-            and (conv or {}).get("combination_rules"):
-        combo_ctx = _ComboPlanCache(conv, driver.converter)
-        base_parser = combo_ctx.make_base_parser(
-            driver.converter.hasher.dim_bits)
-        if base_parser is None:
-            combo_ctx = None
+    if parser.combines and combo_train is not None and not numeric:
+        combo_ctx = _ComboPlanCache(driver.converter)
+    trace = rpc.trace
+
+    def _crossed(cross, rows: int, raw_params: bytes, with_labels: bool):
+        """Account for one combination request's cross product (span
+        ``fv.combine`` is the parser's own clock around it) and return its
+        device-expansion plan, or None where the host's rows are used."""
+        trace.record("fv.combine", cross.seconds)
+        trace.count("fv.combine.rows", rows)
+        trace.count("fv.combine.slots", cross.slots)
+        plan = combo_ctx.plan_for(cross.base_idx, raw_params, with_labels) \
+            if combo_ctx is not None else None
+        trace.count("fv.combine.native" if plan is None
+                    else "fv.combine.device")
+        return plan
 
     def _merge_labels(label_pairs):
         """Union per-request (uniq_labels, label_idx) pairs into one
@@ -511,12 +484,14 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
         previous batch."""
         if not reqs:
             return None
-        if reqs[0][0][0] == "combo":
+        combo = [r for r in reqs if r[0][0] == "combo"]
+        if combo:
             # (("combo", plan), labels, base_idx, base_val): group by
             # plan (one group for a fixed-schema feed) for the
-            # device-expansion path
+            # device-expansion path; requests of the same flush that keep
+            # their host-expanded rows follow as a batch of their own
             groups: dict = {}
-            for tag, lb, _ir, vr in reqs:
+            for tag, lb, _ir, vr in combo:
                 entry = groups.setdefault(id(tag[1]), (tag[1], [], []))
                 entry[1].append(lb)
                 entry[2].append(vr)
@@ -526,7 +501,8 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
                 val = np.concatenate(vals) if len(vals) > 1 else vals[0]
                 out.append((uniq, lidx, plan, val))
             stats["combo_flushes"] += 1
-            return ("combo", out)
+            return ("combo", out, prep_requests(
+                [r for r in reqs if r[0][0] != "combo"]))
         if numeric:
             idx, val = _pad_concat([(ir, vr) for _t, _lb, ir, vr in reqs])
             labels = np.concatenate([r[1] for r in reqs]) \
@@ -555,7 +531,7 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
             return 0
         kind = prepared[0]
         if kind == "combo":
-            n = 0
+            n = apply_prepared(prepared[2])
             for uniq, lidx, plan, val in prepared[1]:
                 n += combo_train(uniq, lidx, plan.uidx, val,
                                  plan.a_idx, plan.b_idx, plan.mul_mask)
@@ -582,14 +558,14 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
             name="train_raw")
         server.coalescers["train_raw"] = co
         co.usage_hook = _usage_batch_hook(server, "train")
-    trace = rpc.trace
 
     def train_raw(raw_params: bytes):
+        cross = None
         with trace.span("fv.convert"):
-            if combo_ctx is not None:
-                item = combo_ctx.parse_train(base_parser, raw_params)
-                if item is RAW_FALLBACK:
-                    return RAW_FALLBACK  # generic batch-converter path
+            if parser.combines:
+                parsed = parser.parse_indexed(raw_params, cross=True)
+                if parsed is not None:
+                    cross, parsed = parsed[3], parsed[:3]
             elif weights is not None and not deferred:
                 with weights.lock:
                     parsed = parser.parse_indexed(raw_params,
@@ -597,14 +573,18 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
             else:
                 # deferred idf / unweighted: lock-free parallel parse
                 parsed = parser.parse_indexed(raw_params)
-        if combo_ctx is None:
-            if parsed is None:
-                return RAW_FALLBACK
-            labels, idx, val = parsed
-            if numeric != isinstance(labels, np.ndarray):
-                return RAW_FALLBACK  # label kind mismatch: let the
-                # generic path produce the proper type error
-            item = (("plain",), labels, idx, val)
+        if parsed is None:
+            return RAW_FALLBACK
+        labels, idx, val = parsed
+        if numeric != isinstance(labels, np.ndarray):
+            return RAW_FALLBACK  # label kind mismatch: let the
+            # generic path produce the proper type error
+        item = (("plain",), labels, idx, val)
+        if cross is not None and idx.shape[0]:
+            plan = _crossed(cross, idx.shape[0], raw_params, True)
+            if plan is not None:
+                item = (("combo", plan), labels, cross.base_idx,
+                        cross.base_val)
         n = item[2].shape[0]
         if n == 0:
             return 0
@@ -680,58 +660,54 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
         qco.usage_hook = _usage_batch_hook(
             server, name[:-4] if name.endswith("_raw") else name)
 
+        def scored(idx, val):
+            (mine,) = qco.submit([(idx, val)], timeout=wait_s)
+            return mine
+
+        return scored
+
+    def _raw_query(scored, cross_scored=None):
+        """The raw handler of a read method: parse, then ``scored(idx,
+        val)`` (a query coalescer, or the driver where coalescing is off).
+        ``cross_scored(plan, base_val)``: what a combination request scores
+        with where its rows allow the device-expansion plan (plans exist
+        for the classifier alone: combo_ctx)."""
         def raw_handler(raw_params: bytes):
+            cross = None
             with trace.span("fv.convert"):
-                parsed = _parse_datums(raw_params)
+                if parser.combines:
+                    parsed = parser.parse_datums(raw_params, cross=True)
+                    if parsed is not None:
+                        cross, parsed = parsed[2], parsed[:2]
+                else:
+                    parsed = _parse_datums(raw_params)
             if parsed is None:
                 return RAW_FALLBACK
             idx, val = parsed
             if idx.shape[0] == 0:
                 return []
-            (mine,) = qco.submit([(idx, val)], timeout=wait_s)
-            return mine
+            if cross is not None:
+                plan = _crossed(cross, idx.shape[0], raw_params, False)
+                if plan is not None:
+                    return cross_scored(plan, cross.base_val)
+            return scored(idx, val)
 
         return raw_handler
 
     if numeric and hasattr(driver, "estimate_hashed"):
-        if max_batch:
-            rpc.register_raw("estimate", _query_coalescer(
-                "estimate_raw", driver.estimate_hashed))
-        else:
-            def estimate_raw(raw_params: bytes):
-                parsed = _parse_datums(raw_params)
-                if parsed is None:
-                    return RAW_FALLBACK
-                return driver.estimate_hashed(*parsed)
-
-            rpc.register_raw("estimate", estimate_raw)
-    elif combo_ctx is not None and hasattr(driver, "classify_hashed_combo"):
-        def classify_combo_raw(raw_params: bytes):
-            with trace.span("fv.convert"):
-                out = combo_ctx.parse_query(base_parser, raw_params)
-            if out is RAW_FALLBACK:
-                return RAW_FALLBACK
-            plan, val = out
-            if plan is None:
-                return []
-            return driver.classify_hashed_combo(
-                plan.uidx, val, plan.a_idx, plan.b_idx, plan.mul_mask)
-
-        rpc.register_raw("classify", classify_combo_raw)
+        rpc.register_raw("estimate", _raw_query(
+            _query_coalescer("estimate_raw", driver.estimate_hashed)
+            if max_batch else driver.estimate_hashed))
     elif not numeric and hasattr(driver, "classify_hashed"):
-        if max_batch:
-            schema_cls = getattr(driver, "classify_hashed_schema", None)
-            rpc.register_raw("classify", _query_coalescer(
-                "classify_raw", driver.classify_hashed,
-                schema_score=schema_cls))
-        else:
-            def classify_raw(raw_params: bytes):
-                parsed = _parse_datums(raw_params)
-                if parsed is None:
-                    return RAW_FALLBACK
-                return driver.classify_hashed(*parsed)
+        def cross_scored(plan, base_val):
+            return driver.classify_hashed_combo(
+                plan.uidx, base_val, plan.a_idx, plan.b_idx, plan.mul_mask)
 
-            rpc.register_raw("classify", classify_raw)
+        rpc.register_raw("classify", _raw_query(
+            _query_coalescer("classify_raw", driver.classify_hashed,
+                             schema_score=getattr(
+                                 driver, "classify_hashed_schema", None))
+            if max_batch else driver.classify_hashed, cross_scored))
 
 
 @_binder("classifier")

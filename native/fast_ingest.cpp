@@ -16,7 +16,9 @@
 // back to the Python converter otherwise): num rules {num, log, str},
 // num filters, string rules with {str, space, ngram} splitters,
 // sample_weight {bin, tf, log_tf}, global_weight {bin, idf}, and
-// combination rules (mul/add; not combinable with idf); no string
+// combination rules (mul/add; not combinable with idf; the pairs' hashes
+// come from the base features' CRC states, see CrcShift, and the rows
+// before the cross product are handed back too); no string
 // filters, no "weight" global weight, no plugins.
 // Semantics mirror core/fv/converter.py: feature names
 //   "<key>@<type>"                      (num/log)
@@ -40,6 +42,7 @@
 
 #include <algorithm>
 #include <charconv>
+#include <chrono>
 #include <cmath>
 #include <cstdint>
 #include <cstdio>
@@ -89,6 +92,39 @@ inline uint32_t crc32_update(uint32_t c, const uint8_t* p, size_t n) {
   for (size_t i = 0; i < n; ++i) c = kCrc.t[(c ^ p[i]) & 0xFF] ^ (c >> 8);
   return c;
 }
+
+// The CRC register is linear over GF(2): running n bytes B from register s
+// gives shift_n(s) ^ run(0, B), where shift_n runs n zero bytes. So the
+// hash of "<a>&<b>" follows from the register after "<a>&" and what is
+// known of <b> alone (its own register and its length) with no string
+// built: four table reads per pair, whatever the names' length.
+struct CrcShift {
+  size_t len = 0;
+  uint32_t t[4][256];
+  uint32_t of_init = 0;  // shift_len(0xFFFFFFFF)
+
+  explicit CrcShift(size_t n) : len(n) {
+    uint32_t col[32];
+    for (int k = 0; k < 32; ++k) {
+      uint32_t c = 1u << k;
+      for (size_t i = 0; i < n; ++i) c = kCrc.t[c & 0xFF] ^ (c >> 8);
+      col[k] = c;
+    }
+    for (int j = 0; j < 4; ++j) {
+      t[j][0] = 0;
+      for (uint32_t v = 1; v < 256; ++v) {
+        int low = __builtin_ctz(v);
+        t[j][v] = t[j][v & (v - 1)] ^ col[8 * j + low];
+      }
+    }
+    of_init = (*this)(0xFFFFFFFFu);
+  }
+
+  uint32_t operator()(uint32_t x) const {
+    return t[0][x & 0xFF] ^ t[1][(x >> 8) & 0xFF] ^ t[2][(x >> 16) & 0xFF] ^
+           t[3][x >> 24];
+  }
+};
 
 // ---- key matchers: "*", "prefix*", "*suffix", exact --------------------
 struct Matcher {
@@ -584,6 +620,14 @@ struct JtIngestOut {
   float* targets;      // [batch] numeric targets (regression train)
   int32_t uniq;        // distinct labels in labels/label_off
   int32_t* label_idx;  // [batch] row -> distinct-label index
+  // combination specs only (null / 0 otherwise): the rows BEFORE the
+  // cross product, packed like idx/val, so that one parse serves both the
+  // host expansion and the device expansion of a uniform-schema batch
+  int32_t base_width;
+  int32_t* base_idx;   // [batch, base_width]
+  float* base_val;     // [batch, base_width]
+  int64_t cross_slots;  // pair features emitted, before the merge by index
+  int64_t cross_ns;     // nanoseconds spent in the cross product
 };
 
 void* jt_ingest_create(const char* spec) {
@@ -724,6 +768,10 @@ void jt_ingest_free_out(JtIngestOut* out) {
   free(out->label_off);
   free(out->targets);
   free(out->label_idx);
+  free(out->base_idx);
+  free(out->base_val);
+  out->base_idx = nullptr;
+  out->base_val = nullptr;
   out->idx = nullptr;
   out->val = nullptr;
   out->labels = nullptr;
@@ -803,35 +851,28 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   std::vector<PosEntry> poscache;
   size_t pos_stride = 0;  // kv slots per rule; grows to max nnv seen
 
-  // combo mode: features accumulate by NAME first (converter.py
-  // _named_features dict), the combination cross product runs over that
-  // map, and only then is everything hashed. The term/pos memos are
-  // bypassed (they exist to skip name assembly, which combos need).
+  // combo mode: the BASE features accumulate by NAME first (converter.py
+  // _named_features dict) and the combination cross product runs over
+  // that map. The term/pos memos are bypassed (they exist to skip name
+  // assembly, which combos need). The cross product itself builds no name
+  // (CrcShift) and keeps no map: two features of one name have one hash,
+  // so the merge by index below adds them as the dict would.
   const bool combo_mode = !ps.combos.empty();
   std::vector<std::pair<std::string, double>> named;  // insertion order
   std::unordered_map<std::string, size_t> named_ix;
-
-  // combo plan (round 5, VERDICT r4 #3): the cross product's pair
-  // structure, names and hashes are a pure function of the BASE
-  // feature-name schema, which repeats across a feed's datums (fixed
-  // key schemas are the production shape). On a schema hit the whole
-  // name-assembly + map + crc32 stage is replayed as (slot -> hashed
-  // idx, bilinear terms over base positions): per datum only the
-  // multiplies/adds and feature pushes remain. Per parse call (one
-  // request) like the term/pos memos, so thread-safety is free.
-  struct ComboTerm {
-    int32_t a, b;
-    uint8_t op;  // 1 mul, 2 add
+  std::vector<Feature> bfeats;        // the rows before the cross product
+  std::vector<int64_t> boffsets(1, 0);
+  std::deque<CrcShift> shifts;        // one per distinct name length
+  struct Base {
+    const std::string* name;
+    double val;
+    uint32_t after_amp;  // register after "<name>&"
+    uint32_t from_zero;  // register after <name>, started from 0
+    const CrcShift* shift;
   };
-  struct ComboPlan {
-    bool valid = false;
-    size_t base_n = 0;
-    std::vector<std::string> base_names;
-    std::vector<int32_t> slot_idx;   // hashed index per output slot
-    std::vector<uint32_t> t_off;     // terms span per slot (slots + 1)
-    std::vector<ComboTerm> terms;
-  } combo_plan;
-  std::vector<std::vector<ComboTerm>> slot_terms;  // recording scratch
+  std::vector<Base> base;  // one example's base features, in name order
+  std::vector<uint8_t> lmatch, rmatch;
+  int64_t cross_slots = 0, cross_ns = 0;
 
   auto add_named = [&](const std::string& nm, double v) {
     auto it = named_ix.find(nm);
@@ -858,6 +899,24 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
       add_named(nm, v);  // idf+combos declined at create
     else
       hash_push(nm, v, idf);
+  };
+
+  // sort one example's features by index and add those that share one
+  auto merge_row = [](std::vector<Feature>& fs, std::vector<int64_t>& offs) {
+    size_t start = size_t(offs.back());
+    std::sort(fs.begin() + offs.back(), fs.end(),
+              [](const Feature& a, const Feature& b) { return a.idx < b.idx; });
+    size_t w = start;
+    for (size_t rdi = start; rdi < fs.size(); ++rdi) {
+      if (w > start && fs[rdi].idx == fs[w - 1].idx) {
+        fs[w - 1].val += fs[rdi].val;
+      } else {
+        fs[w] = fs[rdi];
+        ++w;
+      }
+    }
+    fs.resize(w);
+    offs.push_back(int64_t(fs.size()));
   };
 
   for (int64_t e = 0; e < n; ++e) {
@@ -1128,109 +1187,70 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
       }
     }
 
-    // combinations (converter.py:412-432): cross product over the BASE
-    // named-feature snapshot, each unordered pair once per rule in
-    // canonical (bytewise == codepoint) name order, "<a>&<b>", values
-    // accumulating into the same name map; then hash everything
+    // combinations (converter.py _apply_combos): every unordered pair of
+    // the BASE named features once per rule, in canonical (bytewise ==
+    // codepoint) name order, "<a>&<b>", mul/add of the base values
     if (combo_mode) {
+      auto t_cross = std::chrono::steady_clock::now();
       size_t base_n = named.size();
-      bool plan_hit =
-          combo_plan.valid && combo_plan.base_n == base_n;
-      if (plan_hit) {
-        for (size_t i2 = 0; i2 < base_n; ++i2) {
-          if (named[i2].first != combo_plan.base_names[i2]) {
-            plan_hit = false;
-            break;
+      base.resize(base_n);
+      for (size_t i2 = 0; i2 < base_n; ++i2) {
+        const std::string& nm = named[i2].first;
+        uint32_t reg = crc32_update(
+            0xFFFFFFFFu, reinterpret_cast<const uint8_t*>(nm.data()),
+            nm.size());
+        uint32_t i = (reg ^ 0xFFFFFFFFu) & mask;
+        feats.push_back({int32_t(i ? i : 1), named[i2].second, 0});
+        const CrcShift* sh = nullptr;
+        for (const CrcShift& c : shifts)
+          if (c.len == nm.size()) sh = &c;
+        if (sh == nullptr) {
+          shifts.emplace_back(nm.size());
+          sh = &shifts.back();
+        }
+        const uint8_t amp = '&';
+        base[i2] = {&nm, named[i2].second, crc32_update(reg, &amp, 1),
+                    reg ^ sh->of_init, sh};
+      }
+      bfeats.insert(bfeats.end(), feats.end() - base_n, feats.end());
+      std::sort(base.begin(), base.end(), [](const Base& x, const Base& y) {
+        return *x.name < *y.name;
+      });
+      for (const ComboRule& cr : ps.combos) {
+        bool all = cr.left.kind == Matcher::ALL &&
+                   cr.right.kind == Matcher::ALL;
+        if (!all) {
+          lmatch.resize(base_n);
+          rmatch.resize(base_n);
+          for (size_t oi = 0; oi < base_n; ++oi) {
+            const std::string& nm = *base[oi].name;
+            const uint8_t* p2 = reinterpret_cast<const uint8_t*>(nm.data());
+            lmatch[oi] = cr.left.match(p2, nm.size());
+            rmatch[oi] = cr.right.match(p2, nm.size());
+          }
+        }
+        const bool mul = cr.op == ComboRule::MUL;
+        for (size_t a = 0; a + 1 < base_n; ++a) {
+          const uint32_t pre = base[a].after_amp;
+          const double va = base[a].val;
+          for (size_t b = a + 1; b < base_n; ++b) {
+            // once per unordered pair per rule, whichever side matched
+            // which matcher (the Python loop's seen-set)
+            if (!all && !((lmatch[a] && rmatch[b]) ||
+                          (lmatch[b] && rmatch[a])))
+              continue;
+            const Base& sb = base[b];
+            uint32_t reg = (*sb.shift)(pre) ^ sb.from_zero;
+            uint32_t i = (reg ^ 0xFFFFFFFFu) & mask;
+            feats.push_back({int32_t(i ? i : 1),
+                             mul ? va * sb.val : va + sb.val, 0});
+            ++cross_slots;
           }
         }
       }
-      if (plan_hit) {
-        // replay: no strings, no maps, no crc32 — just the bilinear
-        // terms over this example's base values
-        size_t nslots = combo_plan.slot_idx.size();
-        for (size_t j = 0; j < nslots; ++j) {
-          double v = j < combo_plan.base_n ? named[j].second : 0.0;
-          for (uint32_t t = combo_plan.t_off[j];
-               t < combo_plan.t_off[j + 1]; ++t) {
-            const ComboTerm& tm = combo_plan.terms[t];
-            v += tm.op == 1 ? named[tm.a].second * named[tm.b].second
-                            : named[tm.a].second + named[tm.b].second;
-          }
-          feats.push_back({combo_plan.slot_idx[j], v, 0});
-        }
-      } else {
-        // slow pass — and record the plan for the rest of the request.
-        // frozen base values (Python's `base = list(features.items())`
-        // snapshot): a combined name colliding with a base name must
-        // not change later pairs' inputs
-        slot_terms.assign(base_n, {});
-        std::vector<double> base_val(base_n);
-        for (size_t i2 = 0; i2 < base_n; ++i2)
-          base_val[i2] = named[i2].second;
-        std::string cname;
-        for (const ComboRule& cr : ps.combos) {
-          auto lm = [&](size_t i2) {
-            const std::string& s2 = named[i2].first;
-            return cr.left.match(
-                reinterpret_cast<const uint8_t*>(s2.data()), s2.size());
-          };
-          auto rm = [&](size_t i2) {
-            const std::string& s2 = named[i2].first;
-            return cr.right.match(
-                reinterpret_cast<const uint8_t*>(s2.data()), s2.size());
-          };
-          for (size_t li = 0; li < base_n; ++li) {
-            if (!lm(li)) continue;
-            for (size_t ri = 0; ri < base_n; ++ri) {
-              if (li == ri || !rm(ri)) continue;
-              // once per unordered pair per rule WITHOUT a seen-set (an
-              // allocating tree insert per candidate pair would dominate
-              // the hot path): each pair is visited at most twice; emit
-              // on the canonical visit, or on either visit when the
-              // mirror does not qualify. Values are symmetric (mul/add).
-              if (li > ri && lm(ri) && rm(li)) continue;
-              double cval = cr.op == ComboRule::MUL
-                                ? base_val[li] * base_val[ri]
-                                : base_val[li] + base_val[ri];
-              size_t a = li, b = ri;
-              if (named[b].first < named[a].first) std::swap(a, b);
-              cname = named[a].first;
-              cname += '&';
-              cname += named[b].first;
-              // add_named + record which (a, b, op) fed which slot
-              size_t s;
-              auto it = named_ix.find(cname);
-              if (it == named_ix.end()) {
-                s = named.size();
-                named_ix.emplace(cname, s);
-                named.push_back({cname, cval});
-                slot_terms.emplace_back();
-              } else {
-                s = it->second;
-                named[s].second += cval;
-              }
-              slot_terms[s].push_back(
-                  {int32_t(li), int32_t(ri),
-                   uint8_t(cr.op == ComboRule::MUL ? 1 : 2)});
-            }
-          }
-        }
-        combo_plan.valid = true;
-        combo_plan.base_n = base_n;
-        combo_plan.base_names.assign(base_n, std::string());
-        for (size_t i2 = 0; i2 < base_n; ++i2)
-          combo_plan.base_names[i2] = named[i2].first;
-        combo_plan.slot_idx.clear();
-        combo_plan.terms.clear();
-        combo_plan.t_off.assign(1, 0);
-        for (size_t j = 0; j < named.size(); ++j) {
-          hash_push(named[j].first, named[j].second, false);
-          combo_plan.slot_idx.push_back(feats.back().idx);
-          for (const ComboTerm& tm : slot_terms[j])
-            combo_plan.terms.push_back(tm);
-          combo_plan.t_off.push_back(uint32_t(combo_plan.terms.size()));
-        }
-      }
+      cross_ns += std::chrono::duration_cast<std::chrono::nanoseconds>(
+                      std::chrono::steady_clock::now() - t_cross)
+                      .count();
     }
 
     // idf (converter.py convert(): observe distinct indices, then scale,
@@ -1266,44 +1286,50 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
     }
 
     // per-example: sort by index, merge duplicates (convert() semantics)
-    auto begin = feats.begin() + offsets.back();
-    std::sort(begin, feats.end(),
-              [](const Feature& a, const Feature& b) { return a.idx < b.idx; });
-    size_t start = size_t(offsets.back());
-    size_t w = start;
-    for (size_t rdi = start; rdi < feats.size(); ++rdi) {
-      if (w > start && feats[rdi].idx == feats[w - 1].idx) {
-        feats[w - 1].val += feats[rdi].val;
-      } else {
-        feats[w] = feats[rdi];
-        ++w;
-      }
-    }
-    feats.resize(w);
-    offsets.push_back(int64_t(feats.size()));
+    merge_row(feats, offsets);
+    if (combo_mode) merge_row(bfeats, boffsets);
   }
   if (rd.fail) return 1;
 
   // pack to [batch, width] with the SparseBatch width bucket (pow2, >= 8)
-  int64_t max_nnz = 1;
-  for (size_t e = 0; e + 1 < offsets.size(); ++e)
-    max_nnz = std::max(max_nnz, offsets[e + 1] - offsets[e]);
-  int32_t width = 8;
-  while (width < max_nnz) width *= 2;
+  auto pack = [n](const std::vector<Feature>& fs,
+                  const std::vector<int64_t>& offs, int32_t* width,
+                  int32_t** idx, float** val) {
+    int64_t max_nnz = 1;
+    for (size_t e = 0; e + 1 < offs.size(); ++e)
+      max_nnz = std::max(max_nnz, offs[e + 1] - offs[e]);
+    int32_t w = 8;
+    while (w < max_nnz) w *= 2;
+    *width = w;
+    *idx = static_cast<int32_t*>(calloc(size_t(n) * w, 4));
+    *val = static_cast<float*>(calloc(size_t(n) * w, 4));
+    if (!*idx || !*val) return false;
+    for (int64_t e = 0; e < n; ++e) {
+      int64_t s = offs[e], cnt = offs[e + 1] - offs[e];
+      for (int64_t j = 0; j < cnt; ++j) {
+        (*idx)[e * w + j] = fs[size_t(s + j)].idx;
+        (*val)[e * w + j] = float(fs[size_t(s + j)].val);
+      }
+    }
+    return true;
+  };
 
   size_t uniq = uniq_spans.size();
   out->batch = int32_t(n);
-  out->width = width;
   out->labels_numeric = labels_numeric == 1 ? 1 : 0;
   out->uniq = int32_t(uniq);
-  out->idx = static_cast<int32_t*>(calloc(size_t(n) * width, 4));
-  out->val = static_cast<float*>(calloc(size_t(n) * width, 4));
+  out->cross_slots = cross_slots;
+  out->cross_ns = cross_ns;
+  bool packed = pack(feats, offsets, &out->width, &out->idx, &out->val);
+  if (packed && combo_mode)
+    packed = pack(bfeats, boffsets, &out->base_width, &out->base_idx,
+                  &out->base_val);
   out->labels = static_cast<uint8_t*>(malloc(labels.size() ? labels.size() : 1));
   out->label_off = static_cast<int32_t*>(malloc((uniq + 1) * 4));
   out->targets = static_cast<float*>(malloc((size_t(n) + 1) * 4));
   out->label_idx = static_cast<int32_t*>(malloc((size_t(n) + 1) * 4));
-  if (!out->idx || !out->val || !out->labels || !out->label_off ||
-      !out->targets || !out->label_idx) {
+  if (!packed || !out->labels || !out->label_off || !out->targets ||
+      !out->label_idx) {
     jt_ingest_free_out(out);
     return 2;
   }
@@ -1314,13 +1340,6 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   } else {
     memcpy(out->label_off, label_off.data(), (uniq + 1) * 4);
     memcpy(out->label_idx, label_idx.data(), label_idx.size() * 4);
-  }
-  for (int64_t e = 0; e < n; ++e) {
-    int64_t s = offsets[e], cnt = offsets[e + 1] - offsets[e];
-    for (int64_t j = 0; j < cnt; ++j) {
-      out->idx[e * width + j] = feats[size_t(s + j)].idx;
-      out->val[e * width + j] = float(feats[size_t(s + j)].val);
-    }
   }
   return 0;
 }
