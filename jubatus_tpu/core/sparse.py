@@ -8,8 +8,11 @@ dense arrays (indices, values) padded to a common nnz. Padding entries carry
 value 0.0 so they are no-ops in every kernel (gathers multiply by 0, scatter
 adds add 0).
 
-Pad widths are bucketed to powers of two so XLA recompiles O(log max_nnz)
-times, not per batch shape.
+Pad widths follow the rows: the widest row is rounded up to a rung of an
+eighth-octave ladder (``_width_bucket``: at most an eighth of padding, 8
+rungs per doubling), so XLA compiles O(log max_nnz) programs, not one per
+batch shape, and a 39-feature row runs at 40, not 64. Row counts are
+bucketed to powers of two (``_bucket``).
 """
 
 from __future__ import annotations
@@ -27,6 +30,22 @@ def _bucket(n: int, minimum: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _width_bucket(n: int, minimum: int = 8) -> int:
+    """The width a row of ``n`` entries is padded to: the smallest
+    ``m * 2**e >= n`` with m in 8..16, never under 8 (a sublane group) nor
+    under ``minimum``. Every multiple of 8 up to 128, then steps of 16 to
+    256, 32 to 512, 64 to 1024...: at most an eighth of padding where a
+    power of two gave up to half (gather and scatter cost per entry,
+    padding included), 8 compiled widths per doubling. The same arithmetic
+    as ``pack`` in native/fast_ingest.cpp (tests/test_sparse_width.py holds
+    the two equal)."""
+    n = max(n, minimum, 8)
+    step = 8
+    while step * 16 < n:
+        step *= 2
+    return (n + step - 1) // step * step
 
 
 class CSRBatch:
@@ -111,13 +130,13 @@ class CSRBatch:
     def to_padded(self, min_width: int = 8,
                   batch_bucket: int = 1) -> "SparseBatch":
         """Vectorized pad into the [B, K] device interchange format —
-        the CSR equivalent of SparseBatch.from_vectors (same pow2 width
+        the CSR equivalent of SparseBatch.from_vectors (same width rung
         and optional row bucketing, no Python per-row loop)."""
         b = self.batch_size
         counts = np.diff(self.row_offsets)
         bsz = _bucket(max(b, 1), batch_bucket) if batch_bucket > 1 \
             else max(b, 1)
-        width = _bucket(int(counts.max()) if b else 1, min_width)
+        width = _width_bucket(int(counts.max()) if b else 1, min_width)
         idx = np.zeros((bsz, width), dtype=np.int32)
         val = np.zeros((bsz, width), dtype=np.float32)
         if self.nnz:
@@ -161,12 +180,14 @@ class SparseBatch:
     ) -> "SparseBatch":
         """Pack hashed sparse vectors into padded arrays.
 
-        Widths (and optionally batch sizes) are rounded up to power-of-two
-        buckets to bound the number of distinct XLA compilations.
+        Widths are rounded up to a rung of ``_width_bucket``'s ladder (and
+        optionally batch sizes to a power of two) to bound the number of
+        distinct XLA compilations.
         """
         n = len(vectors)
         bsz = _bucket(max(n, 1), batch_bucket) if batch_bucket > 1 else max(n, 1)
-        width = _bucket(max((len(v) for v in vectors), default=1), min_width)
+        width = _width_bucket(max((len(v) for v in vectors), default=1),
+                              min_width)
         idx = np.zeros((bsz, width), dtype=np.int32)
         val = np.zeros((bsz, width), dtype=np.float32)
         for i, vec in enumerate(vectors):

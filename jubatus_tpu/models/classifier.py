@@ -245,7 +245,10 @@ class ClassifierDriver(DriverBase):
         if self.trace is not None:
             # the width's side of step.train.rows / .rows_padded: entries
             # that carry a feature, entries the rows have at the program's
-            # width, and the bytes the stage put on the device
+            # width, the bytes the stage put on the device, and the width
+            # itself: each distinct one is a program this server's traffic
+            # made it compile (times the row buckets)
+            self.trace.count(f"step.train.width_{idx.shape[1]}")
             self.trace.count("step.train.entries",
                              int(np.count_nonzero(idx)))
             self.trace.count("step.train.entries_padded", b * idx.shape[1])

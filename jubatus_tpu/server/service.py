@@ -392,9 +392,10 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
 
     def _pad_concat(pairs):
         """Merge per-request (idx, val) pairs into one batch: pad widths
-        to the max (already pow2-bucketed by the parser, so pads are rare
-        and small) and concatenate at numpy speed. ONE owner for both the
-        train and query flush paths."""
+        to the max (each already on a rung of the parser's width ladder,
+        and the widest of rungs is a rung, so the flush lands on a compiled
+        width; pads are rare and small) and concatenate at numpy speed.
+        ONE owner for both the train and query flush paths."""
         kmax = max(i.shape[1] for i, _ in pairs)
         parts_i, parts_v = [], []
         for ir, vr in pairs:

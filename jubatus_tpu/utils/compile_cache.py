@@ -1,8 +1,9 @@
 """Where XLA's persistent compilation cache lives — the one place that
 decides.
 
-The serving path pads to power-of-two ``B``/``K`` buckets across several
-train and query plans, so a cold server compiles dozens of small programs;
+The serving path pads rows to power-of-two ``B`` buckets and widths to
+``K`` rungs (core/sparse.py ``_width_bucket``) across several train and
+query plans, so a cold server compiles dozens of small programs;
 a machine that keeps one directory between runs keeps all of them.
 
 Rule: if ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and

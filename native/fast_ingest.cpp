@@ -611,7 +611,8 @@ extern "C" {
 
 struct JtIngestOut {
   int32_t batch;       // examples parsed
-  int32_t width;       // padded nnz per row (pow2, >= 8)
+  int32_t width;       // padded nnz per row: a rung of the width ladder
+                       // (core/sparse.py _width_bucket), >= 8
   int32_t labels_numeric;  // 1: targets[] is set (regression), 0: labels
   int32_t* idx;        // [batch, width], 0-padded
   float* val;          // [batch, width], 0-padded
@@ -623,7 +624,7 @@ struct JtIngestOut {
   // combination specs only (null / 0 otherwise): the rows BEFORE the
   // cross product, packed like idx/val, so that one parse serves both the
   // host expansion and the device expansion of a uniform-schema batch
-  int32_t base_width;
+  int32_t base_width;  // a power of two, >= 8
   int32_t* base_idx;   // [batch, base_width]
   float* base_val;     // [batch, base_width]
   int64_t cross_slots;  // pair features emitted, before the merge by index
@@ -1291,15 +1292,24 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   }
   if (rd.fail) return 1;
 
-  // pack to [batch, width] with the SparseBatch width bucket (pow2, >= 8)
+  // pack to [batch, width] at the SparseBatch width bucket: core/sparse.py
+  // _width_bucket's arithmetic (eight rungs an octave, never finer than 8).
+  // The rows before the cross product (ladder false) keep the power of two:
+  // they feed no gather or scatter of their own, and the benchmark's
+  // tests/perfbench/test_cross.py holds their width at 64 for 39 features
+  // (a benchmark PR's to move: ROADMAP R-B1).
   auto pack = [n](const std::vector<Feature>& fs,
-                  const std::vector<int64_t>& offs, int32_t* width,
-                  int32_t** idx, float** val) {
+                  const std::vector<int64_t>& offs, bool ladder,
+                  int32_t* width, int32_t** idx, float** val) {
     int64_t max_nnz = 1;
     for (size_t e = 0; e + 1 < offs.size(); ++e)
       max_nnz = std::max(max_nnz, offs[e + 1] - offs[e]);
-    int32_t w = 8;
-    while (w < max_nnz) w *= 2;
+    // a step is a sixteenth of the power of two above the row (eight
+    // rungs an octave), or that power of two itself
+    const int64_t steps = ladder ? 16 : 1;
+    int64_t nw = std::max<int64_t>(max_nnz, 8), step = 8;
+    while (step * steps < nw) step *= 2;
+    int32_t w = int32_t((nw + step - 1) / step * step);
     *width = w;
     *idx = static_cast<int32_t*>(calloc(size_t(n) * w, 4));
     *val = static_cast<float*>(calloc(size_t(n) * w, 4));
@@ -1320,9 +1330,9 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   out->uniq = int32_t(uniq);
   out->cross_slots = cross_slots;
   out->cross_ns = cross_ns;
-  bool packed = pack(feats, offsets, &out->width, &out->idx, &out->val);
+  bool packed = pack(feats, offsets, true, &out->width, &out->idx, &out->val);
   if (packed && combo_mode)
-    packed = pack(bfeats, boffsets, &out->base_width, &out->base_idx,
+    packed = pack(bfeats, boffsets, false, &out->base_width, &out->base_idx,
                   &out->base_val);
   out->labels = static_cast<uint8_t*>(malloc(labels.size() ? labels.size() : 1));
   out->label_off = static_cast<int32_t*>(malloc((uniq + 1) * 4));

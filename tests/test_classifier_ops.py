@@ -194,10 +194,14 @@ def _warm_state(rng, cap, dim, method, live):
 
 
 #: (dim, the plan a flush takes there, its width): the narrow flushes the
-#: scenarios were written at, and the width bucket of a row of 780 features
-#: (a combination configuration's), on either side of the choice too
+#: scenarios were written at, the power-of-two bucket of a row of 780
+#: features (a combination configuration's), and the rungs the benchmark's
+#: rows ride at since ISSUE 27 (39 features at 40, 780 at 832: no multiple
+#: of 128, nor a power of two), each on either side of the choice
 PLANS = [(1 << 10, "packed", None), (1 << 16, "columns", None),
-         (1 << 12, "packed", 1024), (1 << 19, "columns", 1024)]
+         (1 << 12, "packed", 1024), (1 << 19, "columns", 1024),
+         (1 << 10, "packed", 40), (1 << 16, "columns", 40),
+         (1 << 12, "packed", 832), (1 << 19, "columns", 832)]
 
 
 def _flush(rng, scenario, dim, method, width=None):
@@ -360,7 +364,8 @@ def test_diff_and_checkpoint_round_trip_in_the_tables_own_shape(rng):
 
 
 @pytest.mark.parametrize("dim_bits,plan,width", [
-    (10, "packed", 5), (16, "columns", 5), (16, "packed", 1024)])
+    (10, "packed", 5), (16, "columns", 5), (16, "packed", 1024),
+    (18, "columns", 40), (16, "packed", 832)])
 def test_a_flush_is_counted_under_the_plan_its_shapes_settled_on(
         dim_bits, plan, width, rng):
     """And its entries beside its rows: those that carry a feature, those
@@ -378,8 +383,9 @@ def test_a_flush_is_counted_under_the_plan_its_shapes_settled_on(
         d.train_hashed([("x", "y")[i % 2] for i in range(20)], idx, val)
     counters = reg.counters()
     plans = {k: v for k, v in counters.items()
-             if k.startswith("step.train.plan_")}
-    assert plans == {"step.train.plan_" + plan: 2}
+             if k.startswith(("step.train.plan_", "step.train.width_"))}
+    assert plans == {"step.train.plan_" + plan: 2,
+                     f"step.train.width_{width}": 2}
     assert counters["step.train.entries"] == 2 * 20 * (width - width // 4)
     assert counters["step.train.entries_padded"] == 2 * 20 * width
     # 20 rows run in the 32-row program: index, value and label arrays
@@ -438,13 +444,15 @@ def test_no_program_relayouts_the_tables(rows, plan, one_chip):
     assert train.memory_analysis().alias_size_in_bytes == 4 * table
 
 
-def test_the_wide_programs_fit_the_chip(one_chip):
+@pytest.mark.parametrize("k,entries", [(1024, 8.39e6), (832, 6.82e6)])
+def test_the_wide_programs_fit_the_chip(k, entries, one_chip):
     """A combination configuration's flush at the benchmark's size (D =
-    2^25, 8,192 rows x 1,024 entries; PERF.md section 4): the train and
+    2^25, 8,192 rows; PERF.md section 4) at the width its 780 features ride
+    at (832) and at the power of two they rode at (1,024): the train and
     scores programs compile for the v5e, take the packed plan, scatter in
     place, and leave most of the chip's 16e9 B free. Their temporaries are
     the packed copy ([16, D] and [8, D]) and the gathered entries."""
-    dim, k = 1 << 25, 1024
+    dim = 1 << 25
     table = CAP * dim * 4
 
     def sds(shape, dtype):
@@ -453,19 +461,22 @@ def test_the_wide_programs_fit_the_chip(one_chip):
     state = C.ClassifierState(*[sds((CAP, dim), jnp.float32)] * 4)
     mask = sds((CAP,), jnp.bool_)
     temps = {}
-    for name, rows in (("train", 8192), ("scores", 512)):
+    for name, rows in (("train", 8192), ("train_512", 512), ("scores", 512)):
         idx, val = sds((rows, k), jnp.int32), sds((rows, k), jnp.float32)
         assert C.gather_plan(CAP, dim, rows * k) == "packed"
-        if name == "train":
+        if name == "scores":
+            prog = C.scores.lower(state, idx, val, mask).compile()
+        else:
             prog = C.train_batch_parallel.lower(
                 state, idx, val, sds((rows,), jnp.int32), mask, 1.0,
                 method="AROW").compile()
             assert prog.memory_analysis().alias_size_in_bytes == 4 * table
-        else:
-            prog = C.scores.lower(state, idx, val, mask).compile()
         m = prog.memory_analysis()
         temps[name] = m.temp_size_in_bytes
         assert m.temp_size_in_bytes + m.argument_size_in_bytes < 8e9, name
-    # the packed copy and what 8.39M (0.52M) gathered entries take beside it
-    assert 2 * table <= temps["train"] < 2 * table * 1.3, temps
+    # the packed copy and what the gathered entries (8.39M at 1,024, 6.82M
+    # at 832; a sixteenth of that at 512 rows) take beside it: 16 sublanes
+    # of f32 an entry
+    assert 2 * table <= temps["train_512"] < 2 * table * 1.05, temps
+    assert 2 * table <= temps["train"] < 2 * table + entries * 64 * 1.05, temps
     assert table <= temps["scores"] < table * 1.05, temps

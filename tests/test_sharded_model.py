@@ -38,16 +38,18 @@ def _batch(rng, b=B, k=K, dim=D):
 
 @pytest.mark.parametrize("n_shards", SHARD_COUNTS)
 @pytest.mark.parametrize("method", ("AROW", "PA1", "CW"))
-def test_train_and_scores_parity(method, n_shards, rng):
+# 40: a rung of the width ladder that is no power of two (39 features)
+@pytest.mark.parametrize("k", (K, 40))
+def test_train_and_scores_parity(method, n_shards, k, rng):
     conf = method in cops.CONFIDENCE_METHODS
     mesh = _mesh(n_shards)
-    idx, val, labels, mask = _batch(rng)
+    idx, val, labels, mask = _batch(rng, k=k)
     ref = cops.train_batch(cops.init_state(L, D, conf), idx, val, labels,
                            mask, 1.0, method=method)
     st = sm.place_state(mesh, cops.init_state(L, D, conf), D)
     # two consecutive batches: the second trains against the first's
     # diffs, so divergence would compound — parity must hold after both
-    idx2, val2, labels2, _ = _batch(rng)
+    idx2, val2, labels2, _ = _batch(rng, k=k)
     ref = cops.train_batch(ref, idx2, val2, labels2, mask, 1.0,
                            method=method)
     st = sm.train_batch(mesh, st, idx, val, labels, mask, 1.0,
@@ -57,7 +59,7 @@ def test_train_and_scores_parity(method, n_shards, rng):
     for name, (a, b) in zip(("w", "dw", "prec", "dprec"), zip(ref, st)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-5, atol=3e-5, err_msg=name)
-    qi, qv, _, _ = _batch(rng)
+    qi, qv, _, _ = _batch(rng, k=k)
     np.testing.assert_allclose(
         np.asarray(sm.scores(mesh, st, qi, qv, mask)),
         np.asarray(cops.scores(ref, qi, qv, mask)),
