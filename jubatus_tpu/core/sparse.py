@@ -114,7 +114,7 @@ class CSRBatch:
     def uniform_row(self) -> Optional[np.ndarray]:
         """The shared index row if EVERY row carries the same index vector
         (fixed key schema — the common production feed), else None.
-        Unlocks the dense submatrix device plans (ops.*_schema)."""
+        The dense submatrix train plan's condition (ops.train_batch_schema)."""
         b = self.batch_size
         if b == 0:
             return None

@@ -18,7 +18,7 @@ array.
 from __future__ import annotations
 
 import json
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -55,8 +55,8 @@ class ClassifierDriver(DriverBase):
         # train/classify paths run as shard_map programs
         # (parallel/sharded_model.py): the CSR batch is column-range
         # partitioned to the owning shard, one psum reduces the [B, L]
-        # logits, and the weight matrix is never gathered. The schema/
-        # combo plans keep GSPMD partitioning of the placed state.
+        # logits, and the weight matrix is never gathered. The dense
+        # uniform-schema plan keeps GSPMD partitioning of the placed state.
         # Orthogonal to cross-server data parallelism via the mix plane
         # (parallel/spmd.py stacks both for the pod path).
         method = config.get("method")
@@ -210,21 +210,36 @@ class ClassifierDriver(DriverBase):
         return self.train_hashed(labels, idx, val)
 
     def _train_slots(self, slots: np.ndarray, idx: np.ndarray,
-                     val: np.ndarray, b: int) -> int:
+                     val: np.ndarray, b: int, uniform: bool = False) -> int:
         """Shared pre-hashed dispatch tail: pow2 row bucketing (same shape
         buckets as the converter path), padding, and the device step. Both
-        hashed entry points funnel here so their semantics cannot drift."""
+        hashed entry points funnel here so their semantics cannot drift.
+
+        ``uniform``: every row carries the same index row (a fixed key
+        schema), so the step takes the dense [L, K]-submatrix plan
+        (ops.train_batch_schema): 8.9 ms against 36.7 for the sparse plan
+        at 8,192 x 40 and the same at 512 x 40, D = 2^25 (PERF.md section
+        6, PR 28). Sequential train mode keeps the sparse scan, where
+        exact per-datum semantics take priority."""
         bsz = _bucket(b, 16)
+        schema = uniform and self.train_mode == "parallel"
         with self._span("step.train.stage"):
-            if bsz != b:
+            if bsz != b:  # zero rows are no-ops (x2 = 0 → alpha 0)
                 idx = np.pad(idx, ((0, bsz - b), (0, 0)))
                 val = np.pad(val, ((0, bsz - b), (0, 0)))
             slots_arr = np.zeros(bsz, dtype=np.int32)
             slots_arr[:b] = slots
-            didx, dval = jnp.asarray(idx), jnp.asarray(val)
-            dslots, mask = jnp.asarray(slots_arr), self._mask()
+            didx = jnp.asarray(idx[0] if schema else idx)
+            dval, dslots = jnp.asarray(val), jnp.asarray(slots_arr)
+            mask = self._mask()
+        plan = None
         with self._span("step.train.dispatch"):
-            if self._mesh is not None and self.train_mode == "parallel":
+            if schema:
+                plan = "schema"
+                self.state = ops.train_batch_schema(
+                    self.state, didx, dval, dslots, mask, self.param,
+                    method=self.method)
+            elif self._mesh is not None and self.train_mode == "parallel":
                 # shard_map path: batch routed by column range, one psum
                 # for the logits — weight state never moves (ISSUE 13)
                 from jubatus_tpu.parallel import sharded_model as _sm
@@ -235,36 +250,30 @@ class ClassifierDriver(DriverBase):
             else:
                 # sequential mode keeps GSPMD partitioning of the placed
                 # state
+                if self.train_mode == "parallel":
+                    plan = ops.gather_plan(*self.state.w.shape, idx.size)
                 self.state = ops.train_batch(
                     self.state, didx, dval, dslots, mask, self.param,
                     method=self.method, mode=self.train_mode)
-                if self.trace is not None and self.train_mode == "parallel":
-                    # which gather the step's shapes settled on
-                    plan = ops.gather_plan(*self.state.w.shape, idx.size)
-                    self.trace.count(f"step.train.plan_{plan}")
-        if self.trace is not None:
-            # the width's side of step.train.rows / .rows_padded: entries
-            # that carry a feature, entries the rows have at the program's
-            # width, the bytes the stage put on the device, and the width
-            # itself: each distinct one is a program this server's traffic
-            # made it compile (times the row buckets)
-            self.trace.count(f"step.train.width_{idx.shape[1]}")
-            self.trace.count("step.train.entries",
-                             int(np.count_nonzero(idx)))
-            self.trace.count("step.train.entries_padded", b * idx.shape[1])
-            self.trace.count("step.train.upload_bytes",
-                             idx.nbytes + val.nbytes + slots_arr.nbytes)
-        return self._trained(b, bsz)
-
-    def _trained(self, b: int, bsz: int) -> int:
-        """What every train plan does once its step is dispatched: the
-        update event, and (under a server) the rows asked for and the
-        rows the compiled bucket ran."""
         self.event_model_updated(b)
         trace = self.trace
         if trace is not None:
+            if plan is not None:
+                # which plan the step's rows and shapes settled on
+                trace.count(f"step.train.plan_{plan}")
+            # the rows asked for and the rows the compiled bucket ran, and
+            # the width's side of them: entries that carry a feature,
+            # entries the rows have at the program's width, the bytes the
+            # stage put on the device, and the width itself: each distinct
+            # one is a program this server's traffic made it compile
+            # (times the row buckets)
             trace.count("step.train.rows", b)
             trace.count("step.train.rows_padded", bsz)
+            trace.count(f"step.train.width_{idx.shape[1]}")
+            trace.count("step.train.entries", int(np.count_nonzero(idx)))
+            trace.count("step.train.entries_padded", b * idx.shape[1])
+            trace.count("step.train.upload_bytes",
+                        didx.nbytes + dval.nbytes + dslots.nbytes)
         return b
 
     @locked
@@ -302,156 +311,10 @@ class ClassifierDriver(DriverBase):
         # uniq labels (past 256 distinct it appends without scanning), and
         # += keeps only the last write per duplicated slot
         np.add.at(self._dcounts, slots_u, counts[:len(slots_u)])
-        return self._train_slots(slots_u[label_idx], idx, val, b)
-
-    @locked
-    def train_indexed_schema(self, uniq_labels: Sequence[str],
-                             label_idx: np.ndarray, uidx: np.ndarray,
-                             val: np.ndarray) -> int:
-        """train_indexed for a UNIFORM-SCHEMA batch: every example shares
-        the hashed index vector ``uidx`` [K] (a fixed key schema — the
-        common production feed; the serving flush detects it). Runs the
-        dense [L, K]-submatrix plan (ops.train_batch_schema): K-descriptor
-        index ops + matmuls instead of B*K-element gathers/scatters —
-        the addressing-floor term (docs/PERF_NOTES.md) drops out
-        entirely. Falls back to the sparse plan under sequential train
-        mode, where exact per-datum semantics take priority."""
-        b = int(label_idx.shape[0])
-        if b == 0:
-            return 0
-        slots_u = np.array([self._ensure_label(lb) for lb in uniq_labels],
-                           dtype=np.int32)
-        counts = np.bincount(label_idx, minlength=len(uniq_labels))
-        np.add.at(self._dcounts, slots_u, counts[:len(slots_u)])
-        slots = slots_u[label_idx]
-        if self.train_mode != "parallel":
-            return self._train_slots(
-                slots, np.broadcast_to(uidx, (b, uidx.shape[0])), val, b)
-        bsz = _bucket(b, 16)
-        with self._span("step.train.stage"):
-            if bsz != b:  # zero rows are no-ops (x2 = 0 → alpha 0)
-                val = np.pad(val, ((0, bsz - b), (0, 0)))
-                slots = np.pad(slots, (0, bsz - b))
-            staged = (jnp.asarray(uidx), jnp.asarray(val),
-                      jnp.asarray(slots), self._mask())
-        with self._span("step.train.dispatch"):
-            self.state = ops.train_batch_schema(
-                self.state, *staged, self.param, method=self.method)
-        return self._trained(b, bsz)
-
-    @locked
-    def train_indexed_combo(self, uniq_labels: Sequence[str],
-                            label_idx: np.ndarray, uidx: np.ndarray,
-                            base_val: np.ndarray, a_idx: np.ndarray,
-                            b_idx: np.ndarray, mul_mask: np.ndarray) -> int:
-        """train_indexed_schema with DEVICE-SIDE combination expansion:
-        ``uidx`` is the full base+slot index vector ([K0+S], no duplicate
-        indices — the plan builder guarantees it), ``base_val`` only the
-        [B, K0] base columns. The cross product's slot values are
-        computed on device (ops._expand_combo), so neither the host
-        parse nor the wire ever carries the (K0+S)-wide row — the combo
-        serving cliff was upload-bound, not compute-bound."""
-        b = int(label_idx.shape[0])
-        if b == 0:
-            return 0
-        slots_u = np.array([self._ensure_label(lb) for lb in uniq_labels],
-                           dtype=np.int32)
-        counts = np.bincount(label_idx, minlength=len(uniq_labels))
-        np.add.at(self._dcounts, slots_u, counts[:len(slots_u)])
-        slots = slots_u[label_idx]
-        k0 = base_val.shape[1]
-        if self.train_mode != "parallel":
-            # sequential mode: exact per-datum semantics take priority —
-            # expand on host and ride the sparse scan path
-            full = _expand_combo_host(base_val, a_idx, b_idx, mul_mask)
-            return self._train_slots(
-                slots, np.broadcast_to(uidx, (b, uidx.shape[0])), full, b)
-        bsz = _bucket(b, 16)
-        with self._span("step.train.stage"):
-            if bsz != b:  # zero base rows expand to zero slots — still no-ops
-                base_val = np.pad(base_val, ((0, bsz - b), (0, 0)))
-                slots = np.pad(slots, (0, bsz - b))
-            staged = (jnp.asarray(uidx), jnp.asarray(base_val),
-                      jnp.asarray(a_idx), jnp.asarray(b_idx),
-                      jnp.asarray(mul_mask), jnp.asarray(slots),
-                      self._mask())
-        with self._span("step.train.dispatch"):
-            self.state = ops.train_batch_schema_combo(
-                self.state, *staged, self.param, method=self.method)
-        return self._trained(b, bsz)
-
-    def _scored_rows(self, n: int, stage: Callable[[int], tuple],
-                     score: Callable[..., Any]
-                     ) -> List[List[Tuple[str, float]]]:
-        """The read side's one path, whatever the plan: ``stage(pad)``
-        pads ``pad`` zero rows on and uploads, ``score(state, *staged,
-        mask)`` enqueues the plan's program.
-
-        Dispatch-under-lock, wait-unlocked: the scores computation is
-        ENQUEUED while the driver lock guarantees no train step can
-        donate the state buffers first (train_batch donates for in-place
-        scatters — dispatching against an already-donated Array raises
-        "Array has been deleted"); once enqueued, the runtime keeps the
-        buffers alive for the pending read, so the device round trip and
-        result wait run unlocked and concurrent queries overlap instead
-        of serializing. ≙ the reference's JRLOCK_ shared reads. H2D
-        transfers touch no driver state: staged unlocked, so the
-        critical section is just the enqueue."""
-        b = _bucket(n, 16)
-        with self._span("step.classify.stage"):
-            staged = stage(b - n)
-        with self._span("step.classify.lock_wait"):
-            self.lock.acquire()
-        try:
-            if not self.label_slots:
-                return [[] for _ in range(n)]
-            slots = list(self.label_slots.items())
-            with self._span("step.classify.dispatch"):
-                pending = score(self.state, *staged, self._mask())
-        finally:
-            self.lock.release()
-        with self._span("step.classify.wait"):
-            sc = np.asarray(pending)[:n]
-        trace = self.trace
-        if trace is not None:
-            trace.count("step.classify.rows", n)
-            trace.count("step.classify.rows_padded", b)
-        # (label, score) pairs are the answer as it goes on the wire: the
-        # packer writes a tuple as it writes a list, so the service hands
-        # these rows on as they are
-        with self._span("classify.encode"):
-            return [[(lab, float(row[slot]))
-                     for lab, slot in slots] for row in sc]
-
-    def classify_hashed_combo(self, uidx: np.ndarray, base_val: np.ndarray,
-                              a_idx: np.ndarray, b_idx: np.ndarray,
-                              mul_mask: np.ndarray
-                              ) -> List[List[Tuple[str, float]]]:
-        """classify_hashed_schema with device-side combo expansion."""
-        n = base_val.shape[0]
-        if n == 0:
-            return []
-
-        def stage(pad: int) -> tuple:
-            val = np.pad(base_val, ((0, pad), (0, 0))) if pad else base_val
-            return (jnp.asarray(uidx), jnp.asarray(val), jnp.asarray(a_idx),
-                    jnp.asarray(b_idx), jnp.asarray(mul_mask))
-
-        return self._scored_rows(n, stage, ops.scores_schema_combo)
-
-    def classify_hashed_schema(self, uidx: np.ndarray,
-                               val: np.ndarray) -> List[List[Tuple[str, float]]]:
-        """classify_hashed for a uniform-schema batch (ops.scores_schema:
-        K descriptors + one matmul)."""
-        n = val.shape[0]
-        if n == 0:
-            return []
-
-        def stage(pad: int) -> tuple:
-            v = np.pad(val, ((0, pad), (0, 0))) if pad else val
-            return jnp.asarray(uidx), jnp.asarray(v)
-
-        return self._scored_rows(n, stage, ops.scores_schema)
+        # one shared index row: a cheap look at the second row settles it
+        # for every feed that is not uniform
+        uniform = bool((idx[1:2] == idx[0]).all() and (idx == idx[0]).all())
+        return self._train_slots(slots_u[label_idx], idx, val, b, uniform)
 
     def classify(self, data: Sequence[Datum]) -> List[List[Tuple[str, float]]]:
         # deliberately NOT @locked: batch conversion touches no driver
@@ -469,25 +332,56 @@ class ClassifierDriver(DriverBase):
     def classify_hashed(self, idx: np.ndarray,
                         val: np.ndarray) -> List[List[Tuple[str, float]]]:
         """Classify pre-hashed features (native ingest fast path); same
-        output shape as classify()."""
+        output shape as classify().
+
+        Dispatch-under-lock, wait-unlocked: the scores computation is
+        ENQUEUED while the driver lock guarantees no train step can
+        donate the state buffers first (train_batch donates for in-place
+        scatters — dispatching against an already-donated Array raises
+        "Array has been deleted"); once enqueued, the runtime keeps the
+        buffers alive for the pending read, so the device round trip and
+        result wait run unlocked and concurrent queries overlap instead
+        of serializing. ≙ the reference's JRLOCK_ shared reads. H2D
+        transfers touch no driver state: staged unlocked, so the
+        critical section is just the enqueue."""
         n = idx.shape[0]
         if n == 0:
             return []
+        b = _bucket(n, 16)
+        with self._span("step.classify.stage"):
+            if b != n:
+                idx = np.pad(idx, ((0, b - n), (0, 0)))
+                val = np.pad(val, ((0, b - n), (0, 0)))
+            didx, dval = jnp.asarray(idx), jnp.asarray(val)
+        with self._span("step.classify.lock_wait"):
+            self.lock.acquire()
+        try:
+            if not self.label_slots:
+                return [[] for _ in range(n)]
+            slots = list(self.label_slots.items())
+            with self._span("step.classify.dispatch"):
+                if self._mesh is None:
+                    pending = ops.scores(self.state, didx, dval, self._mask())
+                else:
+                    from jubatus_tpu.parallel import sharded_model as _sm
 
-        def stage(pad: int) -> tuple:
-            i, v = idx, val
-            if pad:
-                i = np.pad(i, ((0, pad), (0, 0)))
-                v = np.pad(v, ((0, pad), (0, 0)))
-            return jnp.asarray(i), jnp.asarray(v)
-
-        if self._mesh is None:
-            return self._scored_rows(n, stage, ops.scores)
-        from jubatus_tpu.parallel import sharded_model as _sm
-
-        return self._scored_rows(
-            n, stage, lambda state, didx, dval, mask: _sm.scores(
-                self._mesh, state, didx, dval, mask, axis=self._mesh_axis))
+                    pending = _sm.scores(
+                        self._mesh, self.state, didx, dval, self._mask(),
+                        axis=self._mesh_axis)
+        finally:
+            self.lock.release()
+        with self._span("step.classify.wait"):
+            sc = np.asarray(pending)[:n]
+        trace = self.trace
+        if trace is not None:
+            trace.count("step.classify.rows", n)
+            trace.count("step.classify.rows_padded", b)
+        # (label, score) pairs are the answer as it goes on the wire: the
+        # packer writes a tuple as it writes a list, so the service hands
+        # these rows on as they are
+        with self._span("classify.encode"):
+            return [[(lab, float(row[slot]))
+                     for lab, slot in slots] for row in sc]
 
     def shard_stats(self) -> Dict[str, Any]:
         """Feature-shard layout gauges (shard.* catalog rows,
@@ -705,12 +599,3 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
-
-
-def _expand_combo_host(base_val: np.ndarray, a_idx: np.ndarray,
-                       b_idx: np.ndarray, mul_mask: np.ndarray) -> np.ndarray:
-    """Host-side mirror of ops._expand_combo (sequential train mode)."""
-    va = base_val[:, a_idx]
-    vb = base_val[:, b_idx]
-    slots = np.where(mul_mask[None, :], va * vb, va + vb)
-    return np.concatenate([base_val, slots], axis=1).astype(np.float32)

@@ -179,9 +179,8 @@ def _scatter_add(table, rows, idx, up):
 
 
 def decide_updates(s, labels, label_mask, x2, v, x2_vec, param, *, method):
-    """The shared per-batch update decision — one implementation for the
-    single-chip path (train_batch_parallel) and the pod path
-    (parallel/spmd.py), so the two can never drift numerically.
+    """The per-batch update decision of train_rows, on one chip and on
+    the mesh alike.
 
     Inputs are already globally reduced where sharded: s [B, L] raw scores,
     x2/v [B] (= ||x||^2 and x'(Sig_c+Sig_w)x), x2_vec [B, K] *local* squared
@@ -211,13 +210,10 @@ def decide_updates(s, labels, label_mask, x2, v, x2_vec, param, *, method):
     return wrong, alpha, alpha_w, dp
 
 
-@functools.partial(jax.jit, donate_argnums=())
-def scores(state: ClassifierState, idx: jax.Array, val: jax.Array,
-           label_mask: jax.Array) -> jax.Array:
-    """Batch classify scores.
-
-    idx/val: [B, K] hashed sparse batch; label_mask: [L] bool (live labels).
-    Returns [B, L] margins with dead labels at -inf.
+def score_rows(w, dw, idx, val, label_mask, reduce=lambda x: x):
+    """The [B, L] margins of a hashed sparse batch against w + dw, dead
+    labels at -inf. ``reduce`` sums the partial scores over the shards of a
+    mesh (parallel/sharded_model.py); on one chip it is the identity.
 
     The gather takes _gather_sums' plan: columns where the table is large
     beside the batch, the packed [D, L] copy otherwise.
@@ -225,9 +221,20 @@ def scores(state: ClassifierState, idx: jax.Array, val: jax.Array,
     # the scope is the program's own word beside XLA's op names in a
     # device capture: metadata only
     with jax.named_scope("scores"):
-        (g,) = _gather_sums([(state.w, state.dw)], idx)      # [L, B, K]
-        s = jnp.einsum("lbk,bk->bl", g, val)
+        (g,) = _gather_sums([(w, dw)], idx)                   # [L, B, K]
+        s = reduce(jnp.einsum("lbk,bk->bl", g, val))
         return jnp.where(label_mask[None, :], s, _NEG)
+
+
+@functools.partial(jax.jit, donate_argnums=())
+def scores(state: ClassifierState, idx: jax.Array, val: jax.Array,
+           label_mask: jax.Array) -> jax.Array:
+    """Batch classify scores.
+
+    idx/val: [B, K] hashed sparse batch; label_mask: [L] bool (live labels).
+    Returns [B, L] margins with dead labels at -inf.
+    """
+    return score_rows(state.w, state.dw, idx, val, label_mask)
 
 
 def _alpha_and_prec(method: str, param: float, margin, loss, x2, v, x2_vec):
@@ -281,18 +288,11 @@ def _alpha_and_prec(method: str, param: float, margin, loss, x2, v, x2_vec):
     raise ValueError(f"unknown classifier method {method!r}")
 
 
-@functools.partial(jax.jit, static_argnames=("method",), donate_argnums=(0,))
-def train_batch_parallel(
-    state: ClassifierState,
-    idx: jax.Array,        # [B, K] int32
-    val: jax.Array,        # [B, K] float32
-    labels: jax.Array,     # [B] int32 — correct label row per example
-    label_mask: jax.Array, # [L] bool — live labels
-    param: float,
-    *,
-    method: str,
-) -> ClassifierState:
-    """Vectorized microbatch update — the TPU hot path.
+def train_rows(w, dw, prec, dprec, idx, val, labels, label_mask, param, *,
+               method: str, reduce=lambda x: x):
+    """The vectorized microbatch update: the one body of the update rule,
+    for one chip (train_batch_parallel) and for the mesh
+    (parallel/sharded_model.py, parallel/spmd.py). Returns the four tables.
 
     Every example computes its margin/alpha against the batch-start snapshot
     and all updates land in one scatter-add (bounded staleness *within* a
@@ -301,9 +301,14 @@ def train_batch_parallel(
     (train_batch_sequential) is ~40 ms/1024 examples on a v5e chip because a
     sequential scan of tiny gathers/scatters is latency-bound, while this
     path is one gather + one einsum + one scatter over the whole batch.
+
+    On a mesh the tables are a shard's [L, D/S] slices, ``idx`` its local
+    columns and ``val`` zero where the shard does not own the entry, and
+    ``reduce`` sums over the shards the three quantities that cross them:
+    the [B, L] scores, x2 and v. An unowned entry's updates are
+    alpha * sigma * 0 = 0, added at local column 0.
     """
     confidence = method in CONFIDENCE_METHODS
-    w, dw, prec, dprec = state
 
     # The scopes (pack, gather, margin, scatter) are the phases' own
     # words beside XLA's op names in a device capture: metadata only.
@@ -312,9 +317,9 @@ def train_batch_parallel(
     else:
         (eff_g,) = _gather_sums([(w, dw)], idx)
     with jax.named_scope("margin"):
-        s = jnp.einsum("lbk,bk->bl", eff_g, val)
+        s = reduce(jnp.einsum("lbk,bk->bl", eff_g, val))
         x2_vec = val * val                                         # [B, K]
-        x2 = jnp.sum(x2_vec, axis=1)                               # [B]
+        x2 = reduce(jnp.sum(x2_vec, axis=1))                       # [B]
 
         if confidence:
             p_c = jnp.take_along_axis(p_g, labels[None, :, None], axis=0)[0]  # [B,K]
@@ -337,7 +342,7 @@ def train_batch_parallel(
             # row's (possibly trained) precision
             no_rival = jnp.sum(label_mask) < 2
             sig_w = jnp.where(no_rival, 1.0, 1.0 / p_w)
-            v = jnp.sum((sig_c + sig_w) * x2_vec, axis=1)          # [B]
+            v = reduce(jnp.sum((sig_c + sig_w) * x2_vec, axis=1))  # [B]
         else:
             sig_w = jnp.ones_like(val)
             v = jnp.zeros_like(x2)
@@ -361,53 +366,23 @@ def train_batch_parallel(
             dp_w = jnp.where((alpha_w > 0.0)[:, None], dp, 0.0)
             dprec = _scatter_add(dprec, rows, idx2,
                                  jnp.concatenate([dp, dp_w]))
-    return ClassifierState(w, dw, prec, dprec)
+    return w, dw, prec, dprec
 
 
-@functools.partial(jax.jit, donate_argnums=())
-def scores_schema(state: ClassifierState, uidx: jax.Array, val: jax.Array,
-                  label_mask: jax.Array) -> jax.Array:
-    """Batch classify scores for a UNIFORM-SCHEMA batch: every example
-    carries the same hashed index vector ``uidx`` [K] (a fixed key
-    schema — the common production feed shape). The [B*K]-element gather
-    of scores() collapses to K descriptors and the score math becomes a
-    dense [B,K]x[K,L] matmul — MXU work instead of element-granular
-    addressing (per-descriptor cost, docs/PERF_NOTES.md)."""
-    eff_sub = jnp.take(state.w + state.dw, uidx, axis=1)  # [L, K]
-    s = val @ eff_sub.T                                   # [B, L]
-    return jnp.where(label_mask[None, :], s, _NEG)
-
-
-def _expand_combo(base_val: jax.Array, a_idx: jax.Array, b_idx: jax.Array,
-                  mul_mask: jax.Array) -> jax.Array:
-    """Device-side combination-feature expansion for a uniform-schema
-    batch: the cross product's pair values are a bilinear function of the
-    [B, K0] BASE feature matrix, so the host ships K0-wide rows and the
-    device materializes the S combo slots itself — slot s =
-    base[:, a]*base[:, b] (mul) or base[:, a]+base[:, b] (add). The wire
-    and host-emit cost of the (K0 + S)-wide row (528 slots at the bench
-    shape) drops to K0. Padding rows are all-zero base rows, so every
-    slot value is 0 there (0*0 = 0+0 = 0) and the no-op guarantee holds.
-    Returns the full [B, K0 + S] value matrix aligned with the caller's
-    uidx = concat(base_idx_row, slot_idx)."""
-    va = jnp.take(base_val, a_idx, axis=1)
-    vb = jnp.take(base_val, b_idx, axis=1)
-    slots = jnp.where(mul_mask[None, :], va * vb, va + vb)
-    return jnp.concatenate([base_val, slots], axis=1)
-
-
-@functools.partial(jax.jit, donate_argnums=())
-def scores_schema_combo(state: ClassifierState, uidx: jax.Array,
-                        base_val: jax.Array, a_idx: jax.Array,
-                        b_idx: jax.Array, mul_mask: jax.Array,
-                        label_mask: jax.Array) -> jax.Array:
-    """scores_schema with on-device combination expansion (see
-    _expand_combo): ``uidx`` is the full base+slot index vector, the host
-    ships only the base columns."""
-    val = _expand_combo(base_val, a_idx, b_idx, mul_mask)
-    eff_sub = jnp.take(state.w + state.dw, uidx, axis=1)
-    s = val @ eff_sub.T
-    return jnp.where(label_mask[None, :], s, _NEG)
+@functools.partial(jax.jit, static_argnames=("method",), donate_argnums=(0,))
+def train_batch_parallel(
+    state: ClassifierState,
+    idx: jax.Array,        # [B, K] int32
+    val: jax.Array,        # [B, K] float32
+    labels: jax.Array,     # [B] int32 — correct label row per example
+    label_mask: jax.Array, # [L] bool — live labels
+    param: float,
+    *,
+    method: str,
+) -> ClassifierState:
+    """train_rows on one chip — the TPU hot path."""
+    return ClassifierState(*train_rows(
+        *state, idx, val, labels, label_mask, param, method=method))
 
 
 @functools.partial(jax.jit, static_argnames=("method",), donate_argnums=(0,))
@@ -421,56 +396,29 @@ def train_batch_schema(
     *,
     method: str,
 ) -> ClassifierState:
-    """Vectorized microbatch update for a UNIFORM-SCHEMA batch.
+    """Vectorized microbatch update for a UNIFORM-SCHEMA batch: every
+    example carries the same hashed index vector ``uidx`` (a fixed key
+    schema). The driver takes it where a flush's rows say so
+    (models/classifier.py _train_slots).
 
     Semantics are identical to train_batch_parallel (every example
     decides against the batch-start snapshot, updates land together) —
     only the execution plan differs: with one shared index vector the
-    B*K-element packed gather collapses to K descriptors
-    (``take(.., uidx)``), scoring becomes a [B,K]x[K,L] matmul, and the
-    two B*K-element scatter-adds become label-grouped dense reductions
-    (one-hot matmuls, [L,B]x[B,K]) followed by ONE K-column scatter.
-    On v5e the sparse step is addressing-bound at ~37 ns/element
-    (docs/PERF_NOTES.md); this path removes that term entirely for
-    schema-uniform traffic and feeds the MXU instead. Float summation
-    order differs from the sparse plan (dense reductions vs scatter
-    order), so results agree to tolerance, not bitwise.
+    B*K-element gather collapses to K descriptors (``take(.., uidx)``),
+    scoring becomes a [B,K]x[K,L] matmul, and the two B*K-element
+    scatter-adds become label-grouped dense reductions (one-hot matmuls,
+    [L,B]x[B,K]) followed by ONE K-column scatter. On v5e at D = 2^25,
+    L = 8, K = 40 the step is 8.9 ms whatever the rows, almost all of it
+    the two table-wide sums under the ``take``s, against 36.7 ms for the
+    sparse plan at 8,192 rows and 8.8 at 512 (PERF.md section 6, PR 28).
+    Float summation order differs from the sparse plan (dense reductions
+    vs scatter order), so results agree to tolerance, not bitwise.
 
     Duplicate entries in ``uidx`` (e.g. width-padding zeros) are safe:
     the final ``.at[:, uidx].add`` accumulates per occurrence, exactly
     like the sparse scatter over repeated (b, k) slots, and padded
     columns carry val 0 so they contribute nothing.
     """
-    return _train_schema_impl(state, uidx, val, labels, label_mask, param,
-                              method)
-
-
-@functools.partial(jax.jit, static_argnames=("method",), donate_argnums=(0,))
-def train_batch_schema_combo(
-    state: ClassifierState,
-    uidx: jax.Array,       # [K0+S] int32 — base row + combo slot indices
-    base_val: jax.Array,   # [B, K0] float32 — base feature values only
-    a_idx: jax.Array,      # [S] int32 — left base column per slot
-    b_idx: jax.Array,      # [S] int32 — right base column per slot
-    mul_mask: jax.Array,   # [S] bool — mul (True) vs add per slot
-    labels: jax.Array,
-    label_mask: jax.Array,
-    param: float,
-    *,
-    method: str,
-) -> ClassifierState:
-    """train_batch_schema with on-device combination expansion: the host
-    ships the K0 base columns, the device materializes the S combo slots
-    (_expand_combo) and runs the identical dense schema update. The
-    caller guarantees ``uidx`` has no duplicate indices across base and
-    slots (the plan builder declines colliding schemas), so expansion +
-    schema update is exactly the merged per-datum feature vector."""
-    val = _expand_combo(base_val, a_idx, b_idx, mul_mask)
-    return _train_schema_impl(state, uidx, val, labels, label_mask, param,
-                              method)
-
-
-def _train_schema_impl(state, uidx, val, labels, label_mask, param, method):
     confidence = method in CONFIDENCE_METHODS
     w, dw, prec, dprec = state
     num_labels = w.shape[0]

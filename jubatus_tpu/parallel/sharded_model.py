@@ -22,10 +22,11 @@ Execution model (one shard_map'd jitted program per op):
   is never gathered: per-device footprint stays (full size / n_shards)
   + O(batch).
 
-The same decision kernel as the single-chip path
-(ops/classifier.decide_updates) keeps sharded and unsharded results
-identical to f32 rounding; parallel/spmd.py stacks this body under a
-data-parallel replica axis for the pod path.
+The update rule and the scores are the single-chip path's own bodies
+(ops/classifier.train_rows, score_rows) with their cross-shard sums
+psum'd, so sharded and unsharded results are identical to f32 rounding;
+parallel/spmd.py runs the same body under a data-parallel replica axis
+for the pod path.
 
 Mix integration: ``shard_chunks`` / ``assemble_chunks`` convert a
 feature-sharded leaf to/from per-shard host chunks keyed by start
@@ -46,9 +47,9 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jubatus_tpu.ops.classifier import (
-    CONFIDENCE_METHODS,
     ClassifierState,
-    decide_updates,
+    score_rows,
+    train_rows,
 )
 
 DEFAULT_AXIS = "shard"
@@ -113,9 +114,7 @@ def _owned(idx, val, d_local, axis):
     lo = jax.lax.axis_index(axis) * d_local
     li_raw = idx - lo
     owned = (li_raw >= 0) & (li_raw < d_local)
-    li = jnp.where(owned, li_raw, 0)
-    lv = jnp.where(owned, val, 0.0)
-    return li, lv, owned
+    return jnp.where(owned, li_raw, 0), jnp.where(owned, val, 0.0)
 
 
 @functools.partial(
@@ -124,57 +123,19 @@ def train_batch(mesh: Mesh, state: ClassifierState, idx: jax.Array,
                 val: jax.Array, labels: jax.Array, label_mask: jax.Array,
                 param: float, *, method: str,
                 axis: str = DEFAULT_AXIS) -> ClassifierState:
-    """Feature-sharded vectorized microbatch update (the shard_map'd
-    mirror of ops.train_batch_parallel; parallel/spmd.py runs the same
-    body under an extra replica axis). Batch arrays are replicated (the
-    batch is kilobytes; the state is the thing that must not move);
-    state leaves are sharded over ``axis``. One psum of [B, L] partial
-    scores (+ [B] norms) per step — weight state never crosses shards."""
-    confidence = method in CONFIDENCE_METHODS
-    n_shards = mesh.shape[axis]
+    """Feature-sharded vectorized microbatch update: ops.train_rows on
+    each shard's slice (parallel/spmd.py runs the same body under an
+    extra replica axis). Batch arrays are replicated (the batch is
+    kilobytes; the state is the thing that must not move); state leaves
+    are sharded over ``axis``. One psum of [B, L] partial scores (+ [B]
+    norms) per step — weight state never crosses shards."""
     dim = state.w.shape[-1]
 
     def body(w, dw, prec, dprec, idx, val, labels, label_mask):
-        d_local = w.shape[1]
-        li, lv, owned = _owned(idx, val, d_local, axis)
-
-        eff = w + dw
-        g = jnp.take(eff, li, axis=1)                      # [L, B, K]
-        s = jax.lax.psum(jnp.einsum("lbk,bk->bl", g, lv), axis)
-        x2_vec_l = lv * lv
-        x2 = jax.lax.psum(jnp.sum(x2_vec_l, axis=1), axis)
-
-        if confidence:
-            p = prec + dprec
-            pg = jnp.take(p, li, axis=1)                   # [L, B, K]
-            p_c = jnp.take_along_axis(pg, labels[None, :, None], axis=0)[0]
-            sig_c = jnp.where(owned, 1.0 / p_c, 0.0)
-            wrong0, _, _, _ = decide_updates(
-                s, labels, label_mask, x2, jnp.zeros_like(x2), x2_vec_l,
-                param, method=method)
-            p_w = jnp.take_along_axis(pg, wrong0[None, :, None], axis=0)[0]
-            no_rival = jnp.sum(label_mask) < 2
-            sig_w = jnp.where(owned,
-                              jnp.where(no_rival, 1.0, 1.0 / p_w), 0.0)
-            v = jax.lax.psum(
-                jnp.sum((sig_c + sig_w) * x2_vec_l, axis=1), axis)
-        else:
-            sig_c = sig_w = jnp.where(owned, 1.0, 0.0)
-            v = jnp.zeros_like(x2)
-
-        wrong, alpha, alpha_w, dp = decide_updates(
-            s, labels, label_mask, x2, v, x2_vec_l, param, method=method)
-
-        up_c = alpha[:, None] * sig_c * lv
-        up_w = alpha_w[:, None] * sig_w * lv
-        dw = dw.at[labels[:, None], li].add(jnp.where(owned, up_c, 0.0))
-        dw = dw.at[wrong[:, None], li].add(jnp.where(owned, -up_w, 0.0))
-        if confidence:
-            dp = jnp.where(owned, dp, 0.0)
-            dprec = dprec.at[labels[:, None], li].add(dp)
-            dprec = dprec.at[wrong[:, None], li].add(
-                jnp.where((alpha_w > 0.0)[:, None], dp, 0.0))
-        return w, dw, prec, dprec
+        li, lv = _owned(idx, val, w.shape[1], axis)
+        return train_rows(
+            w, dw, prec, dprec, li, lv, labels, label_mask, param,
+            method=method, reduce=lambda x: jax.lax.psum(x, axis))
 
     specs = tuple(state_spec(a, dim, axis) for a in state)
     out = shard_map(
@@ -195,15 +156,11 @@ def scores(mesh: Mesh, state: ClassifierState, idx: jax.Array,
     range, one psum assembles the [B, L] logits (replicated out). Same
     -inf dead-label convention as ops.scores."""
     dim = state.w.shape[-1]
-    neg = jnp.float32(-1e30)
 
     def body(w, dw, idx, val, label_mask):
-        d_local = w.shape[1]
-        li, lv, _ = _owned(idx, val, d_local, axis)
-        eff = w + dw
-        g = jnp.take(eff, li, axis=1)                      # [L, B, K]
-        s = jax.lax.psum(jnp.einsum("lbk,bk->bl", g, lv), axis)
-        return jnp.where(label_mask[None, :], s, neg)
+        li, lv = _owned(idx, val, w.shape[1], axis)
+        return score_rows(w, dw, li, lv, label_mask,
+                          reduce=lambda x: jax.lax.psum(x, axis))
 
     spec = state_spec(state.w, dim, axis)
     return shard_map(
@@ -278,8 +235,7 @@ def regression_estimate(mesh: Mesh, state, idx: jax.Array, val: jax.Array,
     """Feature-sharded batch estimates: [B], one psum of the per-shard
     partial dot products."""
     def body(w, dw, idx, val):
-        d_local = w.shape[0]
-        li, lv, _ = _owned(idx, val, d_local, axis)
+        li, lv = _owned(idx, val, w.shape[0], axis)
         eff = jnp.take(w, li) + jnp.take(dw, li)
         return jax.lax.psum(jnp.einsum("bk,bk->b", eff, lv), axis)
 

@@ -14,8 +14,8 @@ axes (parallel/mesh.py):
   collectives ride ICI.
 
 All computation is inside one shard_map'd jitted step: per-replica vectorized
-train (same math as ops/classifier.train_batch_parallel), optionally followed
-by the mix collective — so a mix round costs one AllReduce, no host round
+train (ops/classifier.train_rows, the single-chip path's own body), optionally
+followed by the mix collective — so a mix round costs one AllReduce, no host round
 trips (the north-star design, SURVEY.md §2.2).
 """
 
@@ -28,10 +28,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from jubatus_tpu.ops.classifier import (
-    CONFIDENCE_METHODS,
-    decide_updates,
-)
+from jubatus_tpu.ops.classifier import CONFIDENCE_METHODS, train_rows
+from jubatus_tpu.parallel.sharded_model import _owned
 
 
 def _state_pspec(mesh: Mesh) -> P:
@@ -82,54 +80,12 @@ def make_spmd_train_step(mesh: Mesh, *, method: str = "AROW", param: float = 1.0
         # local leaves: w [1, L, Dl]; idx/val [1, B, K]; labels [1, B]
         w, dw, prec, dprec = w[0], dw[0], prec[0], dprec[0]
         idx, val, labels = idx[0], val[0], labels[0]
-        d_local = w.shape[1]
-        lo = jax.lax.axis_index("shard") * d_local if n_shards > 1 else 0
-        li_raw = idx - lo
-        owned = (li_raw >= 0) & (li_raw < d_local)
-        li = jnp.where(owned, li_raw, 0)
-        lv = jnp.where(owned, val, 0.0)  # unowned features contribute 0 here
-
+        if n_shards > 1:
+            idx, val = _owned(idx, val, w.shape[1], "shard")
         # partial scores from the local feature shard, reduced over ICI
-        eff = w + dw
-        g = jnp.take(eff, li, axis=1)                      # [L, B, K]
-        s = _shard_psum(jnp.einsum("lbk,bk->bl", g, lv))
-        x2_vec_l = lv * lv
-        x2 = _shard_psum(jnp.sum(x2_vec_l, axis=1))
-
-        if confidence:
-            p = prec + dprec
-            pg = jnp.take(p, li, axis=1)                   # [L, B, K]
-            p_c = jnp.take_along_axis(pg, labels[None, :, None], axis=0)[0]
-            sig_c = jnp.where(owned, 1.0 / p_c, 0.0)
-            # first pass only to identify the competing label for sigma_w
-            wrong0, _, _, _ = decide_updates(
-                s, labels, label_mask, x2, jnp.zeros_like(x2), x2_vec_l,
-                param, method=method,
-            )
-            p_w = jnp.take_along_axis(pg, wrong0[None, :, None], axis=0)[0]
-            # nonexistent rival carries the unit precision prior
-            no_rival = jnp.sum(label_mask) < 2
-            sig_w = jnp.where(owned, jnp.where(no_rival, 1.0, 1.0 / p_w), 0.0)
-            v = _shard_psum(jnp.sum((sig_c + sig_w) * x2_vec_l, axis=1))
-        else:
-            sig_c = sig_w = jnp.where(owned, 1.0, 0.0)
-            v = jnp.zeros_like(x2)
-
-        # the one shared decision kernel (ops/classifier.decide_updates)
-        wrong, alpha, alpha_w, dp = decide_updates(
-            s, labels, label_mask, x2, v, x2_vec_l, param, method=method
-        )
-
-        up_c = alpha[:, None] * sig_c * lv
-        up_w = alpha_w[:, None] * sig_w * lv
-        dw = dw.at[labels[:, None], li].add(jnp.where(owned, up_c, 0.0))
-        dw = dw.at[wrong[:, None], li].add(jnp.where(owned, -up_w, 0.0))
-        if confidence:
-            dp = jnp.where(owned, dp, 0.0)
-            dprec = dprec.at[labels[:, None], li].add(dp)
-            dprec = dprec.at[wrong[:, None], li].add(
-                jnp.where((alpha_w > 0.0)[:, None], dp, 0.0)
-            )
+        w, dw, prec, dprec = train_rows(
+            w, dw, prec, dprec, idx, val, labels, label_mask, param,
+            method=method, reduce=_shard_psum)
 
         if mix:
             # THE mix round: one AllReduce over the replica axis

@@ -1379,8 +1379,8 @@ class EngineServer:
         st["rpc.transport"] = self.rpc.transport
         ingest = getattr(self, "ingest_stats", None)
         st["ingest.native"] = ingest is not None
-        # dense-submatrix (uniform key schema) plan engagement counters
-        # (service.py populates when the native fast path is registered)
+        # the native fast path's flush counters (service.py populates
+        # them when it is registered)
         for k, v in (ingest or {}).items():
             st[f"ingest.{k}"] = v
         st.update({f"driver.{k}": v for k, v in self.driver.get_status().items()})

@@ -138,96 +138,6 @@ def _updating(server: Any, fn: Callable, count: Callable[[Any], int] = lambda r:
 # -- per-engine binders -------------------------------------------------------
 
 
-class _ComboPlanCache:
-    """Device-expansion plans for combination-rule configs, keyed by the
-    base index row (the feature schema). The native parser hands back the
-    [B, K0] rows before the cross product beside the expanded ones
-    (native/ingest.py ``Cross``); where every row of a request shares its
-    base index row, the plan carries the full base+slot index vector and
-    the (a, b, op) bilinear terms the device expands (ops._expand_combo),
-    and only the base columns are shipped. Slot hashes and pair structure
-    come from the Python converter's own combo plan
-    (core/fv/converter.py) — the single owner of combination semantics —
-    validated against the C++ row by hashing a sample datum's base names.
-    Requests the plan cannot serve exactly (rows of differing schemas,
-    hash collisions, idf/user weights, multi-term slots) keep the rows the
-    same parse expanded on the host: nothing is parsed twice."""
-
-    _MISS = object()
-
-    class Plan:
-        __slots__ = ("uidx", "a_idx", "b_idx", "mul_mask")
-
-        def __init__(self, uidx, a_idx, b_idx, mul_mask):
-            self.uidx = uidx
-            self.a_idx = a_idx
-            self.b_idx = b_idx
-            self.mul_mask = mul_mask
-
-    def __init__(self, converter) -> None:
-        self._converter = converter  # the driver's full converter
-        self._plans: Dict[bytes, Any] = {}
-
-    def plan_for(self, base_idx, raw_params: bytes, with_labels: bool):
-        """The plan for a request's rows before the cross product, or None
-        where they do not share one index row or no exact plan exists."""
-        if base_idx.shape[0] == 0:
-            return None
-        row0 = base_idx[0]
-        if base_idx.shape[0] > 1 and not (base_idx == row0).all():
-            return None  # mixed schemas in one request
-        return self._plan_for(row0, raw_params, with_labels)
-
-    def _plan_for(self, row0, raw_params: bytes, with_labels: bool):
-        key = row0.tobytes()
-        plan = self._plans.get(key, self._MISS)
-        if plan is not self._MISS:
-            return plan
-        plan = self._build(row0, raw_params, with_labels)
-        if len(self._plans) >= 64:
-            self._plans.clear()
-        self._plans[key] = plan
-        return plan
-
-    def _build(self, row0, raw_params: bytes, with_labels: bool):
-        import msgpack
-
-        from jubatus_tpu.core.datum import Datum
-
-        try:
-            req = msgpack.unpackb(raw_params, raw=False,
-                                  strict_map_key=False, use_list=True,
-                                  unicode_errors="surrogateescape")
-            wire = req[1][0][1] if with_labels else req[1][0]
-            datum = Datum.from_msgpack(wire)
-        except Exception:  # broad-ok — undecodable sample: decline plan
-            return None
-        conv = self._converter
-        named = conv._base_named_features(datum)
-        names = list(named)
-        live = row0[row0 != 0]
-        if len(names) != live.shape[0]:
-            return None  # hash collision merged base columns
-        idxs, kinds = conv._resolve_names(names)
-        order = np.argsort(idxs, kind="stable")
-        if not np.array_equal(idxs[order], live.astype(np.int32)):
-            return None  # sample's schema does not explain the row
-        if kinds.any():
-            return None  # base features must be bin-weighted
-        sorted_names = tuple(names[i] for i in order)
-        cplan = conv._combo_plan_for(sorted_names)
-        if cplan.slot_kind.any():
-            return None  # combo slots must be bin-weighted
-        if cplan.t_starts.shape[0] != cplan.a_idx.shape[0]:
-            return None  # multi-term slots: host semantics required
-        nz = np.concatenate([live.astype(np.int32), cplan.slot_idx])
-        if np.unique(nz).shape[0] != nz.shape[0]:
-            return None  # index collision: expansion would double-count
-        uidx = np.concatenate([row0.astype(np.int32), cplan.slot_idx])
-        return self.Plan(uidx, cplan.a_idx, cplan.b_idx,
-                         cplan.mul_mask.astype(bool))
-
-
 def _quality_observe_pairs(server: Any, pairs) -> None:
     """Prequential (test-then-train) hook for the generic train path
     (ISSUE 17): on sampled batches, score a bounded prefix with the
@@ -256,17 +166,14 @@ def _quality_observe_raw(server: Any, item, numeric: bool) -> None:
     """Prequential + feature-stat hook for the native raw-ingest path:
     names never materialize here, so values record under the ``hashed``
     group; scoring rides classify_hashed/estimate_hashed on a bounded
-    row prefix. Combo-plan items skip scoring (the base arrays are not
-    the model's input rows)."""
+    row prefix."""
     q = getattr(server, "quality", None)
     if q is None or not q.admit("train"):
         return
     d = server.driver
-    tag, labels, idx, val = item
+    labels, idx, val = item
     try:
         q.record_hashed(val)
-        if tag[0] != "plain":
-            return
         k = min(q.max_score_rows, idx.shape[0])
         if numeric:
             if hasattr(d, "estimate_hashed"):
@@ -408,29 +315,9 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
         return (np.concatenate(parts_i) if len(parts_i) > 1 else parts_i[0],
                 np.concatenate(parts_v) if len(parts_v) > 1 else parts_v[0])
 
-    def _uniform_row(pairs):
-        """The shared index row if EVERY row of every (idx, val) pair
-        equals the first one (same width), else None. A fixed key schema
-        — the common production feed shape — hashes every datum to the
-        same index vector; detecting it per flush costs ~B*K int
-        compares (~0.02 µs/sample) and unlocks the dense submatrix train
-        plan (ops.train_batch_schema: no B*K-element gathers/scatters)."""
-        first = pairs[0][0]
-        row0 = first[0]
-        k = first.shape[1]
-        for ir, _vr in pairs:
-            if ir.shape[1] != k or not (ir == row0).all():
-                return None
-        return row0
-
-    schema_train = getattr(driver, "train_indexed_schema", None)
-    combo_train = getattr(driver, "train_indexed_combo", None)
-    # schema-plan accounting, surfaced by get_status ("ingest.*" keys,
-    # server/base.py) and the e2e bench: how often flushes actually ride
-    # the dense submatrix plan
-    stats = server.ingest_stats = {"schema_flushes": 0, "sparse_flushes": 0,
-                                   "combo_flushes": 0,
-                                   "schema_query_flushes": 0,
+    # what get_status shows as "ingest.*" (server/base.py): the train
+    # and the query flushes this path prepared
+    stats = server.ingest_stats = {"sparse_flushes": 0,
                                    "sparse_query_flushes": 0}
 
     # deferred-idf (pure-idf specs): parses run lock-free against zero df
@@ -440,28 +327,16 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
     weights = driver.converter.weights \
         if (parser.needs_weights or deferred) else None
 
-    # combination configs: the parser expands the cross product on the
-    # host and hands back the base rows too, so one parse serves whichever
-    # plan the request's rows allow. Uniform-schema requests (classifier)
-    # ship only the base columns and expand ON DEVICE
-    # (ops.train_batch_schema_combo); all others keep the expanded rows.
-    combo_ctx = None
-    if parser.combines and combo_train is not None and not numeric:
-        combo_ctx = _ComboPlanCache(driver.converter)
     trace = rpc.trace
 
-    def _crossed(cross, rows: int, raw_params: bytes, with_labels: bool):
-        """Account for one combination request's cross product (span
-        ``fv.combine`` is the parser's own clock around it) and return its
-        device-expansion plan, or None where the host's rows are used."""
+    def _crossed(cross, rows: int) -> None:
+        """Account for one combination request's cross product, which the
+        parser made on its own thread (span ``fv.combine`` is its own
+        clock around it)."""
         trace.record("fv.combine", cross.seconds)
         trace.count("fv.combine.rows", rows)
         trace.count("fv.combine.slots", cross.slots)
-        plan = combo_ctx.plan_for(cross.base_idx, raw_params, with_labels) \
-            if combo_ctx is not None else None
-        trace.count("fv.combine.native" if plan is None
-                    else "fv.combine.device")
-        return plan
+        trace.count("fv.combine.native")
 
     def _merge_labels(label_pairs):
         """Union per-request (uniq_labels, label_idx) pairs into one
@@ -478,72 +353,35 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
         return list(label_map), lidx
 
     def prep_requests(reqs):
-        """Stage 1 (host) of the pipelined flush: merge per-request
-        arrays into ONE device-ready batch — label-map union, width
-        pad+concat, deferred-idf observe+scale, execution-plan selection.
+        """Stage 1 (host) of the pipelined flush: merge the requests'
+        (labels, idx, val) into ONE device-ready batch of the same shape
+        — label-map union, width pad+concat, deferred-idf observe+scale.
         Runs on the flusher thread while the device consumes the
         previous batch."""
         if not reqs:
             return None
-        combo = [r for r in reqs if r[0][0] == "combo"]
-        if combo:
-            # (("combo", plan), labels, base_idx, base_val): group by
-            # plan (one group for a fixed-schema feed) for the
-            # device-expansion path; requests of the same flush that keep
-            # their host-expanded rows follow as a batch of their own
-            groups: dict = {}
-            for tag, lb, _ir, vr in combo:
-                entry = groups.setdefault(id(tag[1]), (tag[1], [], []))
-                entry[1].append(lb)
-                entry[2].append(vr)
-            out = []
-            for plan, lbs, vals in groups.values():
-                uniq, lidx = _merge_labels(lbs)
-                val = np.concatenate(vals) if len(vals) > 1 else vals[0]
-                out.append((uniq, lidx, plan, val))
-            stats["combo_flushes"] += 1
-            return ("combo", out, prep_requests(
-                [r for r in reqs if r[0][0] != "combo"]))
+        idx, val = _pad_concat([(ir, vr) for _lb, ir, vr in reqs])
         if numeric:
-            idx, val = _pad_concat([(ir, vr) for _t, _lb, ir, vr in reqs])
-            labels = np.concatenate([r[1] for r in reqs]) \
-                if len(reqs) > 1 else reqs[0][1]
-            return ("numeric", labels, idx, val)
-        uniq, lidx = _merge_labels([lb for _t, lb, _i, _v in reqs])
-        if schema_train is not None and not deferred:
-            row0 = _uniform_row([(ir, vr) for _t, _lb, ir, vr in reqs])
-            if row0 is not None:
-                stats["schema_flushes"] += 1
-                val = np.concatenate([vr for _t, _lb, _ir, vr in reqs]) \
-                    if len(reqs) > 1 else reqs[0][3]
-                return ("schema", uniq, lidx, row0, val)
-        stats["sparse_flushes"] += 1
-        idx, val = _pad_concat([(ir, vr) for _t, _lb, ir, vr in reqs])
+            labels = np.concatenate([r[0] for r in reqs]) \
+                if len(reqs) > 1 else reqs[0][0]
+        else:
+            labels = _merge_labels([r[0] for r in reqs])
+            stats["sparse_flushes"] += 1
         if deferred:
             from jubatus_tpu.native.ingest import deferred_idf_scale
 
             val = deferred_idf_scale(idx, val, weights, observe=True)
-        return ("sparse", uniq, lidx, idx, val)
+        return labels, idx, val
 
     def apply_prepared(prepared):
-        """Stage 2 (device): dispatch the prepared batch onto the
-        matching driver plan."""
+        """Stage 2 (device): hand the prepared batch to the driver, which
+        settles the step's plan from the arrays."""
         if prepared is None:
             return 0
-        kind = prepared[0]
-        if kind == "combo":
-            n = apply_prepared(prepared[2])
-            for uniq, lidx, plan, val in prepared[1]:
-                n += combo_train(uniq, lidx, plan.uidx, val,
-                                 plan.a_idx, plan.b_idx, plan.mul_mask)
-            return n
-        if kind == "numeric":
-            return driver.train_hashed(prepared[1], prepared[2], prepared[3])
-        if kind == "schema":
-            return schema_train(prepared[1], prepared[2], prepared[3],
-                                prepared[4])
-        return driver.train_indexed(prepared[1], prepared[2], prepared[3],
-                                    prepared[4])
+        labels, idx, val = prepared
+        if numeric:
+            return driver.train_hashed(labels, idx, val)
+        return driver.train_indexed(*labels, idx, val)
 
     max_batch = getattr(server.args, "microbatch_max", 8192)
     wait_s = server.args.timeout * 6 if server.args.timeout > 0 else None
@@ -555,7 +393,7 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
 
         co = PipelinedCoalescer(
             prep_requests, device_step, max_batch=max_batch,
-            weigher=lambda item: item[2].shape[0], trace=rpc.trace,
+            weigher=lambda item: item[1].shape[0], trace=rpc.trace,
             name="train_raw")
         server.coalescers["train_raw"] = co
         co.usage_hook = _usage_batch_hook(server, "train")
@@ -580,15 +418,12 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
         if numeric != isinstance(labels, np.ndarray):
             return RAW_FALLBACK  # label kind mismatch: let the
             # generic path produce the proper type error
-        item = (("plain",), labels, idx, val)
-        if cross is not None and idx.shape[0]:
-            plan = _crossed(cross, idx.shape[0], raw_params, True)
-            if plan is not None:
-                item = (("combo", plan), labels, cross.base_idx,
-                        cross.base_val)
-        n = item[2].shape[0]
+        n = idx.shape[0]
         if n == 0:
             return 0
+        if cross is not None:
+            _crossed(cross, n)
+        item = (labels, idx, val)
         # test-then-train: prequential scoring sees the pre-update model
         _quality_observe_raw(server, item, numeric)
         if max_batch:
@@ -618,30 +453,15 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
                 return parser.parse_datums(raw_params, weights=weights)
         return parser.parse_datums(raw_params)
 
-    def _query_coalescer(name: str, score_batch, schema_score=None):
+    def _query_coalescer(name: str, score_batch):
         """Query-plane microbatching (the mirror of the train coalescer):
         concurrent read requests join ONE device dispatch against the
         same model snapshot — every kernel launch costs ~ms on an
         accelerator regardless of batch size, so per-request dispatch
         caps the query plane at launches/s, not samples/s.
         ``score_batch(idx, val) -> per-row results``; each request gets
-        exactly its rows back (Coalescer split_results).
-        ``schema_score(uidx, val)`` is the uniform-schema dense variant,
-        taken whenever the flush's rows all share one index vector."""
+        exactly its rows back (Coalescer split_results)."""
         def query_flush(items):
-            if schema_score is not None:
-                row0 = _uniform_row(items)
-                if row0 is not None:
-                    stats["schema_query_flushes"] += 1
-                    if len(items) == 1:
-                        return [schema_score(row0, items[0][1])]
-                    vals = np.concatenate([v for _i, v in items])
-                    rows = schema_score(row0, vals)
-                    out, off = [], 0
-                    for i, _ in items:
-                        out.append(rows[off:off + i.shape[0]])
-                        off += i.shape[0]
-                    return out
             stats["sparse_query_flushes"] += 1
             if len(items) == 1:
                 i, v = items[0]
@@ -667,12 +487,10 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
 
         return scored
 
-    def _raw_query(scored, cross_scored=None):
+    def _raw_query(scored):
         """The raw handler of a read method: parse, then ``scored(idx,
-        val)`` (a query coalescer, or the driver where coalescing is off).
-        ``cross_scored(plan, base_val)``: what a combination request scores
-        with where its rows allow the device-expansion plan (plans exist
-        for the classifier alone: combo_ctx)."""
+        val)`` (a query coalescer, or the driver where coalescing is
+        off)."""
         def raw_handler(raw_params: bytes):
             cross = None
             with trace.span("fv.convert"):
@@ -688,9 +506,7 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
             if idx.shape[0] == 0:
                 return []
             if cross is not None:
-                plan = _crossed(cross, idx.shape[0], raw_params, False)
-                if plan is not None:
-                    return cross_scored(plan, cross.base_val)
+                _crossed(cross, idx.shape[0])
             return scored(idx, val)
 
         return raw_handler
@@ -700,15 +516,9 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
             _query_coalescer("estimate_raw", driver.estimate_hashed)
             if max_batch else driver.estimate_hashed))
     elif not numeric and hasattr(driver, "classify_hashed"):
-        def cross_scored(plan, base_val):
-            return driver.classify_hashed_combo(
-                plan.uidx, base_val, plan.a_idx, plan.b_idx, plan.mul_mask)
-
         rpc.register_raw("classify", _raw_query(
-            _query_coalescer("classify_raw", driver.classify_hashed,
-                             schema_score=getattr(
-                                 driver, "classify_hashed_schema", None))
-            if max_batch else driver.classify_hashed, cross_scored))
+            _query_coalescer("classify_raw", driver.classify_hashed)
+            if max_batch else driver.classify_hashed))
 
 
 @_binder("classifier")

@@ -622,8 +622,9 @@ struct JtIngestOut {
   int32_t uniq;        // distinct labels in labels/label_off
   int32_t* label_idx;  // [batch] row -> distinct-label index
   // combination specs only (null / 0 otherwise): the rows BEFORE the
-  // cross product, packed like idx/val, so that one parse serves both the
-  // host expansion and the device expansion of a uniform-schema batch
+  // cross product, packed like idx/val. The server no longer reads them
+  // (the device expansion they fed went in PR 28); the benchmark's test
+  // of the parser does, and they go with it (ROADMAP R-B1)
   int32_t base_width;  // a power of two, >= 8
   int32_t* base_idx;   // [batch, base_width]
   float* base_val;     // [batch, base_width]

@@ -230,6 +230,11 @@ def _flush(rng, scenario, dim, method, width=None):
     elif scenario == "padding":
         idx[:, k // 2:] = 0
         val[:, k // 2:] = 0.0
+    elif scenario == "uniform_rows":    # a fixed key schema: one index row,
+        idx[:] = idx[0]                 # its width padding, some empty rows
+        idx[:, -2:] = 0
+        val[:, -2:] = 0.0
+        val[1::3] = 0.0
     mask = jnp.asarray(np.arange(cap) < live)
     return state, jnp.asarray(idx), jnp.asarray(val), jnp.asarray(labels), mask
 
@@ -255,7 +260,8 @@ def _flush_by_the_per_datum_rule(state, idx, val, labels, mask, method):
     return dw, dprec
 
 
-SCENARIOS = ["hot_column", "single_label", "padding", "grown_16", "ragged"]
+SCENARIOS = ["hot_column", "single_label", "padding", "grown_16", "ragged",
+             "uniform_rows"]
 
 
 @pytest.mark.parametrize("method,scenario,dim,plan,width", [
@@ -287,6 +293,17 @@ def test_a_flush_is_its_rows_by_the_per_datum_rule(method, scenario, dim, plan,
         assert now[:, cold].tobytes() == was[:, cold].tobytes()
     assert got[0].tobytes() == before[0].tobytes()
     assert got[2].tobytes() == before[2].tobytes()
+    if scenario == "uniform_rows":
+        # every column is hit by every row, the padding slot is left alone,
+        # and the dense plan the driver takes for such a flush is the same
+        # flush to float tolerance
+        assert got[1][:, 0].tobytes() == before[1][:, 0].tobytes()
+        dense = C.train_batch_schema(_fresh(state), idx[0], val, labels, mask,
+                                     1.0, method=method)
+        np.testing.assert_allclose(np.asarray(dense.dw), want_dw,
+                                   rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(np.asarray(dense.dprec), want_dprec,
+                                   rtol=2e-5, atol=2e-6)
     if scenario == "padding":
         # column 0 is the padding slot: (idx 0, val 0) entries leave it alone,
         # and the flush without them is the same flush

@@ -1,9 +1,9 @@
 """Uniform-schema dense train/score path ≡ the sparse path.
 
 A fixed key schema (every datum hashes to the same index vector) lets
-the serving plane run the classifier step as dense matmuls over the
+the driver run the classifier step as dense matmuls over the
 [L, K] submatrix instead of B*K-element gathers/scatters
-(ops.classifier.train_batch_schema / scores_schema). Same semantics as
+(ops.classifier.train_batch_schema). Same semantics as
 train_batch_parallel — batch-start snapshot, updates land together —
 different execution plan, so agreement is to tolerance, not bitwise.
 Reference semantics: classifier_serv.cpp:127-146's per-datum update,
@@ -56,17 +56,20 @@ def test_schema_train_matches_parallel(method):
                                rtol=2e-4, atol=1e-5)
 
 
-def test_schema_scores_match_sparse():
+def test_schema_trained_model_scores_as_the_sparse_trained_one():
     uidx, val, labels = _mk(seed=1)
     mask = jnp.ones(L, dtype=bool)
-    st = C.init_state(L, D, confidence=True)
-    st = C.train_batch_schema(st, jnp.asarray(uidx), jnp.asarray(val),
-                              jnp.asarray(labels), mask, 1.0, method="AROW")
     tiled = jnp.asarray(np.broadcast_to(uidx, (B, K)).copy())
-    s_sparse = np.asarray(C.scores(st, tiled, jnp.asarray(val), mask))
-    s_dense = np.asarray(C.scores_schema(st, jnp.asarray(uidx),
-                                         jnp.asarray(val), mask))
-    np.testing.assert_allclose(s_sparse, s_dense, rtol=1e-5, atol=1e-6)
+    st_a = C.train_batch_parallel(
+        C.init_state(L, D, confidence=True), tiled, jnp.asarray(val),
+        jnp.asarray(labels), mask, 1.0, method="AROW")
+    st_b = C.train_batch_schema(
+        C.init_state(L, D, confidence=True), jnp.asarray(uidx),
+        jnp.asarray(val), jnp.asarray(labels), mask, 1.0, method="AROW")
+    s_a = np.asarray(C.scores(st_a, tiled, jnp.asarray(val), mask))
+    s_b = np.asarray(C.scores(st_b, tiled, jnp.asarray(val), mask))
+    assert np.abs(s_a).max() > 0.0
+    np.testing.assert_allclose(s_a, s_b, rtol=2e-4, atol=1e-5)
 
 
 def test_schema_duplicate_pad_columns_are_noops():

@@ -1,12 +1,10 @@
-"""A combination-rule configuration on the server's normal path (ISSUE 26):
-one native parse serves whichever plan a request's rows allow. Rows of
-differing schemas keep the cross product the parse threads made
-(``fv.combine.native``), rows that share one index row ship their base
-columns and expand on the device (``fv.combine.device``,
-``train_batch_schema_combo``), and what the native parser does not serve is
-expanded by the Python converter (``fv.combine.generic``). Each request is
-counted once, by the counter of the path it took, and all three build the
-same model."""
+"""A combination-rule configuration on the server's normal path (ISSUE 26,
+28): the native parse makes the cross product on its own threads
+(``fv.combine.native``), whatever the rows; what the native parser does not
+serve is expanded by the Python converter (``fv.combine.generic``). Each
+request is counted once, by the counter of the path it took, and both build
+the same model. The service hands the expanded rows to the driver, which
+takes its dense plan where a flush's rows all carry one index row."""
 
 from __future__ import annotations
 
@@ -29,7 +27,7 @@ CONF = {
         "hash_max_size": 1 << 18,
     },
 }
-PATHS = ("native", "device", "generic")
+PATHS = ("native", "generic")
 N_NUM, N_STR = 5, 4
 PAIRS = (N_NUM + N_STR) * (N_NUM + N_STR - 1) // 2
 
@@ -99,9 +97,10 @@ def test_each_path_is_counted_once_and_all_build_the_same_model(monkeypatch):
             took = {p: after_mixed[p] - before[p] for p in PATHS}
             took_u = {p: after_uniform[p] - after_mixed[p] for p in PATHS}
             if native:
-                assert took == {"native": 1, "device": 0, "generic": 0}
-                assert took_u == {"native": 0, "device": 1, "generic": 0}
+                assert took == {"native": 1, "generic": 0}
+                assert took_u == {"native": 1, "generic": 0}
                 counters = srv.rpc.trace.counters()
+                assert "fv.combine.device" not in counters
                 # the parse threads made every pair of every row, and the
                 # probe's classify was counted by its rows' path too
                 assert counters["fv.combine.rows"] == 80 + 40
@@ -110,26 +109,27 @@ def test_each_path_is_counted_once_and_all_build_the_same_model(monkeypatch):
                     + 30 * PAIRS + 10 * (N_NUM * (N_NUM - 1) // 2)
                 hist = srv.rpc.trace.trace_status()
                 assert hist["trace.fv.combine.count"] >= 3
-                # the uniform rows rode the device expansion
-                assert srv.ingest_stats["combo_flushes"] == 1
-                assert srv.ingest_stats["sparse_flushes"] == 1
+                # one request shape: both calls were flushes of the one kind
+                assert srv.ingest_stats == {"sparse_flushes": 2,
+                                            "sparse_query_flushes": 1}
             else:
-                assert took == {"native": 0, "device": 0, "generic": 1}
-                assert took_u == {"native": 0, "device": 0, "generic": 1}
+                assert took == {"native": 0, "generic": 1}
+                assert took_u == {"native": 0, "generic": 1}
         finally:
             srv.stop()
     np.testing.assert_allclose(seen[True], seen[False], rtol=2e-5, atol=2e-6)
 
 
-def test_a_uniform_feed_rides_train_batch_schema_combo(monkeypatch):
-    """Whole flushes of one schema take the device expansion; the train
-    program that ran is the schema-combo one."""
+def test_a_uniform_feed_is_expanded_by_the_parser_and_rides_the_dense_plan(
+        monkeypatch):
+    """Whole flushes of one schema: the host's cross product, counted
+    ``native``, and the driver's dense plan on the expanded rows."""
     from jubatus_tpu.client import ClassifierClient
     from jubatus_tpu.ops import classifier as ops
 
     calls = []
-    real = ops.train_batch_schema_combo
-    monkeypatch.setattr(ops, "train_batch_schema_combo",
+    real = ops.train_batch_schema
+    monkeypatch.setattr(ops, "train_batch_schema",
                         lambda *a, **k: calls.append(a[2].shape) or real(
                             *a, **k))
     srv, port = _server(True, monkeypatch)
@@ -137,17 +137,20 @@ def test_a_uniform_feed_rides_train_batch_schema_combo(monkeypatch):
         with ClassifierClient("127.0.0.1", port, "") as c:
             for seed in range(3):
                 assert c.train(_rows(20, seed, True)) == 20
-        assert _counts(srv) == {"native": 0, "device": 3, "generic": 0}
-        # [rows (bucketed), K0 base columns]: the wide row was not shipped
-        assert calls and all(shape[1] <= 8 for shape in calls), calls
-        assert srv.ingest_stats["combo_flushes"] == 3
+        assert _counts(srv) == {"native": 3, "generic": 0}
+        # [rows (bucketed), the base columns and every pair of them]
+        wide = N_NUM + N_NUM * (N_NUM - 1) // 2
+        assert len(calls) == 3 and all(
+            shape[0] == 32 and shape[1] >= wide for shape in calls), calls
+        assert srv.rpc.trace.counters()["step.train.plan_schema"] == 3
+        assert srv.ingest_stats["sparse_flushes"] == 3
     finally:
         srv.stop()
 
 
 def test_a_flush_of_both_kinds_of_request_trains_every_row(monkeypatch):
-    """Requests of the two plans that meet in one flush: the device
-    expansion's groups and the host-expanded rest are both applied."""
+    """Uniform requests and requests of differing schemas that meet in one
+    flush are one batch of expanded rows, all of them applied."""
     from jubatus_tpu.client import ClassifierClient
 
     srv, port = _server(True, monkeypatch)
@@ -167,7 +170,7 @@ def test_a_flush_of_both_kinds_of_request_trains_every_row(monkeypatch):
             for t in threads:
                 t.join(60)
             assert co.stats()["item_count"] - before == 60
-        took = _counts(srv)
-        assert took["native"] == 1 + 3 and took["device"] == 3
+            assert sum(c.get_labels().values()) == 64
+        assert _counts(srv) == {"native": 1 + 6, "generic": 0}
     finally:
         srv.stop()

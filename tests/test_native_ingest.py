@@ -306,6 +306,49 @@ def test_server_idf_fast_path_matches_converter_path():
         slow.stop()
 
 
+def test_server_idf_fast_path_regression_matches_converter_path():
+    """The same for numeric targets: a regression flush under a pure-idf
+    config gets the flush-time observe + scale too (it was skipped until
+    the raw path had one request shape), so both servers estimate alike."""
+    from jubatus_tpu.client import RegressionClient
+    from jubatus_tpu.server import EngineServer
+    from jubatus_tpu.server.args import ServerArgs
+
+    conf = {"method": "PA", "parameter": {"sensitivity": 0.1,
+                                          "regularization_weight": 1.0},
+            "converter": {"string_rules": [
+                {"key": "*", "type": "space", "sample_weight": "tf",
+                 "global_weight": "idf"}]}}
+    fast = EngineServer("regression", conf,
+                        args=ServerArgs(engine="regression"))
+    fast_port = fast.start(0)
+    slow = EngineServer("regression", conf,
+                        args=ServerArgs(engine="regression"))
+    slow_port = slow.start(0)
+    slow.rpc._raw_methods.clear()  # force the converter path
+    try:
+        assert "train" in fast.rpc._raw_methods
+        data = [[3.0, Datum({"t": "win money now now"})],
+                [1.0, Datum({"t": "meet at noon"})],
+                [4.0, Datum({"t": "money money fast"})],
+                [2.0, Datum({"t": "noon lunch plan"})]]
+        with RegressionClient("127.0.0.1", fast_port, "t") as cf, \
+                RegressionClient("127.0.0.1", slow_port, "t") as cs:
+            for _ in range(5):
+                assert cf.train(data) == 4
+                assert cs.train(data) == 4
+            probe = [Datum({"t": "money now"}), Datum({"t": "noon plan"})]
+            np.testing.assert_allclose(cf.estimate(probe), cs.estimate(probe),
+                                       rtol=1e-5, atol=1e-6)
+        assert fast.coalescers["train_raw"].stats()["item_count"] == 20
+        np.testing.assert_array_equal(
+            fast.driver.converter.weights._df_diff,
+            slow.driver.converter.weights._df_diff)
+    finally:
+        fast.stop()
+        slow.stop()
+
+
 def test_server_fallback_on_undecodable_fast_wire():
     """A train request whose first slot kind defies the engine (numeric
     label on a classifier) must fall back to the generic path and behave

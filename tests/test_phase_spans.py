@@ -223,31 +223,29 @@ def test_a_driver_without_a_server_records_nothing():
     assert reg.counters()["step.classify.rows_padded"] == 16
 
 
-@pytest.mark.parametrize("plan", ["schema", "combo"])
-def test_the_other_train_plans_go_through_the_same_helper(plan):
+@pytest.mark.parametrize("plan", ["schema", "columns"])
+def test_the_driver_settles_the_train_plan_from_its_rows(plan):
+    """train_indexed takes the dense plan where every row carries the
+    same index row and the sparse one otherwise; both go through the one
+    tail, so they record the same phases."""
     from jubatus_tpu.models.classifier import ClassifierDriver
 
     d = ClassifierDriver(CONF, dim_bits=14)
     d.trace = reg = tracing.Registry()
-    uidx = np.arange(1, 7, dtype=np.int32)
+    idx = np.tile(np.arange(1, 7, dtype=np.int32), (3, 1))
+    if plan != "schema":
+        idx[2, 0] = 9
+    val = np.ones((3, 6), np.float32)
     lidx = np.array([0, 1, 0], np.int32)
-    if plan == "schema":
-        val = np.ones((3, 6), np.float32)
-        assert d.train_indexed_schema(["a", "b"], lidx, uidx, val) == 3
-        assert len(d.classify_hashed_schema(uidx, val)) == 3
-    else:
-        base = np.ones((3, 4), np.float32)
-        a = np.array([0, 1], np.int32)
-        b = np.array([2, 3], np.int32)
-        mul = np.array([True, False])
-        assert d.train_indexed_combo(["a", "b"], lidx, uidx, base,
-                                     a, b, mul) == 3
-        assert len(d.classify_hashed_combo(uidx, base, a, b, mul)) == 3
+    assert d.train_indexed(["a", "b"], lidx, idx, val) == 3
+    assert len(d.classify_hashed(idx, val)) == 3
     st = reg.trace_status()
     for name in ["step.train.stage", "step.train.dispatch"] + CLASSIFY_STEPS:
         assert st[f"trace.{name}.count"] == 1, name
     c = reg.counters()
     assert (c["step.train.rows"], c["step.train.rows_padded"]) == (3, 16)
+    assert [k for k in c if k.startswith("step.train.plan_")] == \
+        [f"step.train.plan_{plan}"]
 
 
 def test_named_scopes_are_in_the_lowered_programs():

@@ -37,7 +37,8 @@ def _batch(rng, b=B, k=K, dim=D):
 
 
 @pytest.mark.parametrize("n_shards", SHARD_COUNTS)
-@pytest.mark.parametrize("method", ("AROW", "PA1", "CW"))
+# all seven: the mesh runs the body they share (ops.train_rows)
+@pytest.mark.parametrize("method", cops.METHODS)
 # 40: a rung of the width ladder that is no power of two (39 features)
 @pytest.mark.parametrize("k", (K, 40))
 def test_train_and_scores_parity(method, n_shards, k, rng):
