@@ -604,12 +604,22 @@ def leg_sharded(fleet: Fleet, size: dict, n: int = 4) -> Dict[str, Any]:
           f"sharded: weight table on {devices!r}")
     check(st["driver.shard.shard_shape"][1] * n == size["dim"],
           f"sharded: shard shape {st['driver.shard.shard_shape']}")
+    # a device's bytes are the fullest chip's: one shard of the tables
+    # and a flush's inputs, never the four chips' sum
+    in_use = st.get("runtime.jax_device_bytes_in_use")
     if not fleet.rehearse:
         check(all("TPU" in d.upper() for d in devices),
               f"sharded: shards on {devices!r}")
+        per_shard = st["driver.shard.bytes_per_shard"]
+        check(in_use is not None and per_shard <= in_use < 2 * per_shard,
+              f"sharded: {in_use} device bytes in use on the fullest chip, "
+              f"{per_shard} of tables a shard")
     child.terminate_cleanly()
     out.update(dim=size["dim"], device=device, shard_devices=devices,
                shard_shape=st["driver.shard.shard_shape"],
+               device_bytes_in_use=in_use,
+               devices_bytes_in_use_total=st.get(
+                   "runtime.jax_devices_bytes_in_use_total"),
                **compile_report(st))
     return out
 

@@ -20,6 +20,7 @@ from __future__ import annotations
 import json
 from typing import Any, Dict, List, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -92,28 +93,43 @@ class ClassifierDriver(DriverBase):
                 mesh, mesh_axis, self.converter.hasher.dim_bits,
                 ClassifierConfigError, rank=2)
         self._confidence = method in ops.CONFIDENCE_METHODS
+        self.state = None
         self._init_model()
+
+    def _leaf_sharding(self, shape):
+        """The mesh's feature sharding for an [L, D] leaf; None for the
+        (1, 1) placeholders, and without a mesh."""
+        if (self._sharding is not None and len(shape) == 2
+                and shape[1] == self.converter.dim):
+            return self._sharding
+        return None
 
     def _place(self, state: ops.ClassifierState) -> ops.ClassifierState:
         """Pin [L, D] leaves to the feature-sharded layout (no-op without
         a mesh; (1,1) placeholders stay replicated)."""
         if self._sharding is None:
             return state
-        import jax
+        return ops.ClassifierState(*(
+            jax.device_put(leaf, self._leaf_sharding(leaf.shape))
+            for leaf in state))
 
-        def put(a):
-            if a.ndim == 2 and a.shape[1] == self.converter.dim:
-                return jax.device_put(a, self._sharding)
-            return a
-
-        return ops.ClassifierState(*(put(leaf) for leaf in state))
+    def _let_go(self) -> None:
+        """Drop the state there is before a new one is made: two do not
+        fit where one fills over half a chip (8.59e9 B at D = 2^28 over
+        four). Queued steps still hold the old buffers, so wait for them
+        first: the release is then immediate."""
+        jax.block_until_ready(self.state)
+        self.state = None
 
     def _init_model(self) -> None:
         self.labels: List[str] = []           # slot -> label name
         self.label_slots: Dict[str, int] = {}  # label name -> slot
         self.capacity = _INITIAL_CAPACITY
-        self.state = self._place(
-            ops.init_state(self.capacity, self.converter.dim, self._confidence))
+        self._let_go()
+        # born in its layout: on a mesh no [L, D] leaf ever lies whole on
+        # one device
+        self.state = ops.init_state(self.capacity, self.converter.dim,
+                                    self._confidence, self._sharding)
         self.label_counts = np.zeros(self.capacity, dtype=np.float32)
         self._dcounts = np.zeros(self.capacity, dtype=np.float32)
 
@@ -135,8 +151,8 @@ class ClassifierDriver(DriverBase):
             slot = free[0]
         else:
             self.capacity *= 2
-            self.state = self._place(
-                ops.grow_labels(self.state, self.capacity))
+            self.state = ops.grow_labels(self.state, self.capacity,
+                                         self._sharding)
             self.label_counts = np.pad(self.label_counts, (0, self.capacity // 2))
             self._dcounts = np.pad(self._dcounts, (0, self.capacity // 2))
             slot = len(self.labels)
@@ -223,6 +239,10 @@ class ClassifierDriver(DriverBase):
         exact per-datum semantics take priority."""
         bsz = _bucket(b, 16)
         schema = uniform and self.train_mode == "parallel"
+        sharded = (not schema and self._mesh is not None
+                   and self.train_mode == "parallel")
+        trace = self.trace
+        owned = None
         with self._span("step.train.stage"):
             if bsz != b:  # zero rows are no-ops (x2 = 0 → alpha 0)
                 idx = np.pad(idx, ((0, bsz - b), (0, 0)))
@@ -232,6 +252,8 @@ class ClassifierDriver(DriverBase):
             didx = jnp.asarray(idx[0] if schema else idx)
             dval, dslots = jnp.asarray(val), jnp.asarray(slots_arr)
             mask = self._mask()
+            if sharded and trace is not None:
+                owned = self._shard_entries(idx)
         plan = None
         with self._span("step.train.dispatch"):
             if schema:
@@ -239,11 +261,15 @@ class ClassifierDriver(DriverBase):
                 self.state = ops.train_batch_schema(
                     self.state, didx, dval, dslots, mask, self.param,
                     method=self.method)
-            elif self._mesh is not None and self.train_mode == "parallel":
+            elif sharded:
                 # shard_map path: batch routed by column range, one psum
-                # for the logits — weight state never moves (ISSUE 13)
+                # for the logits — weight state never moves (ISSUE 13).
+                # Each shard settles its plan from its own slice's shape
                 from jubatus_tpu.parallel import sharded_model as _sm
 
+                n_shards = self._mesh.shape[self._mesh_axis]
+                num_labels, dim = self.state.w.shape
+                plan = ops.gather_plan(num_labels, dim // n_shards, idx.size)
                 self.state = _sm.train_batch(
                     self._mesh, self.state, didx, dval, dslots, mask,
                     self.param, method=self.method, axis=self._mesh_axis)
@@ -256,7 +282,6 @@ class ClassifierDriver(DriverBase):
                     self.state, didx, dval, dslots, mask, self.param,
                     method=self.method, mode=self.train_mode)
         self.event_model_updated(b)
-        trace = self.trace
         if trace is not None:
             if plan is not None:
                 # which plan the step's rows and shapes settled on
@@ -270,11 +295,38 @@ class ClassifierDriver(DriverBase):
             trace.count("step.train.rows", b)
             trace.count("step.train.rows_padded", bsz)
             trace.count(f"step.train.width_{idx.shape[1]}")
-            trace.count("step.train.entries", int(np.count_nonzero(idx)))
+            trace.count("step.train.entries", int(
+                np.count_nonzero(idx) if owned is None else owned.sum()))
             trace.count("step.train.entries_padded", b * idx.shape[1])
             trace.count("step.train.upload_bytes",
                         didx.nbytes + dval.nbytes + dslots.nbytes)
+            if owned is not None:
+                # the mesh's side: every shard is handed every entry of
+                # the padded flush and masks what it does not own
+                # (sharded_model._owned), so the chips issue shards x
+                # rows x width descriptors for the entries that carry a
+                # feature; the fullest shard's share says how evenly the
+                # columns fall
+                trace.count("step.train.shard_entries", int(owned.sum()))
+                trace.count("step.train.shard_entries_issued",
+                            len(owned) * idx.size)
+                trace.count("step.train.shard_entries_owned_max",
+                            int(owned.max()))
         return b
+
+    def _shard_entries(self, idx: np.ndarray) -> np.ndarray:
+        """The entries of a staged index array that carry a feature, by
+        the shard that owns their column: [shards]. Column 0 is padding
+        and no feature hashes there. One compare and one count for each
+        shard boundary: 0.17 ms at 8,192 x 40 over four shards on the
+        four-chip host, where one bincount of ``idx >> log2(D/N)`` took
+        0.50 (PERF.md section 6, PR 31): stamped on every flush."""
+        n_shards = self._mesh.shape[self._mesh_axis]
+        d_local = self.converter.dim // n_shards
+        at_or_past = [np.count_nonzero(idx)] + [
+            np.count_nonzero(idx >= k * d_local)
+            for k in range(1, n_shards)] + [0]
+        return -np.diff(at_or_past)
 
     @locked
     def train_hashed(self, labels: Sequence[str], idx: np.ndarray,
@@ -498,11 +550,21 @@ class ClassifierDriver(DriverBase):
             s.decode() if isinstance(s, bytes) else s for s in obj["labels"]
         ]
         self.label_slots = {lab: i for i, lab in enumerate(self.labels) if lab}
-        w = jnp.asarray(obj["w"])
-        prec = jnp.asarray(obj["prec"])
-        self.state = self._place(ops.ClassifierState(
-            w=w, dw=jnp.zeros_like(w), prec=prec, dprec=jnp.zeros_like(prec)
-        ))
+        # each leaf goes from the host to where it lies: on a mesh every
+        # device is sent its own columns
+        self._let_go()
+
+        def put(a):
+            return jax.device_put(np.asarray(a),
+                                  self._leaf_sharding(np.shape(a)))
+
+        def zeros(a):
+            return jnp.zeros(np.shape(a), jnp.float32,
+                             device=self._leaf_sharding(np.shape(a)))
+
+        self.state = ops.ClassifierState(
+            w=put(obj["w"]), dw=zeros(obj["w"]),
+            prec=put(obj["prec"]), dprec=zeros(obj["prec"]))
         self.label_counts = np.asarray(obj["label_counts"], dtype=np.float32).copy()
         self._dcounts = np.zeros_like(self.label_counts)
         self.converter.weights.unpack(obj["weights"])

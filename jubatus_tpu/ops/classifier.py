@@ -73,19 +73,36 @@ class ClassifierState(NamedTuple):
     dprec: jax.Array
 
 
-def init_state(num_labels: int, dim: int, confidence: bool) -> ClassifierState:
+def init_state(num_labels: int, dim: int, confidence: bool,
+               sharding=None) -> ClassifierState:
+    """A fresh state. ``sharding``: where the [L, D] leaves are born (a
+    mesh's feature sharding, models/classifier.py): each device gets its
+    own columns and no leaf ever lies whole on one of them; None is the
+    default device. The (1, 1) placeholders stay where they were."""
     shape = (num_labels, dim)
     cshape = shape if confidence else (1, 1)
+    csharding = sharding if confidence else None
     return ClassifierState(
-        w=jnp.zeros(shape, jnp.float32),
-        dw=jnp.zeros(shape, jnp.float32),
-        prec=jnp.ones(cshape, jnp.float32),
-        dprec=jnp.zeros(cshape, jnp.float32),
+        w=jnp.zeros(shape, jnp.float32, device=sharding),
+        dw=jnp.zeros(shape, jnp.float32, device=sharding),
+        prec=jnp.ones(cshape, jnp.float32, device=csharding),
+        dprec=jnp.zeros(cshape, jnp.float32, device=csharding),
     )
 
 
-def grow_labels(state: ClassifierState, new_num_labels: int) -> ClassifierState:
-    """Host-side label-capacity growth (repack + recompile on next call)."""
+@functools.partial(jax.jit, static_argnames=("pad", "fill", "sharding"))
+def _pad_rows(a, *, pad: int, fill: float, sharding):
+    out = jnp.pad(a, ((0, pad), (0, 0)), constant_values=fill)
+    if sharding is None:
+        return out
+    return jax.lax.with_sharding_constraint(out, sharding)
+
+
+def grow_labels(state: ClassifierState, new_num_labels: int,
+                sharding=None) -> ClassifierState:
+    """Label-capacity growth (recompile on next call). The new rows are
+    made where the old ones lie: with ``sharding`` (init_state's) each
+    device pads its own columns."""
     L = state.w.shape[0]
     if new_num_labels <= L:
         return state
@@ -94,7 +111,7 @@ def grow_labels(state: ClassifierState, new_num_labels: int) -> ClassifierState:
     def _pad(a, fill):
         if a.shape == (1, 1):
             return a
-        return jnp.concatenate([a, jnp.full((pad, a.shape[1]), fill, a.dtype)], axis=0)
+        return _pad_rows(a, pad=pad, fill=fill, sharding=sharding)
 
     return ClassifierState(
         w=_pad(state.w, 0.0),

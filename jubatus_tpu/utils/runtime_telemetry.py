@@ -188,13 +188,17 @@ def _jax_sample() -> Dict[str, Any]:
         # live bytes now, and the allocator's high-water mark since the
         # process started: what it handed out between two samples (a
         # flush's inputs, a mix round's buffers). A compiled step's
-        # temporaries are in neither: memory_stats() leaves them out
+        # temporaries are in neither: memory_stats() leaves them out.
+        # "A device's" bytes are the fullest local device's, which is
+        # what fits or does not; a model over several chips also reads
+        # their sum. On one chip the two are one number
         stats = [d.memory_stats() or {} for d in jax.local_devices()
                  if hasattr(d, "memory_stats")]
         for key in ("bytes_in_use", "peak_bytes_in_use"):
-            if any(key in ms for ms in stats):
-                out[f"jax_device_{key}"] = sum(
-                    int(ms.get(key, 0)) for ms in stats)
+            per_device = [int(ms.get(key, 0)) for ms in stats if key in ms]
+            if per_device:
+                out[f"jax_device_{key}"] = max(per_device)
+                out[f"jax_devices_{key}_total"] = sum(per_device)
     except Exception:  # noqa: BLE001 — backend quirks must not kill sampling
         pass
     try:  # pjit C++ jit caches (internal API — best-effort by design)

@@ -284,18 +284,23 @@ def test_peak_bytes_in_use_is_sampled_beside_bytes_in_use(monkeypatch):
         Dev({"bytes_in_use": 10, "peak_bytes_in_use": 70}),
         Dev({"bytes_in_use": 5, "peak_bytes_in_use": 30})])
     out = rt._jax_sample()
-    assert out["jax_device_bytes_in_use"] == 15
-    assert out["jax_device_peak_bytes_in_use"] == 100
-    # a backend that reports nothing (the CPU) leaves both keys out
+    # a device's bytes are the fullest local device's; the sum is beside it
+    assert out["jax_device_bytes_in_use"] == 10
+    assert out["jax_device_peak_bytes_in_use"] == 70
+    assert out["jax_devices_bytes_in_use_total"] == 15
+    assert out["jax_devices_peak_bytes_in_use_total"] == 100
+    # a backend that reports nothing (the CPU) leaves the keys out
     monkeypatch.setattr(jax, "local_devices", lambda: [Dev(None)])
     out = rt._jax_sample()
-    assert "jax_device_bytes_in_use" not in out
-    assert "jax_device_peak_bytes_in_use" not in out
+    assert not [k for k in out if "bytes_in_use" in k]
     reg = tracing.Registry()
     monkeypatch.setattr(jax, "local_devices", lambda: [
         Dev({"bytes_in_use": 1, "peak_bytes_in_use": 2})])
-    assert rt.RuntimeTelemetry(reg, interval_sec=0).sample()[
-        "jax_device_peak_bytes_in_use"] == 2
+    # on one device every reading is what the sum was
+    sample = rt.RuntimeTelemetry(reg, interval_sec=0).sample()
+    assert sample["jax_device_peak_bytes_in_use"] \
+        == sample["jax_devices_peak_bytes_in_use_total"] == 2
+    assert sample["jax_device_bytes_in_use"] == 1
     assert reg.gauges()["jax_device_peak_bytes_in_use"] == 2.0
 
 

@@ -256,3 +256,146 @@ def test_sharded_servers_mix_across_cluster(rng):
             c.close()
         for s in servers:
             s.stop()
+
+
+# -- the state is born in its layout (ISSUE 31) -------------------------------
+
+DIM_BITS = 13       # a width of its own: nothing else in this process has it
+
+
+def _whole_on_one_device(dim):
+    """Live arrays of ``dim`` columns that one device holds whole."""
+    import gc
+
+    gc.collect()
+    return [a for a in jax.live_arrays()
+            if a.ndim >= 1 and a.shape[-1] == dim
+            and any(s.data.shape[-1] == dim for s in a.addressable_shards)]
+
+
+def _assert_born_sharded(drv, n):
+    dim = drv.converter.dim
+    for name, leaf in zip(drv.state._fields, drv.state):
+        assert leaf.shape == (drv.capacity, dim), name
+        assert leaf.sharding.is_equivalent_to(drv._sharding, 2), name
+        assert [s.data.shape for s in leaf.addressable_shards] \
+            == [(drv.capacity, dim // n)] * n, name
+        assert len({s.device for s in leaf.addressable_shards}) == n
+    assert _whole_on_one_device(dim) == []
+
+
+@pytest.mark.parametrize("step", ["construction", "clear", "grow_labels",
+                                  "load"])
+def test_no_table_ever_lies_whole_on_one_device(step, rng, tmp_path):
+    """At 2^28 columns one [8, D] table is 8.59e9 B and two exceed a
+    chip, so every leaf has to be made in its four column ranges: where
+    the driver is built, cleared, grown and loaded."""
+    from jubatus_tpu.framework import load_model, save_model
+
+    mesh4 = Mesh(np.asarray(jax.devices()[:4]), axis_names=("shard",))
+    drv = ClassifierDriver(CONF, dim_bits=DIM_BITS, mesh=mesh4)
+    _assert_born_sharded(drv, 4)
+    if step == "construction":
+        return
+    plain = ClassifierDriver(CONF, dim_bits=DIM_BITS)
+    _train_both(plain, drv, rng, n=12)
+    if step == "clear":
+        drv.clear()
+        assert drv.get_labels() == {} and drv.capacity == 8
+    elif step == "grow_labels":
+        for i in range(9):
+            drv.set_label(f"extra{i}")
+        assert drv.capacity == 16
+    else:
+        path = str(tmp_path / "s.jubatus")
+        save_model(path, drv, config=json.dumps(CONF))
+        drv = ClassifierDriver(CONF, dim_bits=DIM_BITS, mesh=mesh4)
+        load_model(path, drv, expected_config=json.dumps(CONF))
+        q = [Datum({"x": 0.4, "b": 1.0})]
+        np.testing.assert_allclose(
+            [s for _, s in plain.classify(q)[0]],
+            [s for _, s in drv.classify(q)[0]], rtol=1e-5, atol=1e-6)
+    del plain
+    _assert_born_sharded(drv, 4)
+
+
+@pytest.mark.parametrize("how", ["clear", "load"])
+def test_the_old_state_is_let_go_before_the_new_one_is_made(
+        how, rng, monkeypatch, tmp_path):
+    """Two states of 8.59e9 B a chip do not fit 16e9: when the new
+    leaves are made, no table of the old state is alive."""
+    from jubatus_tpu.framework import load_model, save_model
+    from jubatus_tpu.models import classifier as model
+
+    mesh4 = Mesh(np.asarray(jax.devices()[:4]), axis_names=("shard",))
+    drv = ClassifierDriver(CONF, dim_bits=DIM_BITS, mesh=mesh4)
+    _train_both(ClassifierDriver(CONF, dim_bits=DIM_BITS), drv, rng, n=8)
+    path = str(tmp_path / "s.jubatus")
+    save_model(path, drv, config=json.dumps(CONF))
+    dim = drv.converter.dim
+    alive = []
+
+    def tables():
+        import gc
+
+        gc.collect()
+        return [a for a in jax.live_arrays()
+                if a.ndim == 2 and a.shape[-1] == dim]
+
+    real_init, real_put = model.ops.init_state, model.jax.device_put
+
+    def init_state(*a, **k):
+        alive.append(len(tables()))
+        return real_init(*a, **k)
+
+    def device_put(x, *a, **k):
+        alive.append(len(tables()))
+        return real_put(x, *a, **k)
+
+    monkeypatch.setattr(model.ops, "init_state", init_state)
+    monkeypatch.setattr(model.jax, "device_put", device_put)
+    if how == "clear":
+        drv.clear()
+    else:
+        load_model(path, drv, expected_config=json.dumps(CONF))
+    assert alive and alive[0] == 0, alive
+    assert len(tables()) == 4
+
+
+def test_the_shard_counters_add_up(rng):
+    """What a flush stamps for the mesh: issued = shards x padded rows x
+    width, the shards' owned entries are the entries that carry a
+    feature, and the plan is the one a shard's slice settles."""
+    from jubatus_tpu.ops import classifier as ops
+    from jubatus_tpu.utils.tracing import Registry
+
+    n, width, rows = 4, 40, 300
+    mesh4 = Mesh(np.asarray(jax.devices()[:n]), axis_names=("shard",))
+    drv = ClassifierDriver(CONF, dim_bits=DIM_BITS, mesh=mesh4)
+    drv.trace = Registry()
+    dim = drv.converter.dim
+    idx = rng.integers(1, dim, (rows, width)).astype(np.int32)
+    idx[:, 0] = 5                # a key every row carries: one column
+    idx[:, 30:] = 0              # the width's padding
+    val = (idx != 0).astype(np.float32)
+    owned = drv._shard_entries(idx)
+    assert owned.tolist() == np.bincount(
+        idx[idx != 0] // (dim // n), minlength=n).tolist()
+    assert owned.sum() == np.count_nonzero(idx)
+    drv.train_hashed(["a" if i % 2 else "b" for i in range(rows)], idx, val)
+    c = drv.trace.counters()
+    assert c["step.train.shard_entries"] == c["step.train.entries"] \
+        == rows * 30
+    assert c["step.train.shard_entries_issued"] == n * 512 * width
+    assert c["step.train.shard_entries_owned_max"] == owned.max()
+    # the fixed key's column is shard 0's: it owns more than a quarter
+    assert owned.argmax() == 0 and owned.max() > owned.sum() / n
+    plan = ops.gather_plan(8, dim // n, 512 * width)
+    assert c[f"step.train.plan_{plan}"] == 1
+    # one chip stamps its plan from the whole table and no shard counter
+    one = ClassifierDriver(CONF, dim_bits=DIM_BITS)
+    one.trace = Registry()
+    one.train_hashed(["a", "b"] * (rows // 2), idx, val)
+    c1 = one.trace.counters()
+    assert not [k for k in c1 if "shard_entries" in k]
+    assert c1[f"step.train.plan_{ops.gather_plan(8, dim, 512 * width)}"] == 1
