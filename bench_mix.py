@@ -776,7 +776,11 @@ qv = jnp.asarray(rng.normal(size=(256, K)).astype(np.float32))
 if shards > 1:
     mesh = sm.feature_shard_mesh(shards)
     st = sm.place_state(mesh, ops.init_state(L, D, conf), D)
-    train = lambda s: sm.train_batch(mesh, s, idx, val, labels, mask,
+    # routed by column range on the host, as the driver's stage does
+    ridx, rval, _ = sm.route_rows(np.asarray(idx), np.asarray(val), shards,
+                                  D // shards)
+    ridx, rval = jax.device_put((ridx, rval), sm.flush_sharding(mesh))
+    train = lambda s: sm.train_batch(mesh, s, ridx, rval, labels, mask,
                                      1.0, method=method)
     classify = lambda s: sm.scores(mesh, s, qi, qv, mask)
 else:

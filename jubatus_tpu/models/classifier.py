@@ -54,9 +54,10 @@ class ClassifierDriver(DriverBase):
         # mesh: shard the feature dimension of every [L, D] table over the
         # mesh axis — ONE server exploits all its local chips. The hot
         # train/classify paths run as shard_map programs
-        # (parallel/sharded_model.py): the CSR batch is column-range
-        # partitioned to the owning shard, one psum reduces the [B, L]
-        # logits, and the weight matrix is never gathered. The dense
+        # (parallel/sharded_model.py): a train flush is routed by column
+        # range on the host and each chip is uploaded its own entries, a
+        # query masks the batch to the owning shard, one psum reduces the
+        # [B, L] logits, and the weight matrix is never gathered. The dense
         # uniform-schema plan keeps GSPMD partitioning of the placed state.
         # Orthogonal to cross-server data parallelism via the mix plane
         # (parallel/spmd.py stacks both for the pod path).
@@ -87,11 +88,21 @@ class ClassifierDriver(DriverBase):
         # argument — a config-side "hash_max_size" overrides the latter
         self._sharding = None
         if mesh is not None:
+            from jubatus_tpu.native import ingest
             from jubatus_tpu.parallel.mesh import make_feature_sharding
+            from jubatus_tpu.parallel.sharded_model import flush_sharding
 
             self._sharding = make_feature_sharding(
                 mesh, mesh_axis, self.converter.hasher.dim_bits,
                 ClassifierConfigError, rank=2)
+            # a routed train flush: shard n's [Ks, B] plane on chip n
+            # (parallel/sharded_model.py route_rows), at a width that
+            # only grows among flushes of one width K: {K: Ks}
+            self._flush_sharding = flush_sharding(mesh, mesh_axis)
+            self._shard_width: Dict[int, int] = {}
+            # the routing's native library is built or loaded here, not
+            # under the driver's lock at the first flush
+            ingest.available()
         self._confidence = method in ops.CONFIDENCE_METHODS
         self.state = None
         self._init_model()
@@ -249,11 +260,31 @@ class ClassifierDriver(DriverBase):
                 val = np.pad(val, ((0, bsz - b), (0, 0)))
             slots_arr = np.zeros(bsz, dtype=np.int32)
             slots_arr[:b] = slots
-            didx = jnp.asarray(idx[0] if schema else idx)
-            dval, dslots = jnp.asarray(val), jnp.asarray(slots_arr)
+            if sharded:
+                # the flush is routed to its owners before it is uploaded:
+                # a chip is handed its own entries alone, as local
+                # columns, at the width of the fullest row any shard
+                # holds. Among flushes of one width K that width never
+                # shrinks in a server's life, so a short call that by
+                # chance fills no row so far makes no second program
+                # beside the one the traffic settled on; it is no wider
+                # than K's own rung, so a narrow flush after a wide one
+                # issues no more than it did under the mask
+                from jubatus_tpu.parallel import sharded_model as _sm
+
+                n_shards = self._mesh.shape[self._mesh_axis]
+                k = idx.shape[1]
+                ridx, rval, owned = _sm.route_rows(
+                    idx, val, n_shards, self.converter.dim // n_shards,
+                    self._shard_width.get(k, 0))
+                self._shard_width[k] = ridx.shape[1]
+                didx, dval = jax.device_put((ridx, rval),
+                                            self._flush_sharding)
+            else:
+                didx = jnp.asarray(idx[0] if schema else idx)
+                dval = jnp.asarray(val)
+            dslots = jnp.asarray(slots_arr)
             mask = self._mask()
-            if sharded and trace is not None:
-                owned = self._shard_entries(idx)
         plan = None
         with self._span("step.train.dispatch"):
             if schema:
@@ -262,14 +293,12 @@ class ClassifierDriver(DriverBase):
                     self.state, didx, dval, dslots, mask, self.param,
                     method=self.method)
             elif sharded:
-                # shard_map path: batch routed by column range, one psum
-                # for the logits — weight state never moves (ISSUE 13).
-                # Each shard settles its plan from its own slice's shape
-                from jubatus_tpu.parallel import sharded_model as _sm
-
-                n_shards = self._mesh.shape[self._mesh_axis]
+                # shard_map path: one psum for the logits — weight state
+                # never moves (ISSUE 13). Each shard settles its plan from
+                # its own slice's shape and its own plane of the flush
                 num_labels, dim = self.state.w.shape
-                plan = ops.gather_plan(num_labels, dim // n_shards, idx.size)
+                plan = ops.gather_plan(num_labels, dim // n_shards,
+                                       ridx[0].size)
                 self.state = _sm.train_batch(
                     self._mesh, self.state, didx, dval, dslots, mask,
                     self.param, method=self.method, axis=self._mesh_axis)
@@ -289,9 +318,9 @@ class ClassifierDriver(DriverBase):
             # the rows asked for and the rows the compiled bucket ran, and
             # the width's side of them: entries that carry a feature,
             # entries the rows have at the program's width, the bytes the
-            # stage put on the device, and the width itself: each distinct
-            # one is a program this server's traffic made it compile
-            # (times the row buckets)
+            # stage put on the device (a routed flush's summed over the
+            # chips), and the width itself: each distinct one is a program
+            # this server's traffic made it compile (times the row buckets)
             trace.count("step.train.rows", b)
             trace.count("step.train.rows_padded", bsz)
             trace.count(f"step.train.width_{idx.shape[1]}")
@@ -301,32 +330,18 @@ class ClassifierDriver(DriverBase):
             trace.count("step.train.upload_bytes",
                         didx.nbytes + dval.nbytes + dslots.nbytes)
             if owned is not None:
-                # the mesh's side: every shard is handed every entry of
-                # the padded flush and masks what it does not own
-                # (sharded_model._owned), so the chips issue shards x
-                # rows x width descriptors for the entries that carry a
-                # feature; the fullest shard's share says how evenly the
-                # columns fall
+                # the mesh's side: the chips issue shards x padded rows x
+                # routed width descriptors for the entries that carry a
+                # feature (what is left over is row padding: the fullest
+                # row sets the width); the fullest shard's share says how
+                # evenly the columns fall; the routed width is the mesh
+                # program's, as width_<K> is the flush's own
                 trace.count("step.train.shard_entries", int(owned.sum()))
-                trace.count("step.train.shard_entries_issued",
-                            len(owned) * idx.size)
+                trace.count("step.train.shard_entries_issued", ridx.size)
                 trace.count("step.train.shard_entries_owned_max",
                             int(owned.max()))
+                trace.count(f"step.train.shard_width_{ridx.shape[1]}")
         return b
-
-    def _shard_entries(self, idx: np.ndarray) -> np.ndarray:
-        """The entries of a staged index array that carry a feature, by
-        the shard that owns their column: [shards]. Column 0 is padding
-        and no feature hashes there. One compare and one count for each
-        shard boundary: 0.17 ms at 8,192 x 40 over four shards on the
-        four-chip host, where one bincount of ``idx >> log2(D/N)`` took
-        0.50 (PERF.md section 6, PR 31): stamped on every flush."""
-        n_shards = self._mesh.shape[self._mesh_axis]
-        d_local = self.converter.dim // n_shards
-        at_or_past = [np.count_nonzero(idx)] + [
-            np.count_nonzero(idx >= k * d_local)
-            for k in range(1, n_shards)] + [0]
-        return -np.diff(at_or_past)
 
     @locked
     def train_hashed(self, labels: Sequence[str], idx: np.ndarray,

@@ -96,12 +96,56 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(_Out)]
         lib.jt_ingest_free_out.restype = None
         lib.jt_ingest_free_out.argtypes = [ctypes.POINTER(_Out)]
+        _ip = ctypes.POINTER(ctypes.c_int32)
+        lib.jt_route_count.restype = ctypes.c_int32
+        lib.jt_route_count.argtypes = [
+            _ip, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+            ctypes.c_int32, _ip]
+        lib.jt_route_fill.restype = ctypes.c_int
+        lib.jt_route_fill.argtypes = [
+            _ip, _fp, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+            ctypes.c_int32, ctypes.c_int32, _ip, _fp]
         _lib = lib
         return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def route_count(idx: np.ndarray, n_shards: int,
+                d_local: int) -> Optional[np.ndarray]:
+    """The first pass of parallel/sharded_model.py route_rows in C++:
+    lens [N, B], the entries of row i of ``idx`` (int32 [B, K], C order)
+    that shard s owns; None without the library. Raises ValueError for a
+    column outside [0, N * d_local)."""
+    lib = _load()
+    if lib is None:
+        return None
+    b, k = idx.shape
+    lens = np.empty((n_shards, b), dtype=np.int32)
+    _ip = ctypes.POINTER(ctypes.c_int32)
+    if lib.jt_route_count(idx.ctypes.data_as(_ip), b, k, n_shards, d_local,
+                          lens.ctypes.data_as(_ip)) < 0:
+        raise ValueError(
+            f"a column outside [0, {n_shards * d_local}) in a train flush")
+    return lens
+
+
+def route_fill(idx: np.ndarray, val: np.ndarray, n_shards: int,
+               d_local: int, ks: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The second pass: the planes ridx, rval [N, ks, B] of ``idx``/``val``
+    (int32/float32 [B, K], C order; a row's entries in any order), ``ks``
+    no less than the fullest count ``route_count`` gave."""
+    b, k = idx.shape
+    ridx = np.empty((n_shards, ks, b), dtype=np.int32)
+    rval = np.empty((n_shards, ks, b), dtype=np.float32)
+    _ip, _fp = ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_float)
+    if _load().jt_route_fill(
+            idx.ctypes.data_as(_ip), val.ctypes.data_as(_fp), b, k, n_shards,
+            d_local, ks, ridx.ctypes.data_as(_ip), rval.ctypes.data_as(_fp)):
+        raise RuntimeError("jt_route_fill refused what jt_route_count took")
+    return ridx, rval
 
 
 def spec_from_converter_config(conv: dict) -> Optional[str]:

@@ -319,10 +319,12 @@ def train_rows(w, dw, prec, dprec, idx, val, labels, label_mask, param, *,
     sequential scan of tiny gathers/scatters is latency-bound, while this
     path is one gather + one einsum + one scatter over the whole batch.
 
-    On a mesh the tables are a shard's [L, D/S] slices, ``idx`` its local
-    columns and ``val`` zero where the shard does not own the entry, and
-    ``reduce`` sums over the shards the three quantities that cross them:
-    the [B, L] scores, x2 and v. An unowned entry's updates are
+    On a mesh the tables are a shard's [L, D/S] slices and ``idx``/``val``
+    the shard's own entries of each row as local columns (a train flush
+    is routed on the host, parallel/sharded_model.py route_rows; spmd.py
+    masks instead: ``val`` zero where the shard does not own the entry),
+    and ``reduce`` sums over the shards the three quantities that cross
+    them: the [B, L] scores, x2 and v. A padding entry's updates are
     alpha * sigma * 0 = 0, added at local column 0.
     """
     confidence = method in CONFIDENCE_METHODS

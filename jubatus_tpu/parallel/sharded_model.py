@@ -10,11 +10,15 @@ scalars over the interconnect.
 
 Execution model (one shard_map'd jitted program per op):
 
-- Every shard receives the full CSR batch (idx/val [B, K] — kilobytes,
-  vs gigabytes of weight state) and masks it to its OWNED column range
-  ``[shard * D/S, (shard+1) * D/S)`` — the column-range partitioner that
-  routes each batch entry to the owning shard. Unowned entries
-  contribute exact zeros.
+- A train flush is routed on the host (``route_rows``): shard ``n`` is
+  uploaded only the entries whose column lies in its OWNED range
+  ``[n * D/S, (n+1) * D/S)``, row by row, as local columns, at the
+  width of the fullest row any shard holds (24 where a 39-feature flush
+  is 40 wide over four shards). A chip issues no descriptor for an entry
+  it does not own.
+- The query and regression programs still receive the full CSR batch
+  (idx/val [B, K], replicated) and mask it to the owned range
+  (``_owned``): unowned entries contribute exact zeros.
 - Partial scores from the local [L, D/S] slice are reduced with a
   single ``psum`` over the shard axis — the ONLY cross-shard traffic
   per step is [B, L] logits (+ [B] norms), never weight state.
@@ -46,6 +50,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from jubatus_tpu.core.sparse import _width_bucket
 from jubatus_tpu.ops.classifier import (
     ClassifierState,
     score_rows,
@@ -117,6 +122,98 @@ def _owned(idx, val, d_local, axis):
     return jnp.where(owned, li_raw, 0), jnp.where(owned, val, 0.0)
 
 
+def route_rows(idx: np.ndarray, val: np.ndarray, n_shards: int,
+               d_local: int, min_width: int = 0):
+    """Column-range routing of one padded flush, on the host: from
+    ``idx``/``val`` [B, K] (columns as the hasher gives them, in [0, D);
+    column 0 is padding and no feature hashes there) to
+    (``ridx``, ``rval``, ``owned``). Plane ``n`` of ``ridx``/``rval``
+    [N, Ks, B] holds, row by row, the entries whose column lies in
+    ``[n * d_local, (n + 1) * d_local)``, as LOCAL columns, in the order
+    the row has them, padded with column 0 / value 0 as rows are padded.
+    ``Ks`` is the ladder rung (core/sparse.py ``_width_bucket``) of the
+    fullest row of the fullest shard, and never under ``min_width``: one
+    width for all shards, since one program runs on all of them.
+    ``owned`` [N] counts the entries that carry a feature by the shard
+    that owns them. Raises ValueError for a column outside [0, D).
+
+    The row index is the planes' minor-most axis because that is how the
+    device lays out a [B, Ks] array of so few columns: a [Ks, B] plane is
+    uploaded as it lies, where a [B, Ks] one is transposed on the host
+    first, and the chips waited for that (PERF.md section 6, PR 32).
+    ``train_batch`` reads a plane through its transpose, which on the
+    device is the same bytes.
+
+    Two passes over the rows, the count and the fill, with the width
+    settled here between them: native/fast_ingest.cpp's, each one call
+    that holds no interpreter lock (stage 11.6 ms a flush of 8,192 x 40
+    over four shards, the chips never idle), or without the library
+    numpy's, which serve but do not keep four chips fed (stage 22.9 ms,
+    idle 13.3%, even on sorted rows' spans with no sort: PERF.md section
+    6, PR 32)."""
+    from jubatus_tpu.native import ingest
+
+    idx = np.ascontiguousarray(idx, dtype=np.int32)
+    val = np.ascontiguousarray(val, dtype=np.float32)
+    if idx.ndim != 2 or val.shape != idx.shape:
+        raise ValueError(f"idx {idx.shape} and val {val.shape} are not "
+                         "one [B, K] pair")
+    native = ingest.available()
+    lens = (ingest.route_count if native else _route_count)(
+        idx, n_shards, d_local)
+    ks = max(_width_bucket(int(lens.max()) if lens.size else 1), min_width)
+    if native:
+        ridx, rval = ingest.route_fill(idx, val, n_shards, d_local, ks)
+    else:
+        ridx, rval = _route_fill(idx, val, lens, d_local, ks)
+    return ridx, rval, lens.sum(axis=1)
+
+
+def flush_sharding(mesh: Mesh, axis: str = DEFAULT_AXIS) -> NamedSharding:
+    """Where ``route_rows``' planes go: plane n on the mesh's n-th device
+    (the leading axis split, the rest whole)."""
+    return NamedSharding(mesh, P(axis))
+
+
+def _route_count(idx, n_shards, d_local):
+    """``route_rows``' first pass without the native library: lens
+    [N, B], the entries of row i that shard n owns, from one count a
+    shard boundary of the entries at or past it."""
+    if idx.size and not 0 <= idx.min() <= idx.max() < n_shards * d_local:
+        raise ValueError(
+            f"a column outside [0, {n_shards * d_local}) in a train flush")
+    past = np.zeros((n_shards + 1, idx.shape[0]), dtype=np.int32)
+    past[0] = np.count_nonzero(idx, axis=1)
+    for n in range(1, n_shards):
+        past[n] = np.count_nonzero(idx >= n * d_local, axis=1)
+    return past[:-1] - past[1:]
+
+
+def _route_fill(idx, val, lens, d_local, ks):
+    """``route_rows``' second pass without the native library: a stable
+    sort groups each row's entries by owner (padding last) and keeps
+    their order within a shard, so a shard's entries are one span of the
+    row, whose start and length ``lens`` gives: one gather fills the
+    planes."""
+    n_shards, (b, k) = len(lens), idx.shape
+    owner = np.where(idx == 0, n_shards, idx // d_local)
+    order = np.argsort(owner, axis=1, kind="stable")
+    idx = np.take_along_axis(idx, order, axis=1)
+    val = np.take_along_axis(val, order, axis=1)
+    starts = np.cumsum(lens, axis=0, dtype=np.int32) - lens
+    lane = np.arange(ks, dtype=np.int32)[:, None]
+    at = (starts + np.arange(b, dtype=np.int32) * k)[:, None, :] + lane
+    live = lane < lens[:, None, :]
+    lo = (np.arange(n_shards, dtype=np.int32) * d_local)[:, None, None]
+    # a dead lane reads past its span (clipped at the array's end) and
+    # is zeroed: the values selected, not multiplied, so that no row's
+    # NaN leaks into its neighbour
+    ridx = (np.take(idx.reshape(-1), at, mode="clip") - lo) * live
+    rval = np.where(live, np.take(val.reshape(-1), at, mode="clip"),
+                    np.float32(0.0))
+    return ridx, rval
+
+
 @functools.partial(
     jax.jit, static_argnames=("mesh", "axis", "method"), donate_argnums=(1,))
 def train_batch(mesh: Mesh, state: ClassifierState, idx: jax.Array,
@@ -125,22 +222,23 @@ def train_batch(mesh: Mesh, state: ClassifierState, idx: jax.Array,
                 axis: str = DEFAULT_AXIS) -> ClassifierState:
     """Feature-sharded vectorized microbatch update: ops.train_rows on
     each shard's slice (parallel/spmd.py runs the same body under an
-    extra replica axis). Batch arrays are replicated (the batch is
-    kilobytes; the state is the thing that must not move); state leaves
-    are sharded over ``axis``. One psum of [B, L] partial scores (+ [B]
+    extra replica axis). ``idx``/``val`` are ``route_rows``' [N, Ks, B]
+    planes, split over ``axis`` like the state's leaves: a shard is
+    handed its own entries as local columns and nothing else. Labels and
+    the mask are replicated. One psum of [B, L] partial scores (+ [B]
     norms) per step — weight state never crosses shards."""
     dim = state.w.shape[-1]
 
     def body(w, dw, prec, dprec, idx, val, labels, label_mask):
-        li, lv = _owned(idx, val, w.shape[1], axis)
+        # the shard's own [Ks, B] plane, read as [B, Ks] rows
         return train_rows(
-            w, dw, prec, dprec, li, lv, labels, label_mask, param,
-            method=method, reduce=lambda x: jax.lax.psum(x, axis))
+            w, dw, prec, dprec, idx[0].T, val[0].T, labels, label_mask,
+            param, method=method, reduce=lambda x: jax.lax.psum(x, axis))
 
     specs = tuple(state_spec(a, dim, axis) for a in state)
     out = shard_map(
         body, mesh=mesh,
-        in_specs=specs + (P(), P(), P(), P()),
+        in_specs=specs + (P(axis), P(axis), P(), P()),
         out_specs=specs,
         check_vma=False,
     )(state.w, state.dw, state.prec, state.dprec,

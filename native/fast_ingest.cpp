@@ -1407,4 +1407,89 @@ int jt_ingest_parse_datums_w(void* h, const uint8_t* buf, int64_t len,
   }
 }
 
+// ---- column-range routing of a padded flush -------------------------------
+// parallel/sharded_model.py route_rows: a --shard-devices server hands each
+// chip only the entries whose column it owns. idx/val are [b, k] row-major
+// (column 0 is padding), shard s owns columns [s * d_local, (s+1) * d_local).
+// Two calls, because the planes' width follows the count:
+//   jt_route_count: lens [n_shards, b] = entries of row i that shard s owns;
+//     returns the fullest such count, or -1 where a column lies outside
+//     [0, n_shards * d_local).
+//   jt_route_fill: ridx/rval [n_shards, ks, b] (every cell written; row
+//     index minor-most: the device's own layout for such a plane, so the
+//     upload transposes nothing): the entries of row i that shard s owns, as
+//     local columns, in the order the row has them, column 0 / value 0
+//     behind them; 0 = ok, 1 where a row holds more than ks entries of one
+//     shard or a column is out of range.
+// One pass over the rows each, a cursor a shard: any order of a row's
+// entries is routed stably, sorted or not.
+
+// a power-of-two d_local (every hash_max_size over 2^k chips) divides by
+// a shift: 32 says it is none
+static inline uint32_t route_shift(uint32_t d_local) {
+  return (d_local & (d_local - 1)) == 0 ? __builtin_ctz(d_local) : 32;
+}
+
+static inline uint32_t route_owner(uint32_t c, uint32_t d_local,
+                                   uint32_t shift) {
+  return shift < 32 ? c >> shift : c / d_local;
+}
+
+int32_t jt_route_count(const int32_t* idx, int64_t b, int32_t k,
+                       int32_t n_shards, int32_t d_local, int32_t* lens) {
+  if (b < 0 || k < 0 || n_shards <= 0 || d_local <= 0) return -1;
+  std::memset(lens, 0, sizeof(int32_t) * n_shards * b);
+  const uint32_t d = d_local, n = n_shards, shift = route_shift(d);
+  int32_t fullest = 0;
+  for (int64_t i = 0; i < b; ++i) {
+    const int32_t* row = idx + i * k;
+    for (int32_t j = 0; j < k; ++j) {
+      const uint32_t c = static_cast<uint32_t>(row[j]);
+      if (c == 0) continue;
+      const uint32_t s = route_owner(c, d, shift);
+      if (s >= n) return -1;
+      fullest = std::max(fullest, ++lens[s * b + i]);
+    }
+  }
+  return fullest;
+}
+
+int jt_route_fill(const int32_t* idx, const float* val, int64_t b, int32_t k,
+                  int32_t n_shards, int32_t d_local, int32_t ks,
+                  int32_t* ridx, float* rval) {
+  if (b < 0 || k < 0 || n_shards <= 0 || d_local <= 0 || ks < 0) return 1;
+  const uint32_t d = d_local, n = n_shards, shift = route_shift(d);
+  // A plane's lanes lie b rows apart, so a row's entries would land on
+  // n * ks distant lines: route a block of rows into a buffer that the
+  // cache holds, then copy each lane's stretch of the block out whole.
+  constexpr int64_t kBlock = 128;
+  const int64_t lanes = static_cast<int64_t>(n) * ks;
+  std::vector<int32_t> bi(lanes * kBlock), at(n);
+  std::vector<float> bv(lanes * kBlock);
+  for (int64_t i0 = 0; i0 < b; i0 += kBlock) {
+    const int64_t rows = std::min(kBlock, b - i0);
+    std::fill(bi.begin(), bi.end(), 0);
+    std::fill(bv.begin(), bv.end(), 0.0f);
+    for (int64_t i = 0; i < rows; ++i) {
+      const int32_t* row = idx + (i0 + i) * k;
+      const float* vrow = val + (i0 + i) * k;
+      std::fill(at.begin(), at.end(), 0);
+      for (int32_t j = 0; j < k; ++j) {
+        const uint32_t c = static_cast<uint32_t>(row[j]);
+        if (c == 0) continue;
+        const uint32_t s = route_owner(c, d, shift);
+        if (s >= n || at[s] >= ks) return 1;
+        const int64_t o = (static_cast<int64_t>(s) * ks + at[s]++) * kBlock + i;
+        bi[o] = static_cast<int32_t>(c - s * d);
+        bv[o] = vrow[j];
+      }
+    }
+    for (int64_t q = 0; q < lanes; ++q) {
+      std::memcpy(ridx + q * b + i0, &bi[q * kBlock], rows * sizeof(int32_t));
+      std::memcpy(rval + q * b + i0, &bv[q * kBlock], rows * sizeof(float));
+    }
+  }
+  return 0;
+}
+
 }  // extern "C"

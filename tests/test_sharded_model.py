@@ -2,7 +2,9 @@
 ISSUE 13 tentpole): shard_map'd train/classify must match the
 single-device kernels to f32 rounding across shard counts, the drivers
 must route through the sharded path transparently, and the per-shard
-diff chunks must fold/apply without ever materializing the matrix."""
+diff chunks must fold/apply without ever materializing the matrix.
+ISSUE 32: a train flush is routed by column range on the host
+(``route_rows``) and each shard is handed its own entries alone."""
 
 from __future__ import annotations
 
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from jubatus_tpu.core.datum import Datum
+from jubatus_tpu.core.sparse import _width_bucket
 from jubatus_tpu.ops import classifier as cops
 from jubatus_tpu.ops import regression as rops
 from jubatus_tpu.parallel import sharded_model as sm
@@ -27,13 +30,157 @@ def _mesh(n):
 
 
 def _batch(rng, b=B, k=K, dim=D):
-    idx = rng.integers(0, dim, (b, k)).astype(np.int32)
+    # column 0 is padding: no feature hashes there
+    idx = rng.integers(1, dim, (b, k)).astype(np.int32)
     val = rng.normal(size=(b, k)).astype(np.float32)
     labels = rng.integers(0, 3, b).astype(np.int32)
     mask = np.zeros(L, bool)
     mask[:3] = True
     return (jnp.asarray(idx), jnp.asarray(val), jnp.asarray(labels),
             jnp.asarray(mask))
+
+
+def _routed(mesh, idx, val, dim=D, min_width=0):
+    """A flush as the driver's stage hands it to the mesh step: routed
+    by column range on the host, plane n on device n."""
+    n = mesh.shape["shard"]
+    ridx, rval, _ = sm.route_rows(np.asarray(idx), np.asarray(val), n,
+                                  dim // n, min_width)
+    return jax.device_put((ridx, rval), sm.flush_sharding(mesh))
+
+
+def _parsed_rows(rng, b, k, dim, fill):
+    """Rows as the native parser leaves them: ``fill[i]`` distinct
+    columns of row i sorted ascending, padding (column 0 / value 0)
+    behind them."""
+    idx = np.zeros((b, k), np.int32)
+    val = np.zeros((b, k), np.float32)
+    for i, f in enumerate(fill):
+        idx[i, :f] = np.sort(rng.choice(np.arange(1, dim), f, replace=False))
+        val[i, :f] = rng.normal(size=f)
+    return idx, val
+
+
+def _assert_routed(idx, val, n, d_local, ridx, rval, owned):
+    """Every entry of [B, K] lands in exactly one shard's [Ks, B] plane,
+    at its local column, with its value and in its row's order; the rest
+    of every plane is column 0 / value 0; ``owned`` counts them by
+    shard."""
+    b = idx.shape[0]
+    assert ridx.shape == rval.shape
+    assert (ridx.shape[0], ridx.shape[2]) == (n, b)
+    assert ridx.dtype == np.int32 and rval.dtype == np.float32
+    assert ridx.flags.c_contiguous and rval.flags.c_contiguous
+    landed = 0
+    for s in range(n):
+        mine = (idx != 0) & (idx // d_local == s)
+        assert owned[s] == mine.sum()
+        for i in range(b):
+            m = int(mine[i].sum())
+            assert ridx[s, :m, i].tolist() \
+                == (idx[i][mine[i]] - s * d_local).tolist(), (s, i)
+            assert rval[s, :m, i].tolist() == val[i][mine[i]].tolist()
+            assert not ridx[s, m:, i].any() and not rval[s, m:, i].any()
+            landed += m
+    assert landed == np.count_nonzero(idx)
+    ks = ridx.shape[1]
+    fullest = max(int(((idx != 0) & (idx // d_local == s)).sum(axis=1).max())
+                  for s in range(n))
+    assert ks >= fullest and ks == _width_bucket(ks)     # a ladder rung
+    return ks, fullest
+
+
+def _router(how, monkeypatch):
+    """``route_rows`` as a server calls it (the native library's passes,
+    where it is built) or with numpy's, which serve without the library."""
+    from jubatus_tpu.native import ingest
+
+    if how == "numpy":
+        monkeypatch.setattr(ingest, "available", lambda: False)
+    elif not ingest.available():
+        pytest.skip("native toolchain unavailable")
+    return sm.route_rows
+
+
+ROUTERS = pytest.mark.parametrize("how", ("native", "numpy"))
+
+
+@ROUTERS
+@pytest.mark.parametrize("n_shards", (2, 4))
+@pytest.mark.parametrize("rows", ("parsed", "as_drawn"))
+def test_route_rows_lands_every_entry_once(rows, n_shards, how, rng,
+                                            monkeypatch):
+    """The routing alone, on a seeded batch: the parser's sorted rows and
+    rows in any other order land the same entries, in their row's order,
+    and both implementations the same planes."""
+    route = _router(how, monkeypatch)
+    dim, b, k = 1 << 12, 64, 40
+    fill = rng.integers(0, 40, b)
+    idx, val = _parsed_rows(rng, b, k, dim, fill)
+    if rows == "as_drawn":
+        for i in range(b):          # shuffle each row, padding and all
+            perm = rng.permutation(k)
+            idx[i], val[i] = idx[i][perm], val[i][perm]
+    ridx, rval, owned = route(idx, val, n_shards, dim // n_shards)
+    ks, fullest = _assert_routed(idx, val, n_shards, dim // n_shards,
+                                 ridx, rval, owned)
+    assert ks == _width_bucket(fullest)
+    # a floor under the width holds, and changes nothing else
+    wide = route(idx, val, n_shards, dim // n_shards, ks + 8)
+    assert wide[0].shape[1] == ks + 8
+    np.testing.assert_array_equal(wide[0][:, :ks], ridx)
+    np.testing.assert_array_equal(wide[1][:, :ks], rval)
+    assert not wide[0][:, ks:].any() and not wide[1][:, ks:].any()
+    # a column past the last range, or under the first, is refused
+    for bad in (dim, -3):
+        off = idx.copy()
+        off[3, 0] = bad
+        with pytest.raises(ValueError, match="outside"):
+            route(off, val, n_shards, dim // n_shards)
+
+
+def _edge_rows(case, dim, n):
+    d_local = dim // n
+    k = 40
+    idx = np.zeros((4, k), np.int32)
+    if case == "one_shard_holds_a_whole_row":
+        idx[0, :39] = 2 * d_local + 1 + np.arange(39)   # all 39 in shard 2
+        idx[1, :3] = (5, d_local + 5, 3 * d_local + 5)
+    elif case == "a_padding_row":
+        idx[0, :4] = (7, d_local + 1, d_local + 2, 3 * d_local + 9)
+        idx[2, :2] = (1, dim - 1)   # rows 1 and 3 carry nothing
+    elif case == "first_and_last_cell_of_each_range":
+        cells = [c for s in range(n)
+                 for c in (s * d_local, (s + 1) * d_local - 1)][1:]
+        idx[0, :len(cells)] = cells         # column 0 itself is padding
+        idx[1, :2] = (d_local - 1, d_local)
+    else:
+        assert case == "nothing_at_all"
+    val = np.where(idx != 0, idx.astype(np.float32) / dim + 1.0,
+                   np.float32(0.0))
+    return idx, val
+
+
+@ROUTERS
+@pytest.mark.parametrize("case", (
+    "one_shard_holds_a_whole_row", "a_padding_row",
+    "first_and_last_cell_of_each_range", "nothing_at_all"))
+def test_route_rows_edges(case, how, monkeypatch):
+    dim, n = 1 << 10, 4
+    idx, val = _edge_rows(case, dim, n)
+    ridx, rval, owned = _router(how, monkeypatch)(idx, val, n, dim // n)
+    ks, fullest = _assert_routed(idx, val, n, dim // n, ridx, rval, owned)
+    assert ks == _width_bucket(fullest)
+    if case == "one_shard_holds_a_whole_row":
+        assert ks == 40 and owned.tolist() == [1, 1, 39, 1]
+    elif case == "first_and_last_cell_of_each_range":
+        # a range's first cell is local column 0 of its owner, its last
+        # the owner's last: neither falls to the neighbour
+        assert ridx[1, :2, 0].tolist() == [0, dim // n - 1]
+        assert ridx[0, 0, 1] == dim // n - 1 and ridx[1, 0, 1] == 0
+        assert rval[1, 0, 0] == val[0, 1] != 0
+    elif case == "nothing_at_all":
+        assert ks == 8 and owned.tolist() == [0, 0, 0, 0]
 
 
 @pytest.mark.parametrize("n_shards", SHARD_COUNTS)
@@ -53,10 +200,10 @@ def test_train_and_scores_parity(method, n_shards, k, rng):
     idx2, val2, labels2, _ = _batch(rng, k=k)
     ref = cops.train_batch(ref, idx2, val2, labels2, mask, 1.0,
                            method=method)
-    st = sm.train_batch(mesh, st, idx, val, labels, mask, 1.0,
-                        method=method)
-    st = sm.train_batch(mesh, st, idx2, val2, labels2, mask, 1.0,
-                        method=method)
+    st = sm.train_batch(mesh, st, *_routed(mesh, idx, val), labels, mask,
+                        1.0, method=method)
+    st = sm.train_batch(mesh, st, *_routed(mesh, idx2, val2), labels2, mask,
+                        1.0, method=method)
     for name, (a, b) in zip(("w", "dw", "prec", "dprec"), zip(ref, st)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-5, atol=3e-5, err_msg=name)
@@ -65,6 +212,39 @@ def test_train_and_scores_parity(method, n_shards, k, rng):
         np.asarray(sm.scores(mesh, st, qi, qv, mask)),
         np.asarray(cops.scores(ref, qi, qv, mask)),
         rtol=3e-5, atol=3e-5)
+
+
+# AROW keeps a confidence table, PA does not: the two shapes of the rule
+@pytest.mark.parametrize("method", ("AROW", "PA"))
+def test_routed_step_matches_one_device_on_parsed_rows(method, rng):
+    """The routed train_batch against ops.train_batch_parallel on one
+    device, on rows as the parser leaves them (sorted, short rows and
+    padding rows among them), two flushes in a row; a shard's partial score sums the very entries it
+    summed under the mask, so the tables agree to f32 rounding."""
+    dim, b, k, n = 1 << 12, 64, 40, 4
+    conf = method in cops.CONFIDENCE_METHODS
+    mesh = _mesh(n)
+    ref = cops.init_state(L, dim, conf)
+    st = sm.place_state(mesh, cops.init_state(L, dim, conf), dim)
+    mask = jnp.asarray(np.arange(L) < 3)
+    for _ in range(2):
+        fill = rng.integers(0, 40, b)
+        fill[5] = fill[17] = 0
+        idx, val = _parsed_rows(rng, b, k, dim, fill)
+        labels = jnp.asarray(rng.integers(0, 3, b).astype(np.int32))
+        ref = cops.train_batch_parallel(
+            ref, jnp.asarray(idx), jnp.asarray(val), labels, mask, 1.0,
+            method=method)
+        ridx, rval = _routed(mesh, idx, val, dim)
+        assert ridx.shape[1] < k     # narrower than the flush
+        assert [sh.data.shape for sh in ridx.addressable_shards] \
+            == [(1, ridx.shape[1], b)] * n
+        st = sm.train_batch(mesh, st, ridx, rval, labels, mask, 1.0,
+                            method=method)
+    for name, (a, c) in zip(("w", "dw", "prec", "dprec"), zip(ref, st)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=3e-5, atol=3e-5, err_msg=name)
+    assert np.abs(np.asarray(st.dw)).sum() > 0
 
 
 def test_per_device_footprint_is_sliced(rng):
@@ -101,8 +281,8 @@ def test_chunk_roundtrip_and_layout_validation(rng):
     mesh = _mesh(4)
     st = sm.place_state(mesh, cops.init_state(L, D, True), D)
     idx, val, labels, mask = _batch(rng)
-    st = sm.train_batch(mesh, st, idx, val, labels, mask, 1.0,
-                        method="AROW")
+    st = sm.train_batch(mesh, st, *_routed(mesh, idx, val), labels, mask,
+                        1.0, method="AROW")
     chunks = sm.shard_chunks(st.dw)
     assert set(chunks) == {f"c{i * (D // 4)}" for i in range(4)}
     assert all(c.shape == (L, D // 4) for c in chunks.values())
