@@ -17,7 +17,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from . import check, datagen, stats
+from . import check, stats
 from .loadgen import Client, Group, LoadGen
 from .servers import Fleet
 
@@ -60,9 +60,11 @@ class Run:
         self.control: Dict[str, Any] = {}
         self.readings: List[Dict[str, Any]] = []
         self.plan_failed = 0
-        #: the configuration's engine and reference modules, by its names
+        #: the configuration's engine, reference and row generator
+        #: modules, by its names
         self.engine: Any = None
         self.reference: Any = None
+        self.generator: Any = None
         self.pool_rows: Dict[str, List[List[Any]]] = {}
         self.warm_rows: List[tuple] = []
 
@@ -81,7 +83,8 @@ def load_json(path: str) -> Dict[str, Any]:
 
 def load_module(directory: str, name: str) -> Any:
     """``<directory>/<name>.py`` as a module: how a reader, an engine's
-    surface and a reference are found by the name a data file gives."""
+    surface, a reference and a row generator are found by the name a data
+    file gives."""
     spec = importlib.util.spec_from_file_location(
         f"perfbench_{os.path.basename(directory)}_{name.replace('.', '_')}",
         os.path.join(directory, name + ".py"))
@@ -101,8 +104,8 @@ def load_readers(directory: str) -> Dict[str, Any]:
 
 def load_cell(root: str, bdir: str, workload_name: str):
     """(``BENCHMARK.json``, a :class:`Run` that holds the cell's entry, its
-    configuration with the engine and the reference it names, its traffic
-    mix and the peaks), each from its own file."""
+    configuration with the engine, the reference and the row generator it
+    names, its traffic mix and the peaks), each from its own file."""
     bench = load_json(os.path.join(root, "BENCHMARK.json"))
     run = Run()
     run.workload = next(w for w in bench["workloads"]
@@ -117,6 +120,12 @@ def load_cell(root: str, bdir: str, workload_name: str):
                              run.config["engine"])
     run.reference = load_module(os.path.join(bdir, "references"),
                                 run.config["reference"])
+    if "generator" not in run.config["data"]:
+        raise ValueError(
+            f"{conf_entry['file']}: data.generator names no row generator "
+            "(a file of perfbench/generators/, without its .py)")
+    run.generator = load_module(os.path.join(bdir, "generators"),
+                                run.config["data"]["generator"])
     return bench, run
 
 
@@ -128,8 +137,8 @@ def _pools(run: Run, seed: int, name: str) -> Dict[str, List[bytearray]]:
     """One pool of encoded requests per group of the traffic file."""
     pools: Dict[str, List[bytearray]] = {}
     for gi, g in enumerate(run.traffic["groups"]):
-        rows = datagen.make_rows(run.config["data"], seed, 10 + gi,
-                                 g["pool_calls"] * g["rows_per_call"])
+        rows = run.generator.make_rows(run.config["data"], seed, 10 + gi,
+                                       g["pool_calls"] * g["rows_per_call"])
         enc = run.engine.ENCODERS[g["method"]]
         n = g["rows_per_call"]
         run.pool_rows[g["name"]] = [rows[i * n:(i + 1) * n]
@@ -299,7 +308,8 @@ def _warm_calls(run: Run, fleet: Fleet, name: str, seed: int,
     calls = run.traffic["warmup"].get("calls", [])
     frames = []
     for k, c in enumerate(calls):
-        rows = datagen.make_rows(run.config["data"], seed, 500 + k, c["rows"])
+        rows = run.generator.make_rows(run.config["data"], seed, 500 + k,
+                                       c["rows"])
         run.warm_rows.append((c["method"], rows))
         frames.append((c, run.engine.ENCODERS[c["method"]](name, rows)))
 
@@ -434,7 +444,8 @@ def _judge(run: Run, fleet: Fleet, gen: LoadGen, name: str, seed: int,
     plan again through the same servers for each of these seeds, so that a
     dozen readings share one set-up."""
     chk = run.traffic["check"]
-    subject = check.Subject(run.config, run.engine, run.reference, dim)
+    subject = check.Subject(run.config, run.engine, run.reference,
+                            run.generator, dim)
     addresses = [fleet.address(i) for i in range(len(fleet.servers))]
     queue = f"microbatch.{run.engine.QUEUE[run.engine.UPDATE]}"
 

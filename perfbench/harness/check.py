@@ -30,7 +30,6 @@ import time
 import numpy as np
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from . import datagen
 from .loadgen import Client, burst
 
 CHECK_STREAM = 1000      # seed streams of the check's rows start here
@@ -78,14 +77,16 @@ def judge_window(records: Sequence[tuple], groups: Dict[str, Dict[str, Any]],
 
 class Subject:
     """What is checked: a configuration, its engine's RPC surface
-    (``engines/<engine>.py``) and its plain reference
-    (``references/<reference>.py``), at one width."""
+    (``engines/<engine>.py``), its plain reference
+    (``references/<reference>.py``) and the generator of its rows
+    (``generators/<data.generator>.py``), at one width."""
 
     def __init__(self, config: Dict[str, Any], engine: Any, ref: Any,
-                 dim: int) -> None:
+                 generator: Any, dim: int) -> None:
         self.config = config
         self.engine = engine
         self.ref = ref
+        self.generator = generator
         self.data = config["data"]
         self.featurize = ref.Featurizer(config["model"]["converter"], dim)
 
@@ -98,6 +99,11 @@ class Subject:
 
     def request(self, method: str, name: str, rows: List[Any]) -> bytearray:
         return self.engine.ENCODERS[method](name, rows)
+
+    def rows(self, seed: int, stream: int, n: int,
+             key_suffix: str = "") -> List[Any]:
+        return self.generator.make_rows(self.data, seed, stream, n,
+                                        key_suffix)
 
 
 class WindowScores:
@@ -225,6 +231,19 @@ def _rows_gap(result: Any, want: List[Dict[str, float]]) -> float:
     return gap
 
 
+def full_flushes(steps: Sequence[Tuple[int, int]], full_min: int,
+                 full_rows: int) -> int:
+    """How many flushes of the timed size a burst's samples saw. ``steps``
+    are the samples in which the coalescer's ``flush_count`` rose, as
+    (flushes, rows) gained since the sample before. A sample of ``f``
+    flushes counts, as ``f``, where its rows lie between ``f`` times
+    ``full_min`` and ``f`` times ``full_rows``, the sizes of the window's
+    flushes as the traffic file gives them: no flush is larger than
+    ``full_rows``, so at that mean none of them is far below ``full_min``.
+    With ``full_min`` equal to ``full_rows`` only exact flushes count."""
+    return sum(f for f, n in steps if f * full_min <= n <= f * full_rows)
+
+
 class Plan:
     """Runs a check plan against the servers, and again on the reference."""
 
@@ -256,7 +275,7 @@ class Plan:
     # -- rows ----------------------------------------------------------------
     def _rows(self, n: int) -> List[Any]:
         self._stream += 1
-        return datagen.make_rows(self.subject.data, self.seed, self._stream, n)
+        return self.subject.rows(self.seed, self._stream, n)
 
     def _servers(self, step: Dict[str, Any]) -> List[int]:
         which = step.get("server", "each")
@@ -379,9 +398,8 @@ class Plan:
             self._stream += 1
             mine: set = set()
             kept: List[Any] = []
-            for label, strings, nums in datagen.make_rows(
-                    self.subject.data, self.seed, self._stream, rows,
-                    key_suffix=f".{k}"):
+            for label, strings, nums in self.subject.rows(
+                    self.seed, self._stream, rows, key_suffix=f".{k}"):
                 free_s, free_n = [], []
                 for kv in strings:
                     cols = feat.string_columns(*kv)
@@ -408,24 +426,26 @@ class Plan:
         touch its own columns only, and a call is never split between
         flushes, so whichever calls share a flush, and in whatever order
         the flushes go, the model that results is the same. The reference
-        applies them one call a flush. Both labels have to be live before
+        applies them one call a flush. Every label has to be live before
         the burst (a flush with one live label has no rival to step away
-        from), so a plan trains a lone call first.
+        from, and a label that becomes live in the burst is a rival from
+        whichever flush carried it first), so a plan trains a lone call
+        first.
 
         What the flushes were is read back from the coalescer's own
-        counters, sampled all through the burst (:meth:`_watch`): a sample
-        in which ``flush_count`` rose by ``n`` and ``item_count`` by ``n``
-        times ``full_rows`` saw ``n`` flushes of the timed size. Fewer than
-        ``min_full_flushes`` of them and the burst did not drive the timed
-        shape: the plan is run again. (A burst has two or three short
-        flushes while it starts, as the window's stream had before the
-        window, and ends in single calls when the queue runs empty; they
-        are compared with the rest.)"""
+        counters, sampled all through the burst (:meth:`_watch`) and
+        counted by :func:`full_flushes`. Fewer than ``min_full_flushes``
+        of the timed size and the burst did not drive the timed shape: the
+        plan is run again. (A burst has two or three short flushes while
+        it starts, as the window's stream had before the window, and ends
+        in single calls when the queue runs empty; they are compared with
+        the rest.)"""
         servers = self._servers(step)
         calls = {i: self._disjoint_calls(step["calls"], step["rows"])
                  for i in servers}
         conns = int(step.get("connections", step["calls"]))
         full_rows = int(step.get("full_rows", 0))
+        full_min = int(step.get("full_rows_min", full_rows))
 
         def one(i: int) -> None:
             frames = [self._update_frame(r) for r in calls[i]]
@@ -441,12 +461,12 @@ class Plan:
                 self._ack(rec, len(rows), i)
             steps = [(f1 - f0, n1 - n0) for (f0, n0), (f1, n1)
                      in zip(samples, samples[1:]) if f1 != f0]
-            full = sum(f for f, n in steps if n == f * full_rows)
+            full = full_flushes(steps, full_min, full_rows)
             self.log(f"check: server{i}: {len(frames)} calls over {conns} "
                      f"connections were {sum(f for f, _n in steps)} flushes "
                      f"of {sum(n for _f, n in steps)} rows in all, {full} "
-                     f"of them of {full_rows} rows; as sampled (flushes x "
-                     "rows): " + " ".join(
+                     f"of them of {full_min} to {full_rows} rows; as "
+                     "sampled (flushes x rows): " + " ".join(
                          f"{f}x{n // f}" if n % f == 0 else f"{f}:{n}"
                          for f, n in steps))
             if full < step.get("min_full_flushes", 0):

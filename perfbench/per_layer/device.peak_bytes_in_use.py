@@ -1,7 +1,7 @@
 """Device: the allocator's high-water mark since the server started
 (``runtime.jax_device_peak_bytes_in_use``: ``peak_bytes_in_use`` of
-``Device.memory_stats()``, summed over a server's devices), the largest
-of the run's status samples. It holds what the allocator handed out
+``Device.memory_stats()``, the fullest local device's), the largest of
+the run's status samples. It holds what the allocator handed out
 between two of the one-second samples that ``device.bytes_in_use`` reads
 (a flush's uploaded inputs, a mix round's buffers), and no compiled
 step's temporaries: ``memory_stats()`` leaves those out, so where

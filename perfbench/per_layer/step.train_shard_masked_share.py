@@ -1,11 +1,12 @@
 """Step, on a ``--shard-devices`` mesh: share of the gather and scatter
-descriptors the chips issue for a train flush that address nothing their
-chip owns. Every shard is handed every entry of the padded flush and masks
-the ones outside its column range onto one cell of its slice, so counters
-``step.train.shard_entries_issued`` (shards x padded rows x width) less
-``step.train.shard_entries`` (entries that carry a feature), over the
-former: 75% and the padding on four shards. A step that routed each entry
-to its owner would leave the padding alone."""
+descriptors the chips issue for a train flush that carry no feature. A
+flush is routed by column range on the host, so a shard is handed only the
+entries it owns, its rows padded to the width of the fullest row any shard
+holds: counters ``step.train.shard_entries_issued`` (shards x padded rows
+x routed width) less ``step.train.shard_entries`` (entries that carry a
+feature), over the former. What is left is row padding: 60% on four
+shards at a routed width of 24 where a shard's mean row holds 9.5
+entries; handing every shard every entry cost 75% and the padding."""
 
 from harness import reading
 

@@ -1,8 +1,8 @@
 """Step: share of the entries a train flush's rows have at the program's
 width that carry no feature: counters ``step.train.entries_padded`` (rows
-asked for x the width bucket) less ``step.train.entries``, over the
-former (780 features in a bucket of 1,024: 23.8%). The rows' own padding
-is ``step.train_pad_share``."""
+asked for x the width ladder's rung) less ``step.train.entries``, over
+the former (780 features on the rung of 832: 6.25%). The rows' own
+padding is ``step.train_pad_share``."""
 
 from harness import reading
 

@@ -15,15 +15,29 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 
-
 def load_config(name="criteo_arow"):
     with open(os.path.join(BENCH, "configs", name + ".json")) as f:
         return json.load(f)
 
 
+def generator(config=None):
+    """The row generator the configuration names, as the harness loads
+    it: ``perfbench/generators/<data.generator>.py``."""
+    from harness import cell
+
+    config = config or load_config()
+    return cell.load_module(os.path.join(BENCH, "generators"),
+                            config["data"]["generator"])
+
+
+def make_rows(config, seed, stream, n, key_suffix=""):
+    return generator(config).make_rows(config["data"], seed, stream, n,
+                                       key_suffix)
+
+
 def subject(dim, config=None):
-    """The configuration with the engine and the reference it names, as
-    the harness loads them."""
+    """The configuration with the engine, the reference and the generator
+    it names, as the harness loads them."""
     from harness import cell, check
 
     config = config or load_config()
@@ -31,7 +45,7 @@ def subject(dim, config=None):
         config, cell.load_module(os.path.join(BENCH, "engines"),
                                  config["engine"]),
         cell.load_module(os.path.join(BENCH, "references"),
-                         config["reference"]), dim)
+                         config["reference"]), generator(config), dim)
 
 
 #: a check plan that needs no timing: lone calls only. On the CPU a step

@@ -34,10 +34,8 @@ universe_of, label_scores = cross.universe_of, cross.label_scores
 
 
 def _rows(n, stream=1):
-    from harness import datagen
-
     conf = u.load_config("criteo_arow_cross")
-    return conf, datagen.make_rows(conf["data"], 2200000123, stream, n)
+    return conf, u.make_rows(conf, 2200000123, stream, n)
 
 
 def test_the_configuration_is_criteo_arow_with_the_upstream_rule():
@@ -138,8 +136,8 @@ def test_the_cells_rehearsal_is_correct():
     assert m["ingest.cross_slots_per_row"] == 741
     assert m["ingest.cross_generic_share"] == 0
     assert m["ingest.cross_us_per_row"] > 0
-    # 780 features less the merged ones, in the width bucket of 1,024
-    assert 23.8 <= m["step.train_width_pad_share"] < 26
+    # 780 features less the merged ones, on the width ladder's rung of 832
+    assert 6.2 <= m["step.train_width_pad_share"] < 8
     assert m["step.train_upload_mb_per_flush"] > 1
     assert m["compile.in_window"] == 0
     assert m["ingest.sparse_flush_share"] == 100

@@ -1,12 +1,16 @@
-"""A later PR adds a configuration, a traffic mix, a per-layer metric and
-a cell with new files and one ``workloads`` entry, and edits no file that
-is there. This does exactly that in a throw-away checkout and runs the
-new cell (CPU rehearsal)."""
+"""A later PR adds a configuration, a traffic mix, a per-layer metric, a
+row generator and a cell with new files and one ``workloads`` entry, and
+edits no file that is there. This does exactly that in a throw-away
+checkout and runs the new cells (CPU rehearsal)."""
 
 import json
 import os
+import sys
+
+import pytest
 
 import pbtest_util as u
+from test_rehearsal import BURST_PLAN, TRAIN
 
 NEW_METRIC = '''"""A test's per-layer metric: train calls answered in the window."""
 
@@ -18,14 +22,126 @@ def read(run):
 '''
 
 
-def test_new_files_and_one_entry_make_a_cell(tmp_path):
-    root, bench = u.make_checkout(tmp_path)
+FIVE_WAY = '''"""A test's generator: rows of any number of labels over
+``string_keys`` string keys and ``num_keys`` numeric ones. A row's first
+string value names its label three times in four, which a linear model can
+learn."""
+
+import numpy as np
+
+
+def make_rows(data, seed, stream, n, key_suffix=""):
+    rng = np.random.default_rng([int(seed), int(stream)])
+    labels = data["labels"]
+    y = rng.integers(len(labels), size=n)
+    said = np.where(rng.random(n) < 0.75, y, rng.integers(len(labels), size=n))
+    token = rng.integers(int(data["values"]), size=(n, int(data["string_keys"])))
+    number = 1.0 + np.floor(rng.random((n, int(data["num_keys"]))) * 8.0) / 4.0
+    rows = []
+    for i in range(n):
+        strings = [(f"S{j}{key_suffix}", f"v{t}")
+                   for j, t in enumerate(token[i].tolist())]
+        strings[0] = (strings[0][0], f"says-{said[i]}-{token[i, 0] % 5}")
+        rows.append((labels[y[i]], strings,
+                     [(f"N{j}{key_suffix}", v)
+                      for j, v in enumerate(number[i].tolist())]))
+    return rows
+'''
+
+
+def _files(root):
     before = {}
     for d, _dirs, files in os.walk(os.path.join(root, "perfbench")):
         for fn in files:
             p = os.path.join(d, fn)
             with open(p, "rb") as f:
                 before[p] = f.read()
+    return before
+
+
+def _five_way(root, bench, generator="five_way"):
+    """A configuration of five labels that names a generator of its own,
+    and a train cell on it: files that were not there, and entries."""
+    conf = u.load_config()
+    conf.update(name="five_way", live_labels=5, features_per_row=5)
+    conf["rehearsal"]["hash_max_size"] = 1 << 14
+    conf["data"] = {"labels": list("abcde"), "string_keys": 3, "num_keys": 2,
+                    "values": 40}
+    if generator is not None:
+        conf["data"]["generator"] = generator
+    with open(os.path.join(root, "perfbench", "configs", "five_way.json"),
+              "w") as f:
+        json.dump(conf, f)
+    bench["configs"].append({
+        "name": "five_way", "source": "a test",
+        "file": "perfbench/configs/five_way.json", "reduced": [],
+        "why": "a test's configuration"})
+    u.add_cell(root, bench, "five_way.t_train", "five_way", "t_train",
+               u.small_traffic(TRAIN, plan=BURST_PLAN),
+               like="criteo_arow.train")
+    return conf
+
+
+@pytest.mark.parametrize("fault", ["none", "half_batch"])
+def test_a_generator_file_a_configuration_and_one_entry_make_a_cell(
+        tmp_path, fault):
+    """Five labels and keys of its own through the real server, the shipped
+    engine surface and the shipped ``linear_classifier`` reference: the
+    harness finds the rows by the name in the configuration's ``data``,
+    and the comparison is as sharp on five labels as on two (a server
+    that trains on half of every flush is not ``correct``)."""
+    root, bench = u.make_checkout(tmp_path)
+    before = _files(root)
+    os.makedirs(os.path.join(root, "perfbench", "generators"), exist_ok=True)
+    with open(os.path.join(root, "perfbench", "generators", "five_way.py"),
+              "w") as f:
+        f.write(FIVE_WAY)
+    conf = _five_way(root, bench)
+
+    res = u.rehearse(root, "five_way.t_train", server_entry=[
+        sys.executable, os.path.join(u.HERE, "faulty_server.py"), fault])
+    gap = res["compared"]["score_gap"]
+    if fault == "none":
+        assert res["correct"] is True, res["compared"]
+        assert res["failed"] == 0 and res["attempted"] > 0
+        assert res["metrics"]["train_rows_per_s"]["value"] > 0
+        assert gap["value"] > 0                  # a model was learnt
+    else:
+        assert res["correct"] is False
+        assert not gap["value"] <= gap["limit"]
+    # the rows were the new generator's, all five labels among them
+    from harness import cell
+
+    _bench, run = cell.load_cell(root, os.path.join(root, "perfbench"),
+                                 "five_way.t_train")
+    rows = run.generator.make_rows(conf["data"], 2200000123, 10, 400)
+    assert {r[0] for r in rows} == set("abcde")
+    assert {k for r in rows for k, _v in r[1] + r[2]} \
+        == {"S0", "S1", "S2", "N0", "N1"}
+    # nothing that was there has changed
+    for p, content in before.items():
+        with open(p, "rb") as f:
+            assert f.read() == content, p
+
+
+@pytest.mark.parametrize("generator,error,names", [
+    (None, ValueError, "data.generator"),
+    ("no_such_file", FileNotFoundError, "no_such_file.py"),
+])
+def test_a_configuration_that_names_no_generator_is_refused_by_name(
+        tmp_path, generator, error, names):
+    """No silent default: the key is asked for by its name (and a name
+    with no file behind it by the file's), before a server is started."""
+    root, bench = u.make_checkout(tmp_path)
+    _five_way(root, bench, generator=generator)
+    with pytest.raises(error, match=names):
+        u.rehearse(root, "five_way.t_train")
+    assert not os.path.exists(os.path.join(root, ".perfbench_run"))
+
+
+def test_new_files_and_one_entry_make_a_cell(tmp_path):
+    root, bench = u.make_checkout(tmp_path)
+    before = _files(root)
 
     # a configuration: the same engine at another width, in a file of its own
     with open(os.path.join(root, "perfbench", "configs",

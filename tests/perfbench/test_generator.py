@@ -1,6 +1,9 @@
-"""The generator is a function of the seed: same seed, same bytes;
-another seed, other rows. Its rows have the configuration's shape, and the
-reference's hashing is the program's."""
+"""A generator is a function of the seed: same seed, same bytes; another
+seed, other rows. ``click_log``'s rows have the configuration's shape and
+are the rows the benchmark has always sent, and the reference's hashing
+is the program's."""
+
+import hashlib
 
 import numpy as np
 
@@ -10,15 +13,16 @@ import pbtest_util
 from harness import datagen, wire
 
 config = pbtest_util.load_config
+make_rows = pbtest_util.make_rows
 
 
 def test_same_seed_same_bytes_other_seed_other_rows():
-    data = config()["data"]
+    conf = config()
     big = 3000000019     # more than 32 signed bits hold
-    a = datagen.make_rows(data, big, 10, 200)
-    b = datagen.make_rows(data, big, 10, 200)
-    c = datagen.make_rows(data, big + 1, 10, 200)
-    d = datagen.make_rows(data, big, 11, 200)
+    a = make_rows(conf, big, 10, 200)
+    b = make_rows(conf, big, 10, 200)
+    c = make_rows(conf, big + 1, 10, 200)
+    d = make_rows(conf, big, 11, 200)
     engine = pbtest_util.subject(1 << 16).engine
     for encode in engine.ENCODERS.values():
         assert encode("n", a) == encode("n", b)
@@ -27,7 +31,7 @@ def test_same_seed_same_bytes_other_seed_other_rows():
 
 def test_rows_have_the_click_logs_shape():
     conf = config()
-    rows = datagen.make_rows(conf["data"], 7, 0, 4000)
+    rows = make_rows(conf, 7, 0, 4000)
     label, strings, nums = rows[0]
     assert len(strings) == 26 and len(nums) == 13
     assert [k for k, _ in nums] == [f"I{i}" for i in range(1, 14)]
@@ -43,6 +47,45 @@ def test_rows_have_the_click_logs_shape():
     # a Zipf field: the commonest value of the widest field is common
     top = max(np.unique([r[1][25][1] for r in rows], return_counts=True)[1])
     assert top > 40
+
+
+#: recorded on the parent of the PR that moved the generator out of the
+#: harness (5614cde), at seed 2200000123: ``repr`` of 2,000 rows of stream
+#: 10, and the 64 encoded requests of ``traffic/train.json``'s group 0
+GOLDEN = {
+    "rows": "b16738cc46498b1840e126643569b5a9a8cc2cf586a39fd7741c8227b51f7f46",
+    "pool": "f1402e4434e71c6693dfecefda5f0c900c77948b9c7774a1ce023fac3d118035",
+}
+
+
+@pytest.mark.parametrize("what", sorted(GOLDEN))
+def test_click_log_sends_what_the_benchmark_has_always_sent(what):
+    """The cells' rows and request bytes are those of the generator that
+    lived in the harness: a reading of before the move compares with one
+    of after it."""
+    from harness import cell
+
+    if what == "rows":
+        conf = config()
+        digest = hashlib.sha256(repr(make_rows(
+            conf, 2200000123, 10, 2000)).encode()).hexdigest()
+    else:
+        _bench, run = cell.load_cell(pbtest_util.REPO, pbtest_util.BENCH,
+                                     "criteo_arow.train")
+        group = run.traffic["groups"][0]["name"]
+        pool = cell._pools(run, 2200000123, run.config["cluster_name"])[group]
+        assert len(pool) == 64 and sum(map(len, pool)) == 17185728
+        digest = hashlib.sha256(b"".join(bytes(f) for f in pool)).hexdigest()
+    assert digest == GOLDEN[what]
+
+
+def test_the_shipped_configurations_name_click_log_and_it_wants_two_labels():
+    for name in ("criteo_arow", "criteo_arow_cross", "criteo_arow_sharded4"):
+        assert config(name)["data"]["generator"] == "click_log"
+    conf = config()
+    conf["data"]["labels"] = ["a", "b", "c"]
+    with pytest.raises(ValueError, match="data.labels"):
+        make_rows(conf, 7, 0, 10)
 
 
 def test_zipf_ranks_stay_in_range_and_skew():
@@ -72,7 +115,7 @@ def test_reference_featurize_is_the_programs_converter():
     conv = make_fv_converter(dict(conf["model"]["converter"],
                                   hash_max_size=dim))
     featurize = pbtest_util.subject(dim).featurize
-    for row in datagen.make_rows(conf["data"], 11, 3, 20):
+    for row in make_rows(conf, 11, 3, 20):
         d = Datum()
         for k, v in row[1]:
             d.add_string(k, v)

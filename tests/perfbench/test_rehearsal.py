@@ -3,6 +3,7 @@ each cell's kind at a tiny width, through real server processes. It names
 its platform, reports nothing under a device metric's name, and comes out
 ``correct``; with a fault planted in the timed path it comes out not."""
 
+import json
 import os
 import subprocess
 import sys
@@ -112,6 +113,42 @@ def test_a_burst_that_was_not_cut_into_the_timed_flushes_is_not_correct(
     assert res["compared"]["flushes_not_as_planned"]["value"] == 1
     assert res["compared"]["score_gap"]["value"] \
         <= res["compared"]["score_gap"]["limit"]
+
+
+#: what the watcher sampled of three attempts of ``criteo_arow.train``'s
+#: burst on the chip (my chip runs, PR 33: run p1), as (flushes, rows)
+SAMPLED = [
+    [(2, 1000), (1, 1000), (2, 14000), (2, 16000), (2, 15500), (2, 13500),
+     (3, 20000), (1, 5000), (2, 4000), (2, 1000), (3, 2500), (3, 1500),
+     (1, 500), (1, 500)],
+    [(2, 1000), (2, 10000), (1, 8000), (1, 8000), (6, 45000), (2, 13500),
+     (2, 4000), (2, 1000), (3, 3500), (1, 500), (1, 500), (1, 500), (1, 500)],
+    [(2, 1000), (2, 3500), (1, 6000), (1, 8000), (2, 16000), (2, 16000),
+     (1, 8000), (2, 16000), (2, 14500), (2, 4000), (3, 1500), (1, 500),
+     (1, 500), (1, 500)],
+]
+
+
+@pytest.mark.parametrize("steps,exact,timed", zip(SAMPLED, (2, 2, 8),
+                                                   (11, 10, 10)))
+def test_a_flush_of_thirteen_to_sixteen_calls_is_of_the_timed_size(
+        steps, exact, timed):
+    """The train cells' traffic file counts a sampled flush of 6,500 to
+    8,000 rows as one of the timed size; with no ``full_rows_min`` only
+    exact ones count, and the first two of these attempts fell short of
+    the five asked."""
+    from harness import check
+
+    with open(os.path.join(u.BENCH, "traffic", "train.json")) as f:
+        step = next(s for s in json.load(f)["check"]["steps"]
+                    if s["op"] == "train_burst")
+    assert (step["full_rows_min"], step["full_rows"]) == (6500, 8000)
+    assert check.full_flushes(steps, 8000, 8000) == exact
+    assert check.full_flushes(steps, 6500, 8000) == timed \
+        >= step["min_full_flushes"]
+    # single calls and the start's short flushes never count
+    assert check.full_flushes([(3, 1500), (1, 500), (2, 10000)],
+                              6500, 8000) == 0
 
 
 def test_the_command_fails_without_a_tpu():
