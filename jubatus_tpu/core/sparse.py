@@ -11,8 +11,9 @@ adds add 0).
 Pad widths follow the rows: the widest row is rounded up to a rung of an
 eighth-octave ladder (``_width_bucket``: at most an eighth of padding, 8
 rungs per doubling), so XLA compiles O(log max_nnz) programs, not one per
-batch shape, and a 39-feature row runs at 40, not 64. Row counts are
-bucketed to powers of two (``_bucket``).
+batch shape, and a 39-feature row runs at 40, not 64; rows of uneven
+length keep to powers of two (``_request_width``). Row counts are bucketed
+to powers of two (``_bucket``).
 """
 
 from __future__ import annotations
@@ -46,6 +47,28 @@ def _width_bucket(n: int, minimum: int = 8) -> int:
     while step * 16 < n:
         step *= 2
     return (n + step - 1) // step * step
+
+
+def _request_width(counts: np.ndarray, minimum: int = 8) -> int:
+    """The width the rows of one request are packed at, ``counts`` their
+    entries: the ladder's rung of the fullest row where the rows fill at
+    least half of their entries at that rung (rows that are alike: 39 of
+    40, 780 of 832), else the power of two at or above the fullest row.
+    Where lengths are heavy-tailed the fullest row's rung says nothing of
+    the padding, which rung a request lands on is chance, and every rung
+    is a compiled program; on powers of two a server's life sees one
+    program a doubling. A stop-gap: it buys a bounded set of programs and
+    leaves the padding (nine entries in ten of a 500-document call, at
+    which the train step, a classify and the quality plane's scoring of
+    its first rows all run), and goes when requests come off the parser
+    in a form without it. The same arithmetic as ``pack`` in
+    native/fast_ingest.cpp (tests/test_sparse_width.py holds the two
+    equal)."""
+    most = int(counts.max()) if counts.size else 1
+    rung = _width_bucket(most, minimum)
+    if 2 * int(counts.sum()) >= counts.size * rung:
+        return rung
+    return 1 << (max(most, minimum, 8) - 1).bit_length()
 
 
 class CSRBatch:
@@ -130,13 +153,13 @@ class CSRBatch:
     def to_padded(self, min_width: int = 8,
                   batch_bucket: int = 1) -> "SparseBatch":
         """Vectorized pad into the [B, K] device interchange format —
-        the CSR equivalent of SparseBatch.from_vectors (same width rung
+        the CSR equivalent of SparseBatch.from_vectors (same width rule
         and optional row bucketing, no Python per-row loop)."""
         b = self.batch_size
         counts = np.diff(self.row_offsets)
         bsz = _bucket(max(b, 1), batch_bucket) if batch_bucket > 1 \
             else max(b, 1)
-        width = _width_bucket(int(counts.max()) if b else 1, min_width)
+        width = _request_width(counts, min_width)
         idx = np.zeros((bsz, width), dtype=np.int32)
         val = np.zeros((bsz, width), dtype=np.float32)
         if self.nnz:
@@ -180,14 +203,14 @@ class SparseBatch:
     ) -> "SparseBatch":
         """Pack hashed sparse vectors into padded arrays.
 
-        Widths are rounded up to a rung of ``_width_bucket``'s ladder (and
-        optionally batch sizes to a power of two) to bound the number of
-        distinct XLA compilations.
+        Widths follow ``_request_width`` (a rung of ``_width_bucket``'s
+        ladder, or a power of two for uneven rows; optionally batch sizes
+        a power of two) to bound the number of distinct XLA compilations.
         """
         n = len(vectors)
         bsz = _bucket(max(n, 1), batch_bucket) if batch_bucket > 1 else max(n, 1)
-        width = _width_bucket(max((len(v) for v in vectors), default=1),
-                              min_width)
+        width = _request_width(
+            np.fromiter((len(v) for v in vectors), np.int64, n), min_width)
         idx = np.zeros((bsz, width), dtype=np.int32)
         val = np.zeros((bsz, width), dtype=np.float32)
         for i, vec in enumerate(vectors):

@@ -143,6 +143,13 @@ class ClassifierDriver(DriverBase):
                                     self._confidence, self._sharding)
         self.label_counts = np.zeros(self.capacity, dtype=np.float32)
         self._dcounts = np.zeros(self.capacity, dtype=np.float32)
+        self._label_gauges()
+
+    def _label_gauges(self) -> None:
+        """Live labels beside the rows the tables reserve for them."""
+        if self.trace is not None:
+            self.trace.gauge("model.labels_live", len(self.label_slots))
+            self.trace.gauge("model.label_capacity", self.capacity)
 
     # -- label management ----------------------------------------------------
     def _mask(self) -> jnp.ndarray:
@@ -161,17 +168,23 @@ class ClassifierDriver(DriverBase):
         if free:
             slot = free[0]
         else:
-            self.capacity *= 2
-            self.state = ops.grow_labels(self.state, self.capacity,
-                                         self._sharding)
+            # every table is copied into one of twice the rows, under the
+            # caller's lock; the old state lives until the copy has run
+            with self._span("model.grow_labels"):
+                self.capacity *= 2
+                self.state = ops.grow_labels(self.state, self.capacity,
+                                             self._sharding)
             self.label_counts = np.pad(self.label_counts, (0, self.capacity // 2))
             self._dcounts = np.pad(self._dcounts, (0, self.capacity // 2))
             slot = len(self.labels)
+            if self.trace is not None:
+                self.trace.count("model.label_grow")
         if slot == len(self.labels):
             self.labels.append(label)
         else:
             self.labels[slot] = label
         self.label_slots[label] = slot
+        self._label_gauges()
         return slot
 
     @locked
@@ -202,6 +215,7 @@ class ClassifierDriver(DriverBase):
         self.label_counts[slot] = 0.0
         self._dcounts[slot] = 0.0
         self.labels[slot] = ""
+        self._label_gauges()
         return True
 
     @locked
@@ -443,6 +457,9 @@ class ClassifierDriver(DriverBase):
         if trace is not None:
             trace.count("step.classify.rows", n)
             trace.count("step.classify.rows_padded", b)
+            # each distinct width is a scores program (times the row
+            # buckets), as step.train.width_<K> is a train program
+            trace.count(f"step.classify.width_{idx.shape[1]}")
         # (label, score) pairs are the answer as it goes on the wire: the
         # packer writes a tuple as it writes a list, so the service hands
         # these rows on as they are
@@ -528,6 +545,7 @@ class ClassifierDriver(DriverBase):
         self.capacity = new_cap
         self.labels = list(union_schema) + [""] * (new_cap - len(union_schema))
         self.label_slots = {lab: i for i, lab in enumerate(union_schema)}
+        self._label_gauges()
 
     def get_mixables(self):
         return {"classifier": _ClassifierMixable(self), "weights": self.converter.weights}
@@ -565,6 +583,7 @@ class ClassifierDriver(DriverBase):
             s.decode() if isinstance(s, bytes) else s for s in obj["labels"]
         ]
         self.label_slots = {lab: i for i, lab in enumerate(self.labels) if lab}
+        self._label_gauges()
         # each leaf goes from the host to where it lies: on a mesh every
         # device is sent its own columns
         self._let_go()
@@ -590,6 +609,7 @@ class ClassifierDriver(DriverBase):
         st.update(
             method=self.method,
             num_labels=len(self.label_slots),
+            label_capacity=self.capacity,
             num_features=self.converter.dim,
         )
         st.update({f"shard.{k}": v for k, v in self.shard_stats().items()})

@@ -32,6 +32,16 @@ class Cross(NamedTuple):
     seconds: float         # spent in the cross product
 
 
+class Counts(NamedTuple):
+    """What a parse counted as it went (``parse_indexed`` /
+    ``parse_datums`` with ``counts=True``, always last)."""
+
+    tokens: int   # tokens the string rules cut (a whole value is one)
+    terms: int    # distinct terms of them that became entries
+    pow2: bool    # uneven rows: packed at a power of two, not a rung
+                  # (core/sparse.py _request_width)
+
+
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -54,6 +64,9 @@ class _Out(ctypes.Structure):
         ("base_val", ctypes.POINTER(ctypes.c_float)),
         ("cross_slots", ctypes.c_int64),
         ("cross_ns", ctypes.c_int64),
+        ("str_tokens", ctypes.c_int64),
+        ("str_terms", ctypes.c_int64),
+        ("pow2", ctypes.c_int32),
     ]
 
 
@@ -498,9 +511,17 @@ class IngestParser:
         bidx, bval = self._idx_val(out, base=True)
         return Cross(bidx, bval, int(out.cross_slots), out.cross_ns * 1e-9)
 
-    def parse_indexed(self, raw: bytes, weights=None, cross: bool = False):
+    def _extras(self, out: "_Out", cross: bool, counts: bool) -> tuple:
+        """What a parse hands back behind its arrays."""
+        return ((self._cross(out),) if cross else ()) + ((Counts(
+            int(out.str_tokens), int(out.str_terms), bool(out.pow2)),)
+            if counts else ())
+
+    def parse_indexed(self, raw: bytes, weights=None, cross: bool = False,
+                      counts: bool = False):
         """Raw train params msgpack -> (labels, idx [B,K] i32, val [B,K] f32),
-        and with ``cross`` (combination specs) a :class:`Cross` as fourth.
+        with ``cross`` (combination specs) a :class:`Cross` behind them,
+        and with ``counts`` a :class:`Counts` last.
 
         ``labels`` is a float32 array for regression targets, or — for
         string labels — a ``(uniq_labels, label_idx)`` pair: the DISTINCT
@@ -557,11 +578,9 @@ class IngestParser:
                     out.label_idx, shape=(b,)).copy() if b else \
                     np.zeros(0, np.int32)
                 labels = (uniq, lidx)
-            if cross:
-                return labels, idx, val, self._cross(out)
+            return (labels, idx, val) + self._extras(out, cross, counts)
         finally:
             self._lib.jt_ingest_free_out(ctypes.byref(out))
-        return labels, idx, val
 
     def parse(self, raw: bytes, weights=None):
         """Like parse_indexed but with per-row label strings (compat shape:
@@ -575,11 +594,13 @@ class IngestParser:
             labels = [uniq[i] for i in lidx]
         return labels, idx, val
 
-    def parse_datums(self, raw: bytes, weights=None, cross: bool = False):
+    def parse_datums(self, raw: bytes, weights=None, cross: bool = False,
+                     counts: bool = False):
         """Raw classify/estimate params msgpack ([name, [datum, ...]]) ->
         (idx [B,K] i32, val [B,K] f32), with ``cross`` (combination specs)
-        a :class:`Cross` as third, or None when the wire shape is
-        not a datum list. For idf specs, ``weights`` is read (NOT
+        a :class:`Cross` behind them and with ``counts`` a :class:`Counts`
+        last, or None when the wire shape is not a datum list. For idf
+        specs, ``weights`` is read (NOT
         observed — queries never record documents; caller holds the
         lock)."""
         if self._prefilters is not None:
@@ -603,9 +624,7 @@ class IngestParser:
         if rc != 0:
             return None
         try:
-            if cross:
-                return self._idx_val(out) + (self._cross(out),)
-            return self._idx_val(out)
+            return self._idx_val(out) + self._extras(out, cross, counts)
         finally:
             self._lib.jt_ingest_free_out(ctypes.byref(out))
 

@@ -131,11 +131,31 @@ def grow_labels(state: ClassifierState, new_num_labels: int,
 _PACK_UNDER = 1024
 
 
+def _label_group(num_labels: int, dim: int) -> int:
+    """Label rows that one descriptor fetches: a sublane group of 8 where
+    the table lies in whole tiles of 8 rows x 128 columns, else all."""
+    return 8 if num_labels % 8 == 0 and dim % 128 == 0 else num_labels
+
+
 def gather_plan(num_labels: int, dim: int, n_idx: int) -> str:
     """The plan _gather_sums takes for [num_labels, dim] tables and n_idx
     gathered indices: "columns" or "packed". Static shapes only, so it is
-    settled when the program is traced."""
-    return "columns" if num_labels * dim > _PACK_UNDER * n_idx else "packed"
+    settled when the program is traced. Tables and descriptors both grow
+    with the label groups, so the rule is one group's: what it was at 8
+    labels, at any capacity."""
+    return "columns" if _label_group(num_labels, dim) * dim \
+        > _PACK_UNDER * n_idx else "packed"
+
+
+def _by_group(table):
+    """An [L, D] table of G groups of 8 label rows as [8, G * D]: group g's
+    column c is column g * D + c. The tiles of a group's 8 rows lie one
+    behind another, so the view is the table's own bytes (the reshapes
+    are bitcasts on the TPU, as _scatter_add_tiled's are)."""
+    num_labels, dim = table.shape
+    g = _label_group(num_labels, dim)
+    return table.reshape(num_labels // g, g, dim).transpose(1, 0, 2).reshape(
+        g, -1)
 
 
 def _gather_sums(pairs, idx):
@@ -143,14 +163,37 @@ def _gather_sums(pairs, idx):
     as [L, B, K]; idx is [B, K]. Both plans add the same two floats, so they
     agree to the bit.
 
-    columns: gather the columns out of each table as it lies; the L label
+    A descriptor fetches the label rows of one sublane group, 8 of them.
+    Past 8 labels the tables are addressed a group at a time through
+    _by_group's view, one descriptor a group and index, so the work grows
+    with the groups and nothing table-sized is made that 8 labels do not
+    make. Left to itself XLA first copies every [32, D] table into a
+    column-major layout, 128 lanes for 32 labels: four times each table,
+    20 GB at D = 2^23 (v5e's compiler, PR 34).
+    """
+    num_labels, dim = pairs[0][0].shape
+    g = _label_group(num_labels, dim)
+    if g == num_labels:
+        return _gather_group(pairs, idx)
+    at = idx + (jnp.arange(num_labels // g, dtype=idx.dtype)
+                * dim)[:, None, None]                          # [G, B, K]
+    got = _gather_group([(_by_group(m), _by_group(d)) for m, d in pairs], at)
+    return [jnp.moveaxis(a, 0, 1).reshape((num_labels,) + idx.shape)
+            for a in got]                  # [8, G, B, K] -> [G * 8, B, K]
+
+
+def _gather_group(pairs, idx):
+    """_gather_sums for tables of one label group: [g, D] tables, idx of
+    any shape, the pairs' sums as [g, *idx.shape].
+
+    columns: gather the columns out of each table as it lies; the g label
       rows of a column are the sublanes of one tile, so one descriptor
       fetches them all, and nothing table-sized is made.
     packed: stack the masters, stack the diffs, add the stacks (one pass:
       summing each pair first was three), and fetch everything a feature
-      needs out of that [n*L, D] table with a single descriptor: gather
+      needs out of that [n*g, D] table with a single descriptor: gather
       cost is per DESCRIPTOR, not per element (docs/PERF_NOTES.md). On the
-      TPU the [D, n*L] transpose is that table's own bytes.
+      TPU the [D, n*g] transpose is that table's own bytes.
     """
     num_labels, dim = pairs[0][0].shape
     flat = idx.reshape(-1)

@@ -300,9 +300,11 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
     def _pad_concat(pairs):
         """Merge per-request (idx, val) pairs into one batch: pad widths
         to the max (each already on a rung of the parser's width ladder,
-        and the widest of rungs is a rung, so the flush lands on a compiled
-        width; pads are rare and small) and concatenate at numpy speed.
-        ONE owner for both the train and query flush paths."""
+        or a power of two where its rows are uneven, and the widest of
+        rungs is a rung and of powers of two a power of two, so the flush
+        lands on a compiled width; pads are rare and small where rows are
+        alike) and concatenate at numpy speed. ONE owner for both the
+        train and query flush paths."""
         kmax = max(i.shape[1] for i, _ in pairs)
         parts_i, parts_v = [], []
         for ir, vr in pairs:
@@ -337,6 +339,16 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
         trace.count("fv.combine.rows", rows)
         trace.count("fv.combine.slots", cross.slots)
         trace.count("fv.combine.native")
+
+    def _counted(counts) -> None:
+        """What the parser counted of one request as it went: the tokens
+        its string rules cut, the distinct terms of them that became
+        entries, and whether its rows were uneven enough to be packed at
+        a power of two (core/sparse.py _request_width)."""
+        trace.count("fv.tokens", counts.tokens)
+        trace.count("fv.terms", counts.terms)
+        if counts.pow2:
+            trace.count("fv.pack.pow2")
 
     def _merge_labels(label_pairs):
         """Union per-request (uniq_labels, label_idx) pairs into one
@@ -402,19 +414,21 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
         cross = None
         with trace.span("fv.convert"):
             if parser.combines:
-                parsed = parser.parse_indexed(raw_params, cross=True)
+                parsed = parser.parse_indexed(raw_params, cross=True,
+                                              counts=True)
                 if parsed is not None:
-                    cross, parsed = parsed[3], parsed[:3]
+                    cross, parsed = parsed[3], parsed[:3] + parsed[4:]
             elif weights is not None and not deferred:
                 with weights.lock:
                     parsed = parser.parse_indexed(raw_params,
-                                                  weights=weights)
+                                                  weights=weights,
+                                                  counts=True)
             else:
                 # deferred idf / unweighted: lock-free parallel parse
-                parsed = parser.parse_indexed(raw_params)
+                parsed = parser.parse_indexed(raw_params, counts=True)
         if parsed is None:
             return RAW_FALLBACK
-        labels, idx, val = parsed
+        labels, idx, val, counts = parsed
         if numeric != isinstance(labels, np.ndarray):
             return RAW_FALLBACK  # label kind mismatch: let the
             # generic path produce the proper type error
@@ -423,6 +437,7 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
             return 0
         if cross is not None:
             _crossed(cross, n)
+        _counted(counts)
         item = (labels, idx, val)
         # test-then-train: prequential scoring sees the pre-update model
         _quality_observe_raw(server, item, numeric)
@@ -437,21 +452,23 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
     # the query path rides the same parser: [name, [datum, ...]] -> hashed
     # batch -> snapshot-read scores, no Datum objects
     def _parse_datums(raw_params: bytes):
+        """(idx, val, counts), or None."""
         if deferred:
             # lock-free parse, then one vectorized idf gather (queries
             # read idf, never observe)
-            parsed = parser.parse_datums(raw_params)
+            parsed = parser.parse_datums(raw_params, counts=True)
             if parsed is None:
                 return None
             from jubatus_tpu.native.ingest import deferred_idf_scale
 
-            idx, val = parsed
+            idx, val, counts = parsed
             return idx, deferred_idf_scale(idx, val, weights,
-                                           observe=False)
+                                           observe=False), counts
         if weights is not None:
             with weights.lock:  # queries read idf, never observe
-                return parser.parse_datums(raw_params, weights=weights)
-        return parser.parse_datums(raw_params)
+                return parser.parse_datums(raw_params, weights=weights,
+                                           counts=True)
+        return parser.parse_datums(raw_params, counts=True)
 
     def _query_coalescer(name: str, score_batch):
         """Query-plane microbatching (the mirror of the train coalescer):
@@ -495,18 +512,20 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
             cross = None
             with trace.span("fv.convert"):
                 if parser.combines:
-                    parsed = parser.parse_datums(raw_params, cross=True)
+                    parsed = parser.parse_datums(raw_params, cross=True,
+                                                 counts=True)
                     if parsed is not None:
-                        cross, parsed = parsed[2], parsed[:2]
+                        cross, parsed = parsed[2], parsed[:2] + parsed[3:]
                 else:
                     parsed = _parse_datums(raw_params)
             if parsed is None:
                 return RAW_FALLBACK
-            idx, val = parsed
+            idx, val, counts = parsed
             if idx.shape[0] == 0:
                 return []
             if cross is not None:
                 _crossed(cross, idx.shape[0])
+            _counted(counts)
             return scored(idx, val)
 
         return raw_handler

@@ -611,8 +611,9 @@ extern "C" {
 
 struct JtIngestOut {
   int32_t batch;       // examples parsed
-  int32_t width;       // padded nnz per row: a rung of the width ladder
-                       // (core/sparse.py _width_bucket), >= 8
+  int32_t width;       // padded nnz per row: a rung of the width ladder or
+                       // a power of two (core/sparse.py _request_width),
+                       // >= 8
   int32_t labels_numeric;  // 1: targets[] is set (regression), 0: labels
   int32_t* idx;        // [batch, width], 0-padded
   float* val;          // [batch, width], 0-padded
@@ -630,6 +631,9 @@ struct JtIngestOut {
   float* base_val;     // [batch, base_width]
   int64_t cross_slots;  // pair features emitted, before the merge by index
   int64_t cross_ns;     // nanoseconds spent in the cross product
+  int64_t str_tokens;   // tokens the string rules cut (a whole value is one)
+  int64_t str_terms;    // distinct terms of them that became entries
+  int32_t pow2;         // 1: uneven rows, so width is a power of two
 };
 
 void* jt_ingest_create(const char* spec) {
@@ -875,6 +879,7 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   std::vector<Base> base;  // one example's base features, in name order
   std::vector<uint8_t> lmatch, rmatch;
   int64_t cross_slots = 0, cross_ns = 0;
+  int64_t str_tokens = 0, str_terms = 0;
 
   auto add_named = [&](const std::string& nm, double v) {
     auto it = named_ix.find(nm);
@@ -1092,6 +1097,8 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
             slot = (slot + 1) & (cap - 1);
           }
         }
+        str_tokens += int64_t(T);
+        str_terms += int64_t(distinct.size());
         // (rule, key, term) -> hashed index memo across the request:
         // repeated vocabulary skips name assembly + CRC-32 entirely.
         // The key is LENGTH-PREFIXED (raw keys/terms may contain any
@@ -1293,24 +1300,38 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   }
   if (rd.fail) return 1;
 
-  // pack to [batch, width] at the SparseBatch width bucket: core/sparse.py
-  // _width_bucket's arithmetic (eight rungs an octave, never finer than 8).
+  // pack to [batch, width] at the width of core/sparse.py _request_width,
+  // the same arithmetic: the rung of the fullest row on _width_bucket's
+  // ladder (eight rungs an octave, never finer than 8) where the rows fill
+  // at least half of their entries there (rows that are alike: 39 of 40,
+  // 780 of 832), else the power of two at or above the fullest row. Where
+  // lengths are heavy-tailed (text) which rung a request lands on is
+  // chance and every rung is a compiled program; on powers of two a
+  // server's life sees one program a doubling. A stop-gap: nine entries in
+  // ten of a 500-document call stay padding, and the rule goes when
+  // requests leave here in a form without it.
   // The rows before the cross product (ladder false) keep the power of two:
   // they feed no gather or scatter of their own, and the benchmark's
   // tests/perfbench/test_cross.py holds their width at 64 for 39 features
   // (a benchmark PR's to move: ROADMAP R-B1).
   auto pack = [n](const std::vector<Feature>& fs,
                   const std::vector<int64_t>& offs, bool ladder,
-                  int32_t* width, int32_t** idx, float** val) {
+                  int32_t* width, int32_t* pow2, int32_t** idx,
+                  float** val) {
     int64_t max_nnz = 1;
     for (size_t e = 0; e + 1 < offs.size(); ++e)
       max_nnz = std::max(max_nnz, offs[e + 1] - offs[e]);
     // a step is a sixteenth of the power of two above the row (eight
     // rungs an octave), or that power of two itself
-    const int64_t steps = ladder ? 16 : 1;
-    int64_t nw = std::max<int64_t>(max_nnz, 8), step = 8;
-    while (step * steps < nw) step *= 2;
-    int32_t w = int32_t((nw + step - 1) / step * step);
+    const int64_t nw = std::max<int64_t>(max_nnz, 8);
+    auto rung = [nw](int64_t steps) {
+      int64_t step = 8;
+      while (step * steps < nw) step *= 2;
+      return int32_t((nw + step - 1) / step * step);
+    };
+    int32_t w = rung(ladder ? 16 : 1);
+    *pow2 = ladder && 2 * (offs.back() - offs.front()) < n * int64_t(w);
+    if (*pow2) w = rung(1);
     *width = w;
     *idx = static_cast<int32_t*>(calloc(size_t(n) * w, 4));
     *val = static_cast<float*>(calloc(size_t(n) * w, 4));
@@ -1331,10 +1352,14 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   out->uniq = int32_t(uniq);
   out->cross_slots = cross_slots;
   out->cross_ns = cross_ns;
-  bool packed = pack(feats, offsets, true, &out->width, &out->idx, &out->val);
+  out->str_tokens = str_tokens;
+  out->str_terms = str_terms;
+  int32_t base_pow2;
+  bool packed = pack(feats, offsets, true, &out->width, &out->pow2, &out->idx,
+                     &out->val);
   if (packed && combo_mode)
-    packed = pack(bfeats, boffsets, false, &out->base_width, &out->base_idx,
-                  &out->base_val);
+    packed = pack(bfeats, boffsets, false, &out->base_width, &base_pow2,
+                  &out->base_idx, &out->base_val);
   out->labels = static_cast<uint8_t*>(malloc(labels.size() ? labels.size() : 1));
   out->label_off = static_cast<int32_t*>(malloc((uniq + 1) * 4));
   out->targets = static_cast<float*>(malloc((size_t(n) + 1) * 4));

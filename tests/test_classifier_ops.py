@@ -216,9 +216,11 @@ def _flush(rng, scenario, dim, method, width=None):
         b, k = (3, width - 3) if width else (7, 9)
     elif scenario == "grown_16":
         live = 10
+    elif scenario == "grown_32":        # twenty labels: four sublane groups
+        live = 20
     state = _warm_state(rng, CAP, dim, method, min(live, CAP))
-    if scenario == "grown_16":
-        cap = 16
+    if scenario in ("grown_16", "grown_32"):
+        cap = int(scenario[-2:])
         state = C.grow_labels(state, cap)
         assert state.w.shape == (cap, dim)
     idx = rng.integers(1, dim, size=(b, k)).astype(np.int32)
@@ -261,13 +263,17 @@ def _flush_by_the_per_datum_rule(state, idx, val, labels, mask, method):
 
 
 SCENARIOS = ["hot_column", "single_label", "padding", "grown_16", "ragged",
-             "uniform_rows"]
+             "uniform_rows", "grown_32"]
 
 
 @pytest.mark.parametrize("method,scenario,dim,plan,width", [
     (m, s) + p for p in PLANS for s in SCENARIOS
-    # at the wide row: one method with a precision table and one without
-    for m in (C.METHODS if p[2] is None else ("PA", "AROW"))])
+    # at the wide row, and at 32 label rows: one method with a precision
+    # table and one without (and 32 rows at the narrow widths alone: the
+    # per-datum rule is slow there)
+    for m in (C.METHODS if p[2] is None and s != "grown_32"
+              else ("PA", "AROW"))
+    if s != "grown_32" or p[2] in (None, 40)])
 def test_a_flush_is_its_rows_by_the_per_datum_rule(method, scenario, dim, plan,
                                                    width, rng):
     state, idx, val, labels, mask = _flush(rng, scenario, dim, method, width)
@@ -352,6 +358,190 @@ def test_scores_are_the_same_bits_on_either_gather_plan(dim, plan, width, rng):
     np.testing.assert_allclose(got[:, :3], want[:, :3], rtol=1e-5,
                                atol=1e-4 if width else 1e-6)
     assert (got[:, 3:] == C._NEG).all()
+
+
+@pytest.mark.parametrize("plan", ["columns", "packed"])
+@pytest.mark.parametrize("cap,dim", [(16, 1 << 10), (32, 1 << 12), (32, 384),
+                                     (24, 200), (12, 256)])
+def test_past_eight_labels_the_gather_is_the_plain_gather_to_the_bit(
+        cap, dim, plan, rng, monkeypatch):
+    """Tables of several sublane groups are addressed a group at a time
+    (``_by_group``), where they lie in whole tiles; what comes back is
+    ``(master + diff)[:, idx]`` for every pair, on either plan, bit for
+    bit, and the plan is one group's rule whatever the capacity."""
+    pairs = [(jnp.asarray(rng.normal(size=(cap, dim)), jnp.float32),
+              jnp.asarray(rng.normal(size=(cap, dim)), jnp.float32))
+             for _ in range(2)]
+    idx = jnp.asarray(rng.integers(0, dim, size=(7, 9)), jnp.int32)
+    group = C._label_group(cap, dim)
+    assert group == (8 if cap % 8 == 0 and dim % 128 == 0 else cap)
+    assert C.gather_plan(cap, dim, 63) == C.gather_plan(group, dim, 63)
+    monkeypatch.setattr(C, "gather_plan", lambda *a: plan)
+    got = jax.jit(C._gather_sums)(pairs, idx)
+    for (m, d), g in zip(pairs, got):
+        want = (np.asarray(m) + np.asarray(d))[:, np.asarray(idx)]
+        assert g.shape == (cap, 7, 9)
+        assert np.asarray(g).tobytes() == want.tobytes()
+    # the view is the table, group g's column c at column g * dim + c
+    view = np.asarray(C._by_group(pairs[0][0]))
+    table = np.asarray(pairs[0][0])
+    assert view.shape == (group, cap // group * dim)
+    for g in range(cap // group):
+        assert np.array_equal(view[:, g * dim:(g + 1) * dim],
+                              table[g * group:(g + 1) * group])
+
+
+def test_a_model_grown_to_32_in_one_call_is_one_born_at_32(rng, monkeypatch):
+    """Twenty labels in a model's first call: the tables go 8 -> 16 -> 32
+    rows under the driver's lock before the first step. What the call
+    leaves is, to the bit, what a model with 32 rows from the start holds,
+    and the growth is counted, timed and shown as gauges."""
+    from jubatus_tpu.models import classifier as M
+    from jubatus_tpu.utils import tracing
+
+    idx = rng.integers(1, DIM, size=(60, 24)).astype(np.int32)
+    val = rng.normal(size=(60, 24)).astype(np.float32)
+    names = [f"label{i % 20:02d}" for i in range(60)]
+    grown = M.ClassifierDriver(AROW_CONF, dim_bits=12)
+    grown.trace = reg = tracing.Registry()
+    assert grown.capacity == 8
+    monkeypatch.setattr(M, "_INITIAL_CAPACITY", 32)
+    born = M.ClassifierDriver(AROW_CONF, dim_bits=12)
+    assert born.capacity == 32
+    for d in (grown, born):
+        for _ in range(2):
+            d.train_hashed(names, idx, val)
+    assert grown.capacity == 32 and grown.labels == born.labels
+    for a, b in zip(grown.state, born.state):
+        assert a.shape == (32, DIM)
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert grown.classify_hashed(idx, val) == born.classify_hashed(idx, val)
+    assert len(grown.classify_hashed(idx[:3], val[:3])[0]) == 20
+    assert reg.counters()["model.label_grow"] == 2
+    assert reg.trace_status()["trace.model.grow_labels.count"] == 2
+    assert reg.gauges()["model.labels_live"] == 20
+    assert reg.gauges()["model.label_capacity"] == 32
+    assert grown.get_status()["label_capacity"] == 32
+    # and after a clear the model is born small again, and says so
+    monkeypatch.setattr(M, "_INITIAL_CAPACITY", 8)
+    grown.clear()
+    assert reg.gauges()["model.labels_live"] == 0
+    assert reg.gauges()["model.label_capacity"] == 8
+
+
+@pytest.mark.parametrize("narrow,wide", [(704, 1024), (40, 64), (320, 512)])
+def test_a_flush_at_a_rung_and_at_a_power_of_two_is_one_model(narrow, wide,
+                                                              rng):
+    """The width rule packs uneven rows at the power of two where chance
+    gave a rung (704 for 1,024): the padding is (column 0, value 0)
+    entries, and the model that results is the same to the bit on the
+    CPU, as are the scores."""
+    dim = 1 << 14
+    state = C.grow_labels(_warm_state(rng, CAP, dim, "AROW", 8), 32)
+    mask = jnp.asarray(np.arange(32) < 20)
+    b = 12
+    counts = rng.integers(1, narrow + 1, size=b)
+    counts[0] = narrow
+    idx = np.zeros((b, narrow), np.int32)
+    val = np.zeros((b, narrow), np.float32)
+    for i, n in enumerate(counts):
+        idx[i, :n] = rng.integers(1, dim, size=n)
+        val[i, :n] = 1.0
+    labels = jnp.asarray(rng.integers(0, 20, size=b), jnp.int32)
+    pad = ((0, 0), (0, wide - narrow))
+    got = []
+    for i, v in ((idx, val), (np.pad(idx, pad), np.pad(val, pad))):
+        new = C.train_batch_parallel(_fresh(state), jnp.asarray(i),
+                                     jnp.asarray(v), labels, mask, 1.0,
+                                     method="AROW")
+        got.append([np.asarray(a) for a in new]
+                   + [np.asarray(C.scores(new, jnp.asarray(i),
+                                          jnp.asarray(v), mask))])
+    for a, w in zip(*got):
+        assert a.tobytes() == w.tobytes()
+    assert np.abs(got[0][1]).max() > 0
+
+
+def _uneven_flush(rng, b, k, dim, labels=20):
+    """Rows of heavy-tailed length at width ``k``: the fullest first, one
+    with no feature, one with a zeroed entry in its middle (what the
+    ingest's finite screen leaves of a NaN)."""
+    counts = np.clip(np.floor(np.exp(rng.normal(4.5, 0.8, size=b)) * 0.73),
+                     1, k).astype(int)
+    counts[0], counts[3] = k, 0
+    idx = np.zeros((b, k), np.int32)
+    val = np.zeros((b, k), np.float32)
+    for i, n in enumerate(counts):
+        idx[i, :n] = np.sort(rng.choice(np.arange(1, dim), size=n,
+                                        replace=False))
+        val[i, :n] = rng.uniform(0.5, 2.0, size=n)
+    idx[5, 1], val[5, 1] = 0, 0.0
+    return idx, val, rng.integers(0, labels, size=b).astype(np.int32)
+
+
+@pytest.mark.parametrize("method", ["AROW", "PA", "CW"])
+@pytest.mark.parametrize("width", [1024, 704, 320])
+def test_uneven_rows_that_share_no_column_train_as_one_by_one(method, width,
+                                                              rng):
+    """A flush of uneven rows at 32 label rows (20 live: a rival is chosen
+    among 19), at the width of its fullest row. Where no two rows share a
+    column every row meets the model the flush began with, so the
+    vectorised step leaves what the reference's one-by-one scan leaves, up
+    to the order of float additions; a row without a feature and a zeroed
+    entry change nothing."""
+    dim, b = 1 << 14, 96
+    state = C.grow_labels(_warm_state(rng, CAP, dim, method, 8), 32)
+    mask = jnp.asarray(np.arange(32) < 20)
+    idx, val, labels = _uneven_flush(rng, b, width, dim)
+    # the same lengths over columns no two rows share
+    cols = rng.permutation(np.arange(1, dim)).astype(np.int32)
+    used = idx != 0
+    assert used.sum() < cols.size and used[0].all() and not used[3].any()
+    idx[used] = cols[:used.sum()]
+    got = [C.train_batch(_fresh(state), jnp.asarray(idx), jnp.asarray(val),
+                         jnp.asarray(labels), mask, 1.0, method=method,
+                         mode=mode) for mode in ("parallel", "sequential")]
+    for a, w in zip(*got):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                   rtol=2e-5, atol=2e-6)
+    assert np.abs(np.asarray(got[0].dw) - np.asarray(state.dw)).max() > 0
+    # rows 24 to 31 hold no live label and are never written
+    assert not np.asarray(got[0].dw)[20:].any()
+
+
+@pytest.mark.parametrize("kind,width", [
+    ("click_log", 40), ("cross", 832), ("text", 1024), ("text", 128)])
+def test_the_driver_runs_a_flush_at_the_width_it_came_at(kind, width, rng):
+    """Rows that are alike and rows of uneven length run as they come: one
+    program a width, the rows and the entries (those that carry a feature,
+    and those the width makes of them) counted as the program ran them."""
+    from jubatus_tpu.models import classifier as M
+    from jubatus_tpu.utils import tracing
+
+    dim, b = 1 << 14, 200
+    if kind == "text":
+        idx, val, _ = _uneven_flush(rng, b, width, dim)
+    else:
+        idx = rng.integers(1, dim, size=(b, width)).astype(np.int32)
+        idx[:, width - (1 if kind == "click_log" else 52):] = 0
+        val = (idx != 0).astype(np.float32)
+    names = [f"label{i % 20:02d}" for i in range(b)]
+    d = M.ClassifierDriver(AROW_CONF, dim_bits=14)
+    d.trace = reg = tracing.Registry()
+    for _ in range(2):
+        assert d.train_hashed(names, idx, val) == b
+    c = reg.counters()
+    assert [k for k in c if k.startswith("step.train.width_")] \
+        == [f"step.train.width_{width}"]
+    assert c[f"step.train.width_{width}"] == 2
+    assert c["step.train.rows"] == 2 * b
+    assert c["step.train.rows_padded"] == 2 * 256
+    assert c["step.train.entries"] == 2 * np.count_nonzero(idx)
+    assert c["step.train.entries_padded"] == 2 * b * width
+    assert c["step.train.upload_bytes"] == 2 * (256 * width * 8 + 256 * 4)
+    assert d.update_count == 2 * b and d.capacity == 32
+    assert len(d.classify_hashed(idx[:8], val[:8])[0]) == 20
+    assert reg.counters()[f"step.classify.width_{width}"] == 1
 
 
 def test_diff_and_checkpoint_round_trip_in_the_tables_own_shape(rng):
@@ -497,3 +687,44 @@ def test_the_wide_programs_fit_the_chip(k, entries, one_chip):
     assert 2 * table <= temps["train_512"] < 2 * table * 1.05, temps
     assert 2 * table <= temps["train"] < 2 * table + entries * 64 * 1.05, temps
     assert table <= temps["scores"] < table * 1.05, temps
+
+
+@pytest.mark.parametrize("rows", [
+    512, pytest.param(8192, marks=pytest.mark.slow)])
+def test_the_step_at_32_label_rows_fits_the_chip(rows, one_chip):
+    """The text deployment's programs at the benchmark's size (news20_arow:
+    D = 2^23, label capacity 32; PERF.md section 4): the train step at the
+    width of 1,024 (a lone 500-document call here; the window's flush of
+    8,000 in 8,192 x 1,024 takes half a minute to compile and is held by
+    the slow case) and the scores of the quality plane's 8 rows at a call's
+    width. Left to itself XLA copies every [32, D] table into a
+    column-major layout first, 128 lanes for 32 labels: 20 GB, which the
+    v5e's compiler refuses at every shape. Addressed a sublane group at a
+    time the tables stay where they lie: the packed plan's temporaries are
+    the packed copy and the gathered entries ([4 x D, 16] and [4 x rows x
+    1,024, 16]), the column plan makes nothing table-sized, and the
+    scatters run in place."""
+    cap, dim, k = 32, 1 << 23, 1024
+    table = cap * dim * 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = C.ClassifierState(*[sds((cap, dim), jnp.float32)] * 4)
+    mask = sds((cap,), jnp.bool_)
+    idx, val = sds((16, k), jnp.int32), sds((16, k), jnp.float32)
+    assert C.gather_plan(cap, dim, 16 * k) == "columns"
+    scores = C.scores.lower(state, idx, val, mask).compile()
+    assert scores.memory_analysis().temp_size_in_bytes < table // 64
+    idx, val = sds((rows, k), jnp.int32), sds((rows, k), jnp.float32)
+    assert C.gather_plan(cap, dim, rows * k) == "packed"
+    train = C.train_batch_parallel.lower(
+        state, idx, val, sds((rows,), jnp.int32), mask, 1.0,
+        method="AROW").compile()
+    m = train.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * table
+    # half the tables' bytes for the packed copy, and 16 sublanes of f32
+    # for each of the 4 x rows x 1,024 gathered entries beside it
+    assert 2 * table <= m.temp_size_in_bytes \
+        < 2 * table + 4 * rows * k * 64 * 1.1
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 9e9

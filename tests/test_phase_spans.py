@@ -175,6 +175,45 @@ def test_train_steps_are_recorded_on_the_device_workers_thread(server):
                for k in range(9)) == st["microbatch.train_raw.flush_count"]
 
 
+def test_tokens_terms_widths_and_a_label_growth_are_counted_where_they_happen(
+        server):
+    """The parser counts the tokens its string rules cut and the distinct
+    terms that became entries as it goes (12 whole string values a row
+    here); a classify flush counts its width as a train flush does; nine
+    labels more than the model's 8 rows make the tables grow once, on the
+    device worker's thread, under no request's trace id, as one
+    ``model.grow_labels`` span and one count, with the gauges beside."""
+    from jubatus_tpu.client import ClassifierClient
+
+    srv, port = server
+    reg = srv.rpc.trace
+    c0, st0 = reg.counters(), reg.trace_status()
+    ms, _ = _lone_call(server, "classify", 30)       # two calls of 30 rows
+    ms_t, _ = _lone_call(server, "train", 50)        # two calls of 50
+    c1 = reg.counters()
+    gain = {k: c1[k] - c0.get(k, 0) for k in c1}
+    assert gain["fv.tokens"] == gain["fv.terms"] == 12 * (2 * 30 + 2 * 50 + 2)
+    assert gain.get("fv.pack.pow2", 0) == 0          # rows alike: the rung
+    assert gain["step.classify.width_24"] == 2
+    assert gain["step.train.width_24"] >= 2
+    assert "model.grow_labels" not in ms and "model.grow_labels" not in ms_t
+    ctx = tracing.new_root()
+    with ClassifierClient("127.0.0.1", port, "") as c, tracing.use_trace(ctx):
+        assert c.train([(f"label{i}", d)
+                        for i, d in enumerate(_rows(9, 3))]) == 9
+    names = {r["name"] for r in reg.get_spans(ctx.trace_id)}
+    assert "rpc.train" in names and "model.grow_labels" not in names
+    st1 = reg.trace_status()
+    assert st1["trace.model.grow_labels.count"] \
+        - st0.get("trace.model.grow_labels.count", 0) == 1
+    assert reg.counters()["model.label_grow"] \
+        - c0.get("model.label_grow", 0) == 1
+    assert reg.gauges()["model.label_capacity"] == 16
+    assert reg.gauges()["model.labels_live"] == 11
+    status = next(iter(srv.get_status().values()))
+    assert status["driver.label_capacity"] == 16
+
+
 def test_the_quality_planes_scoring_shows_under_the_train_calls_trace():
     """Every admitted train call scores a few rows with the current model
     before it is queued: that wait for the device is a ``step.classify.*``

@@ -18,7 +18,7 @@ import pytest
 from jubatus_tpu.core.datum import Datum
 from jubatus_tpu.core.fv.converter import make_fv_converter
 from jubatus_tpu.core.sparse import (CSRBatch, SparseBatch, _bucket,
-                                     _width_bucket)
+                                     _request_width, _width_bucket)
 from jubatus_tpu.native import ingest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -111,6 +111,171 @@ def test_the_native_parser_pads_as_to_padded_and_from_vectors_do(widest):
     again = CSRBatch.from_vectors(
         [conv.convert(d) for d in rows]).to_padded()
     assert again.idx.tobytes() == padded.idx.tobytes()
+
+
+# -- one rule for the width uneven rows run at (ISSUE 34) ---------------------
+def _counts(kind, rng, n=500):
+    """Entries a row of one request of a deployment's kind."""
+    if kind == "click_log":          # every row alike: 39 of 40
+        return np.full(n, 39)
+    if kind == "cross":              # 780 less a few merged: of 832
+        return 780 - rng.integers(0, 4, size=n)
+    # documents: floor(exp(N(4.5, 0.8))) tokens, most of them distinct
+    return np.clip(np.floor(np.exp(rng.normal(4.5, 0.8, size=n)) * 0.73),
+                   1, 1000).astype(np.int64)
+
+
+@pytest.mark.parametrize("kind,width,keeps_rung", [
+    ("click_log", 40, True), ("cross", 832, True), ("text", None, False)])
+def test_even_rows_keep_their_rung_and_uneven_rows_a_power_of_two(
+        kind, width, keeps_rung):
+    widths = set()
+    for seed in range(40):
+        counts = _counts(kind, np.random.default_rng(seed))
+        w = _request_width(counts)
+        rung = _width_bucket(int(counts.max()))
+        filled = counts.sum() / (counts.size * rung)
+        assert (filled >= 0.5) == keeps_rung
+        assert w == (rung if keeps_rung else
+                     1 << int(counts.max() - 1).bit_length())
+        assert w >= counts.max()
+        widths.add(w)
+    if keeps_rung:
+        assert widths == {width}
+    else:   # a 500-document call: 512 or 1,024, where the rungs were many
+        assert widths == {512, 1024}
+        rungs = {_width_bucket(int(_counts(
+            kind, np.random.default_rng(seed)).max())) for seed in range(40)}
+        assert len(rungs) >= 6
+
+
+@pytest.mark.parametrize("counts,width", [
+    ([39] * 5, 40), ([780, 779, 780], 832), ([8, 1, 1, 1], 8),
+    ([9, 1, 1, 1], 16), ([100, 3, 2, 1, 1, 1], 128), ([600, 1, 1], 1024),
+    ([704, 352, 352], 704), ([704, 351, 351, 1], 1024), ([1], 8), ([], 8),
+    ([1025, 2, 2], 2048), ([2049, 1, 1, 1, 1], 4096)])
+def test_the_rule_point_by_point(counts, width):
+    """At least half full at the fullest row's rung keeps the rung; under
+    half is the power of two at or above the fullest row."""
+    assert _request_width(np.array(counts, np.int64)) == width
+    # the floor holds on both sides of the rule
+    assert _request_width(np.array(counts, np.int64), 2048) >= 2048
+
+
+def _uneven_rows(kind, rng):
+    """Datums whose feature counts are a request's of the kind, cut down
+    to a few rows (the fullest first)."""
+    counts = np.sort(_counts(kind, rng, 40))[::-1]
+    return [Datum({f"k{rng.integers(1 << 30)}_{j}": float(rng.uniform(0.5, 2))
+                   for j in range(int(n))}) for n in counts]
+
+
+@native_only
+@pytest.mark.parametrize("kind", ["click_log", "cross", "text"])
+def test_the_native_pack_and_the_python_twin_agree_on_the_rule(kind):
+    rng = np.random.default_rng(34)
+    rows = _uneven_rows(kind, rng)
+    p = ingest.IngestParser(ingest.spec_from_converter_config(NUM_CONV), 24)
+    conv = make_fv_converter(NUM_CONV, dim_bits=24)
+    raw = msgpack.packb(["c", [["x", d.to_msgpack()] for d in rows]])
+    _labels, idx, val, counts = p.parse_indexed(raw, counts=True)
+    csr = conv.convert_batch(rows)
+    per_row = np.diff(csr.row_offsets)
+    assert idx.shape[1] == _request_width(per_row)
+    assert counts.pow2 == (kind == "text")
+    assert counts.tokens == counts.terms == 0        # no string rule cut one
+    if kind == "text":
+        assert idx.shape[1] == 1 << int(per_row.max() - 1).bit_length()
+        assert idx.shape[1] != _width_bucket(int(per_row.max()))
+    else:
+        assert idx.shape[1] == _width_bucket(int(per_row.max()))
+    for other in (csr.to_padded(), SparseBatch.from_vectors(csr.rows())):
+        assert other.idx.shape == idx.shape
+        assert other.idx.tobytes() == np.ascontiguousarray(idx).tobytes()
+        assert other.val.tobytes() == np.ascontiguousarray(val).tobytes()
+    # row bucketing pads rows, not the rule's count of them
+    assert csr.to_padded(batch_bucket=64).idx.shape == (64, idx.shape[1])
+
+
+TEXT_CONF = {
+    "method": "AROW", "parameter": {"regularization_weight": 1.0},
+    "converter": {"string_rules": [{"key": "message", "type": "space",
+                                    "sample_weight": "bin",
+                                    "global_weight": "bin"}],
+                  "hash_max_size": 1 << 16}}
+
+
+def _documents(n, seed, labels=20):
+    rng = np.random.default_rng(seed)
+    lengths = np.clip(np.floor(np.exp(rng.normal(4.5, 0.8, size=n))), 1,
+                      2000).astype(int)
+    return [(f"g{i % labels:02d}", Datum({"message": " ".join(
+        f"w{int(w)}" for w in rng.zipf(1.3, size=m) % 60000)}))
+        for i, m in enumerate(lengths)]
+
+
+@native_only
+def test_mixed_requests_and_the_quality_planes_rows_run_at_powers_of_two():
+    """Calls of uneven documents from several connections at once: every
+    flush runs at the widest of its requests, a power of two, and so does
+    the quality plane's 8-row scoring of a sampled call; the parser's
+    counts, the label growth and the widths are all in the registry."""
+    import threading
+
+    from jubatus_tpu.client import ClassifierClient
+    from jubatus_tpu.server import EngineServer
+    from jubatus_tpu.server.args import ServerArgs
+
+    srv = EngineServer(
+        "classifier", TEXT_CONF,
+        args=ServerArgs(engine="classifier", listen_addr="127.0.0.1",
+                        quality_sample=1.0))
+    port = srv.start(0)
+    calls = [_documents(n, 100 + n) for n in (40, 300, 8, 120, 500, 60)]
+    try:
+        def send(docs):
+            with ClassifierClient("127.0.0.1", port, "") as c:
+                assert c.train(docs) == len(docs)
+
+        send(calls[0])                     # every label live, then at once
+        threads = [threading.Thread(target=send, args=(docs,))
+                   for docs in calls[1:]]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        with ClassifierClient("127.0.0.1", port, "") as c:
+            answers = c.classify([d for _l, d in calls[1][:30]])
+        counters = srv.rpc.trace.counters()
+        status = next(iter(srv.get_status().values()))
+        gauges = srv.rpc.trace.gauges()
+    finally:
+        srv.stop()
+    assert all(len(row) == 20 for row in answers)
+    train_w = {int(k.rsplit("_", 1)[1]): v for k, v in counters.items()
+               if k.startswith("step.train.width_")}
+    classify_w = {int(k.rsplit("_", 1)[1]): v for k, v in counters.items()
+                  if k.startswith("step.classify.width_")}
+    assert train_w and all(w & (w - 1) == 0 for w in train_w)
+    assert classify_w and all(w & (w - 1) == 0 for w in classify_w)
+    assert sum(train_w.values()) == status["microbatch.train_raw.flush_count"]
+    # five scorings of 8 rows at their calls' widths (the first call
+    # found no label to score), one classify call
+    assert sum(classify_w.values()) == 6
+    assert 5 <= counters["fv.pack.pow2"] <= 7
+    docs = [d for call in calls for _l, d in call] \
+        + [d for _l, d in calls[1][:30]]
+    words = [d.string_values[0][1].split() for d in docs]
+    assert counters["fv.tokens"] == sum(len(w) for w in words)
+    assert counters["fv.terms"] == sum(len(set(w)) for w in words)
+    assert counters["step.train.entries"] <= counters["fv.terms"]
+    # twenty labels in the first call: 8 -> 16 -> 32 rows
+    assert counters["model.label_grow"] == 2
+    assert status["trace.model.grow_labels.count"] == 2
+    assert (status["driver.num_labels"], status["driver.label_capacity"]) \
+        == (20, 32)
+    assert (gauges["model.labels_live"], gauges["model.label_capacity"]) \
+        == (20, 32)
 
 
 def _criteo_like(n, seed, n_num=13, n_str=26):
