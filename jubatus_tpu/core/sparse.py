@@ -12,8 +12,9 @@ Pad widths follow the rows: the widest row is rounded up to a rung of an
 eighth-octave ladder (``_width_bucket``: at most an eighth of padding, 8
 rungs per doubling), so XLA compiles O(log max_nnz) programs, not one per
 batch shape, and a 39-feature row runs at 40, not 64; rows of uneven
-length keep to powers of two (``_request_width``). Row counts are bucketed
-to powers of two (``_bucket``).
+length keep to powers of two (``_request_width``) and are trained as
+slabs (models/classifier.py _cut_slabs). Row counts are bucketed to
+powers of two (``_bucket``).
 """
 
 from __future__ import annotations
@@ -57,13 +58,16 @@ def _request_width(counts: np.ndarray, minimum: int = 8) -> int:
     Where lengths are heavy-tailed the fullest row's rung says nothing of
     the padding, which rung a request lands on is chance, and every rung
     is a compiled program; on powers of two a server's life sees one
-    program a doubling. A stop-gap: it buys a bounded set of programs and
-    leaves the padding (nine entries in ten of a 500-document call, at
-    which the train step, a classify and the quality plane's scoring of
-    its first rows all run), and goes when requests come off the parser
-    in a form without it. The same arithmetic as ``pack`` in
-    native/fast_ingest.cpp (tests/test_sparse_width.py holds the two
-    equal)."""
+    program a doubling. What it decides: the width a request travels at
+    from the parser to the driver (a flush is padded to its widest
+    request's), and the width a classify and the quality plane's scoring
+    of a call's first rows run at, padding included (nine entries in ten
+    of a 500-document call). It no longer decides the train program: a
+    train flush of such rows is handed to the chip as slabs
+    (models/classifier.py _cut_slabs). It goes when requests come off the
+    parser, and scores run, in a form without the padding. The same
+    arithmetic as ``pack`` in native/fast_ingest.cpp
+    (tests/test_sparse_width.py holds the two equal)."""
     most = int(counts.max()) if counts.size else 1
     rung = _width_bucket(most, minimum)
     if 2 * int(counts.sum()) >= counts.size * rung:

@@ -34,6 +34,21 @@ from jubatus_tpu.ops import classifier as ops
 _LINEAR_METHODS = set(ops.METHODS)
 _INITIAL_CAPACITY = 8
 
+# A train flush of uneven rows is handed to the chip as slabs: a row of n
+# entries becomes ceil(n / _SLAB_WIDTH) rows of _SLAB_WIDTH entries, their
+# count bucketed to a power of two, where that issues at most 1 /
+# _SLAB_GAIN of the entries the rows would (gather and scatter cost per
+# entry issued, padding included). Swept on v5e at D = 2^23 and 32 label
+# rows over flushes of 8,000 documents of 89.8 features at 8,192 x 1,024
+# (PERF.md section 6, PR 35; docs/PERF_NOTES.md has the table): rows
+# 1,625 ms a flush; slabs of 32, 64, 128, 256 in their power of two 191,
+# 178, 348, 710; on the width ladder's rungs less, but a flush's slab
+# count straddles a rung (two programs in a window). Rows and slabs cross
+# where the slabs issue 1 / 1.1 of the rows' entries; full rows cannot
+# reach 1 / 2 whatever the two buckets' chance.
+_SLAB_WIDTH = 64
+_SLAB_GAIN = 2
+
 
 class ClassifierConfigError(ValueError):
     pass
@@ -261,19 +276,29 @@ class ClassifierDriver(DriverBase):
         (ops.train_batch_schema): 8.9 ms against 36.7 for the sparse plan
         at 8,192 x 40 and the same at 512 x 40, D = 2^25 (PERF.md section
         6, PR 28). Sequential train mode keeps the sparse scan, where
-        exact per-datum semantics take priority."""
+        exact per-datum semantics take priority.
+
+        The shape a sparse flush is handed to one chip in follows its
+        rows: as slabs where ``_cut_slabs`` says so, else as it came."""
         bsz = _bucket(b, 16)
-        schema = uniform and self.train_mode == "parallel"
-        sharded = (not schema and self._mesh is not None
-                   and self.train_mode == "parallel")
+        parallel = self.train_mode == "parallel"
+        schema = uniform and parallel
+        sharded = not schema and self._mesh is not None and parallel
         trace = self.trace
-        owned = None
+        owned = slabs = downer = None
         with self._span("step.train.stage"):
-            if bsz != b:  # zero rows are no-ops (x2 = 0 → alpha 0)
-                idx = np.pad(idx, ((0, bsz - b), (0, 0)))
-                val = np.pad(val, ((0, bsz - b), (0, 0)))
-            slots_arr = np.zeros(bsz, dtype=np.int32)
-            slots_arr[:b] = slots
+            if parallel and not (schema or sharded):
+                slabs = _cut_slabs(idx, val, slots, bsz)
+            if slabs is not None:
+                sidx, sval, slots_arr, owner, n_slabs, entries = slabs
+                didx, dval = jnp.asarray(sidx), jnp.asarray(sval)
+                downer = jnp.asarray(owner)
+            else:
+                if bsz != b:  # zero rows are no-ops (x2 = 0 → alpha 0)
+                    idx = np.pad(idx, ((0, bsz - b), (0, 0)))
+                    val = np.pad(val, ((0, bsz - b), (0, 0)))
+                slots_arr = np.zeros(bsz, dtype=np.int32)
+                slots_arr[:b] = slots
             if sharded:
                 # the flush is routed to its owners before it is uploaded:
                 # a chip is handed its own entries alone, as local
@@ -294,12 +319,16 @@ class ClassifierDriver(DriverBase):
                 self._shard_width[k] = ridx.shape[1]
                 didx, dval = jax.device_put((ridx, rval),
                                             self._flush_sharding)
-            else:
+            elif slabs is None:
                 didx = jnp.asarray(idx[0] if schema else idx)
                 dval = jnp.asarray(val)
             dslots = jnp.asarray(slots_arr)
             mask = self._mask()
         plan = None
+        # the program the flush runs: one a form and shape
+        form = "schema" if schema else "mesh" if sharded \
+            else "scan" if not parallel \
+            else "rows" if slabs is None else "slabs"
         with self._span("step.train.dispatch"):
             if schema:
                 plan = "schema"
@@ -319,30 +348,46 @@ class ClassifierDriver(DriverBase):
             else:
                 # sequential mode keeps GSPMD partitioning of the placed
                 # state
-                if self.train_mode == "parallel":
-                    plan = ops.gather_plan(*self.state.w.shape, idx.size)
+                if parallel:
+                    plan = ops.gather_plan(*self.state.w.shape, didx.size)
                 self.state = ops.train_batch(
                     self.state, didx, dval, dslots, mask, self.param,
-                    method=self.method, mode=self.train_mode)
+                    method=self.method, mode=self.train_mode, owner=downer)
         self.event_model_updated(b)
         if trace is not None:
             if plan is not None:
                 # which plan the step's rows and shapes settled on
                 trace.count(f"step.train.plan_{plan}")
-            # the rows asked for and the rows the compiled bucket ran, and
-            # the width's side of them: entries that carry a feature,
-            # entries the rows have at the program's width, the bytes the
-            # stage put on the device (a routed flush's summed over the
-            # chips), and the width itself: each distinct one is a program
-            # this server's traffic made it compile (times the row buckets)
+            # the flush as it arrives from the coalescer: the rows asked
+            # for and the rows of their bucket, the width its requests
+            # were packed at (each distinct one a shape the host pads
+            # and concatenates to), the entries that carry a feature and
+            # the entries the rows have at that width
             trace.count("step.train.rows", b)
             trace.count("step.train.rows_padded", bsz)
             trace.count(f"step.train.width_{idx.shape[1]}")
-            trace.count("step.train.entries", int(
-                np.count_nonzero(idx) if owned is None else owned.sum()))
+            if slabs is None:
+                entries = int(np.count_nonzero(idx) if owned is None
+                              else owned.sum())
+            trace.count("step.train.entries", entries)
             trace.count("step.train.entries_padded", b * idx.shape[1])
-            trace.count("step.train.upload_bytes",
-                        didx.nbytes + dval.nbytes + dslots.nbytes)
+            # what the device is handed: the bytes the stage put on it (a
+            # routed flush's summed over the chips), the entries it
+            # issues descriptors for, padding included, and the program
+            # that runs them: each distinct key is one this server's
+            # traffic made it compile
+            trace.count("step.train.upload_bytes", sum(
+                a.nbytes for a in (didx, dval, dslots, downer)
+                if a is not None))
+            trace.count("step.train.entries_issued", dval.size)
+            shape = "x".join(map(str, dval.shape))
+            trace.count(f"step.train.program_{form}_{shape}")
+            if slabs is not None:
+                # a cut flush: the slabs that carry a row's entries and
+                # the bucket they ran in
+                trace.count("step.train.slab_flushes")
+                trace.count("step.train.slabs", n_slabs)
+                trace.count("step.train.slabs_padded", dval.shape[0])
             if owned is not None:
                 # the mesh's side: the chips issue shards x padded rows x
                 # routed width descriptors for the entries that carry a
@@ -689,6 +734,59 @@ def _assemble_sharded(driver, array_diff: dict, rank: int) -> dict:
                  for k, c in v.items()), key=lambda kv: kv[0])
             out[key] = np.concatenate([c for _, c in items], axis=-1)
     return out
+
+
+def _cut_slabs(idx: np.ndarray, val: np.ndarray, slots: np.ndarray,
+               bsz: int):
+    """A flush ``[b, K]`` cut into slabs of ``_SLAB_WIDTH`` entries, or
+    None where it runs as the rows it came as.
+
+    The one rule, from what the flush shows: slabs where they issue fewer
+    entries than the rows by a clear factor, ``S_b * W * _SLAB_GAIN <=
+    bsz * K`` (``S_b`` the slabs that carry an entry, bucketed to a power
+    of two, ``bsz`` the rows' own bucket). Rows no wider than ``W``, or
+    whose width is no multiple of it, stay rows without a count (K = 40:
+    a row is under one slab, nothing to win).
+
+    A row's entries are packed from column 0 and column 0 of the hashed
+    space is never a feature, so a slab carries entries iff its first
+    column is non-zero: ``b * K / W`` elements read to decide. Returns
+    ``(idx [S_b, W], val [S_b, W], labels [S_b], owner [S_b], slabs,
+    entries)``: a slab's label is its document's, ``owner`` numbers the
+    documents that have a slab in order (what ops.train_batch_parallel
+    sums over), padding slabs are ``(0, 0.0)`` entries of a document of
+    their own, the last, and ``entries`` the flush's that carry a feature.
+    """
+    b, k = idx.shape
+    w = _SLAB_WIDTH
+    if k <= w or k % w:
+        return None
+    shape = (b, k // w, w)
+    idx3 = idx.reshape(shape)
+    live = idx3[:, :, 0] != 0
+    s_b = _bucket(int(np.count_nonzero(live)), 16)
+    if s_b * w * _SLAB_GAIN > bsz * k:
+        return None
+    entries = int(np.count_nonzero(idx))
+    cut = idx3[live]
+    if np.count_nonzero(cut) != entries:
+        # an entry zeroed in place (the ingest's finite screen writes
+        # (0, 0.0) over a NaN) stood first in its slab: look at them all
+        live = idx3.any(axis=2)
+        cut = idx3[live]
+        s_b = _bucket(len(cut), 16)
+    n = len(cut)
+    doc = np.nonzero(live)[0]
+    sidx = np.zeros((s_b, w), np.int32)
+    sval = np.zeros((s_b, w), np.float32)
+    labels = np.zeros(s_b, np.int32)
+    owner = np.full(s_b, s_b - 1, np.int32)
+    sidx[:n] = cut
+    sval[:n] = val.reshape(shape)[live]
+    labels[:n] = slots[doc]
+    owner[0] = 0
+    owner[1:n] = np.cumsum(doc[1:] != doc[:-1])
+    return sidx, sval, labels, owner, n, entries
 
 
 def _next_pow2(n: int) -> int:

@@ -368,7 +368,9 @@ def train_rows(w, dw, prec, dprec, idx, val, labels, label_mask, param, *,
     masks instead: ``val`` zero where the shard does not own the entry),
     and ``reduce`` sums over the shards the three quantities that cross
     them: the [B, L] scores, x2 and v. A padding entry's updates are
-    alpha * sigma * 0 = 0, added at local column 0.
+    alpha * sigma * 0 = 0, added at local column 0. Where the rows are
+    slabs of a document's entries (train_batch_parallel's ``owner``)
+    ``reduce`` sums the same three over a document's slabs.
     """
     confidence = method in CONFIDENCE_METHODS
 
@@ -431,6 +433,21 @@ def train_rows(w, dw, prec, dprec, idx, val, labels, label_mask, param, *,
     return w, dw, prec, dprec
 
 
+def _over_documents(owner):
+    """train_rows' ``reduce`` for a flush cut into slabs: the sum over a
+    document's slabs, handed back to each of them. ``owner`` [S] numbers
+    the documents in the order their slabs come (ascending, below S).
+    With None every row is a document and nothing is summed."""
+    if owner is None:
+        return lambda x: x
+
+    def reduce(x):
+        return jax.ops.segment_sum(
+            x, owner, num_segments=owner.shape[0],
+            indices_are_sorted=True)[owner]
+    return reduce
+
+
 @functools.partial(jax.jit, static_argnames=("method",), donate_argnums=(0,))
 def train_batch_parallel(
     state: ClassifierState,
@@ -439,12 +456,24 @@ def train_batch_parallel(
     labels: jax.Array,     # [B] int32 — correct label row per example
     label_mask: jax.Array, # [L] bool — live labels
     param: float,
+    owner=None,            # [B] int32, or None: every row a document
     *,
     method: str,
 ) -> ClassifierState:
-    """train_rows on one chip — the TPU hot path."""
+    """train_rows on one chip — the TPU hot path.
+
+    ``owner``: the rows are slabs, pieces of a document's entries
+    (models/classifier.py _train_slots cuts a flush of uneven rows so),
+    ``owner[s]`` the document slab s belongs to and ``labels[s]`` that
+    document's label. Scores, x2 and v are then summed over a document's
+    slabs, the hook through which the mesh sums over shards, so every
+    slab of a document decides the document's alpha and rival, and each
+    entry's update is the one the row form makes. A slab with no entry
+    is a no-op as a padding row is. With None the program is the one
+    without the argument, instruction for instruction."""
     return ClassifierState(*train_rows(
-        *state, idx, val, labels, label_mask, param, method=method))
+        *state, idx, val, labels, label_mask, param, method=method,
+        reduce=_over_documents(owner)))
 
 
 @functools.partial(jax.jit, static_argnames=("method",), donate_argnums=(0,))
@@ -602,16 +631,20 @@ def train_batch(
     *,
     method: str,
     mode: str = "parallel",
+    owner=None,
 ) -> ClassifierState:
     """Train dispatcher: mode="parallel" (TPU hot path, intra-batch snapshot
-    semantics) or "sequential" (exact reference per-datum semantics)."""
+    semantics; ``owner``: the rows are slabs, train_batch_parallel) or
+    "sequential" (exact reference per-datum semantics, whole rows only)."""
     if mode == "parallel":
-        fn = train_batch_parallel
-    elif mode == "sequential":
-        fn = train_batch_sequential
-    else:
+        return train_batch_parallel(state, idx, val, labels, label_mask,
+                                    param, owner, method=method)
+    if mode != "sequential":
         raise ValueError(f"unknown train mode {mode!r}")
-    return fn(state, idx, val, labels, label_mask, param, method=method)
+    if owner is not None:
+        raise ValueError("the sequential scan takes whole rows, not slabs")
+    return train_batch_sequential(state, idx, val, labels, label_mask, param,
+                                  method=method)
 
 
 # -- mixable protocol -------------------------------------------------------

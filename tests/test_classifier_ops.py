@@ -538,10 +538,222 @@ def test_the_driver_runs_a_flush_at_the_width_it_came_at(kind, width, rng):
     assert c["step.train.rows_padded"] == 2 * 256
     assert c["step.train.entries"] == 2 * np.count_nonzero(idx)
     assert c["step.train.entries_padded"] == 2 * b * width
-    assert c["step.train.upload_bytes"] == 2 * (256 * width * 8 + 256 * 4)
+    if kind == "text" and width == 1024:
+        # uneven rows, nine entries in ten padding: handed over as slabs
+        # of 64 entries, each with its label and its document's number
+        assert c["step.train.slab_flushes"] == 2
+        slabs = c["step.train.slabs_padded"] // 2
+        assert slabs * 64 * 2 <= 256 * width
+        assert c["step.train.upload_bytes"] == 2 * slabs * (64 * 8 + 8)
+    else:
+        assert "step.train.slab_flushes" not in c
+        assert c["step.train.upload_bytes"] \
+            == 2 * (256 * width * 8 + 256 * 4)
     assert d.update_count == 2 * b and d.capacity == 32
     assert len(d.classify_hashed(idx[:8], val[:8])[0]) == 20
     assert reg.counters()[f"step.classify.width_{width}"] == 1
+
+
+def _row(idx, val, i, n, rng, dim):
+    """Row ``i`` with ``n`` entries, packed from column 0."""
+    idx[i], val[i] = 0, 0.0
+    idx[i, :n] = np.sort(rng.choice(np.arange(1, dim), size=n, replace=False))
+    val[i, :n] = rng.uniform(0.5, 2.0, size=n)
+
+
+def _model(rng, cap, dim, method):
+    """A warm model of ``cap`` label rows, its mask and how many are live."""
+    if cap == CAP:
+        return _warm_state(rng, CAP, dim, method, 5), \
+            jnp.asarray(np.arange(CAP) < 5), 5
+    return C.grow_labels(_warm_state(rng, CAP, dim, method, 8), cap), \
+        jnp.asarray(np.arange(cap) < 20), 20
+
+
+def _in_rows_and_in_slabs(state, idx, val, labels, mask, method):
+    """The flush trained as it came, in its row bucket, and as the slabs
+    the driver cuts it into: the four tables of each."""
+    from jubatus_tpu.core.sparse import _bucket
+    from jubatus_tpu.models import classifier as M
+
+    b = len(labels)
+    bsz = _bucket(b, 16)
+    slabs = M._cut_slabs(idx, val, labels, bsz)
+    assert slabs is not None
+    sidx, sval, slabels, owner, n, entries = slabs
+    assert entries == np.count_nonzero(idx) == np.count_nonzero(sidx)
+    assert np.array_equal(sval != 0, sidx != 0)
+    assert (np.diff(owner) >= 0).all() and owner.max() < len(owner)
+    pad = ((0, bsz - b), (0, 0))
+    rows = C.train_batch_parallel(
+        _fresh(state), jnp.asarray(np.pad(idx, pad)),
+        jnp.asarray(np.pad(val, pad)),
+        jnp.asarray(np.pad(labels, (0, bsz - b))), mask, 1.0, method=method)
+    cut = C.train_batch_parallel(
+        _fresh(state), jnp.asarray(sidx), jnp.asarray(sval),
+        jnp.asarray(slabels), mask, 1.0, jnp.asarray(owner), method=method)
+    return [np.asarray(a) for a in rows], [np.asarray(a) for a in cut], slabs
+
+
+@pytest.mark.parametrize("cap", [CAP, 32])
+@pytest.mark.parametrize("method", ["AROW", "PA"])
+def test_a_flush_in_slabs_is_the_flush_in_rows(method, cap, rng):
+    """Uneven rows at 1,024 wide, among them rows of 0, 1, 64, 65, 984 and
+    1,024 entries, cut into slabs of 64: a document's scores, x2 and v are
+    summed over its slabs (two float32 reductions where the rows' were
+    one) and every slab decides its document's alpha and rival, so each
+    entry's update is the one the row form makes: the tables agree within
+    1e-6, at 8 and at 32 label rows."""
+    dim, b, k = 1 << 14, 96, 1024
+    state, mask, live = _model(rng, cap, dim, method)
+    idx, val, labels = _uneven_flush(rng, b, k, dim, labels=live)
+    for i, n in ((6, 1), (7, 64), (8, 65), (9, 984)):
+        _row(idx, val, i, n, rng, dim)
+    rows, cut, (sidx, _v, slabels, owner, n, _e) = _in_rows_and_in_slabs(
+        state, idx, val, labels, mask, method)
+    counts = np.count_nonzero(idx, axis=1)
+    assert n == np.sum(-(-counts // 64)) and n < len(sidx) < 2 * n
+    # the row with no entry has no slab; the others' slabs carry their label
+    docs = np.flatnonzero(counts)
+    assert 3 not in docs
+    assert np.array_equal(slabels[:n], labels[docs][owner[:n]])
+    assert np.array_equal(np.bincount(owner[:n]), -(-counts[docs] // 64))
+    for a, w in zip(cut, rows):
+        np.testing.assert_allclose(a, w, rtol=1e-6, atol=1e-6)
+    assert np.abs(rows[1] - np.asarray(state.dw)).max() > 0.01
+
+
+@pytest.mark.parametrize("cap", [CAP, 32])
+@pytest.mark.parametrize("method", ["AROW", "PA"])
+def test_where_every_row_is_one_slab_the_tables_agree_to_the_bit(method, cap,
+                                                                 rng):
+    """Rows of at most 64 entries at 128 wide: a sum over one slab is the
+    slab's own float, so the slab program leaves, to the bit, what the row
+    program leaves of the same arrays with every slab a document; against
+    the rows at 128 wide only the order XLA sums a row's entries in
+    differs (a reduction over 128 lanes where it was 64)."""
+    dim, b = 1 << 14, 90
+    state, mask, live = _model(rng, cap, dim, method)
+    idx = np.zeros((b, 128), np.int32)
+    val = np.zeros((b, 128), np.float32)
+    for i in range(b):
+        _row(idx, val, i, int(rng.integers(0, 65)), rng, dim)
+    _row(idx, val, 0, 64, rng, dim)
+    _row(idx, val, 1, 0, rng, dim)
+    labels = rng.integers(0, live, size=b).astype(np.int32)
+    rows, cut, (sidx, sval, slabels, owner, n, _e) = _in_rows_and_in_slabs(
+        state, idx, val, labels, mask, method)
+    assert np.array_equal(owner[:n], np.arange(n))
+    alone = C.train_batch_parallel(
+        _fresh(state), jnp.asarray(sidx), jnp.asarray(sval),
+        jnp.asarray(slabels), mask, 1.0, method=method)
+    for a, w, r in zip(cut, alone, rows):
+        assert a.tobytes() == np.asarray(w).tobytes()
+        np.testing.assert_allclose(a, r, rtol=1e-6, atol=1e-6)
+    assert np.abs(rows[1] - np.asarray(state.dw)).max() > 0.01
+
+
+def test_padding_slabs_and_padding_documents_change_nothing(rng):
+    """A slab with no entry is a no-op as a padding row is, wherever the
+    bucket puts it, and so is a document with no entry among the others:
+    twice the padding slabs, and empty documents strewn through the flush,
+    leave the same tables to the bit. An entry zeroed in place (what the
+    ingest's finite screen leaves of a NaN) at the head of a slab does not
+    hide the slab's other entries."""
+    from jubatus_tpu.models import classifier as M
+
+    dim, b, k = 1 << 14, 60, 512
+    state, mask, live = _model(rng, 32, dim, "AROW")
+    idx, val, labels = _uneven_flush(rng, b, k, dim, labels=live)
+    _row(idx, val, 2, 200, rng, dim)
+    idx[2, 128], val[2, 128] = 0, 0.0       # the head of its third slab
+    rows, cut, (sidx, sval, slabels, owner, n, entries) = \
+        _in_rows_and_in_slabs(state, idx, val, labels, mask, "AROW")
+    assert entries == np.count_nonzero(idx)
+    for a, w in zip(cut, rows):
+        np.testing.assert_allclose(a, w, rtol=1e-6, atol=1e-6)
+    # twice the bucket: the padding slabs are a document of their own, last
+    s_b = len(sidx)
+    more = C.train_batch_parallel(
+        _fresh(state), jnp.asarray(np.pad(sidx, ((0, s_b), (0, 0)))),
+        jnp.asarray(np.pad(sval, ((0, s_b), (0, 0)))),
+        jnp.asarray(np.pad(slabels, (0, s_b))), mask, 1.0,
+        jnp.asarray(np.concatenate([
+            np.where(np.arange(s_b) < n, owner, 2 * s_b - 1),
+            np.full(s_b, 2 * s_b - 1, np.int32)])), method="AROW")
+    for a, w in zip(more, cut):
+        assert np.asarray(a).tobytes() == w.tobytes()
+    # empty documents among the others (a label each, as the wire gives)
+    at = np.sort(rng.choice(b, size=9, replace=False))
+    widx, wval = np.insert(idx, at, 0, axis=0), np.insert(val, at, 0, axis=0)
+    wlabels = np.insert(labels, at, 1)
+    wide = M._cut_slabs(widx, wval, wlabels, 128)
+    assert wide[4] == n and np.array_equal(wide[0], sidx) \
+        and np.array_equal(wide[2], slabels) and np.array_equal(wide[3], owner)
+
+
+@pytest.mark.parametrize("mode", ["parallel", "sequential", "sharded"])
+def test_the_driver_cuts_uneven_rows_on_one_chip_and_counts_both_forms(mode,
+                                                                       rng):
+    """A flush of uneven rows through ``_train_slots``. On one chip in
+    parallel mode it is cut: the old counters describe the flush as it
+    arrives (its rows, the width its requests were packed at, the entries
+    at that width) exactly as they did, and five new ones what the device
+    is handed. The sequential scan and the mesh take it in rows."""
+    from jax.sharding import Mesh
+
+    from jubatus_tpu.models import classifier as M
+    from jubatus_tpu.utils import tracing
+
+    dim, b, k = 1 << 14, 200, 1024
+    idx, val, _ = _uneven_flush(rng, b, k, dim)
+    names = [f"label{i % 20:02d}" for i in range(b)]
+    kw = {"parallel": {}, "sequential": {"train_mode": "sequential"},
+          "sharded": {"mesh": Mesh(np.asarray(jax.devices()[:4]),
+                                   axis_names=("shard",))}}[mode]
+    d = M.ClassifierDriver(AROW_CONF, dim_bits=14, **kw)
+    d.trace = reg = tracing.Registry()
+    assert d.train_hashed(names, idx, val) == b
+    c = reg.counters()
+    assert (c["step.train.rows"], c["step.train.rows_padded"]) == (b, 256)
+    assert c["step.train.width_1024"] == 1
+    assert c["step.train.entries"] == np.count_nonzero(idx)
+    assert c["step.train.entries_padded"] == b * k
+    programs = {p: v for p, v in c.items()
+                if p.startswith("step.train.program_")}
+    if mode != "parallel":
+        assert not any(p.startswith("step.train.slab") for p in c)
+        if mode == "sequential":
+            assert c["step.train.entries_issued"] == 256 * k
+            assert programs == {"step.train.program_scan_256x1024": 1}
+        else:
+            assert c["step.train.entries_issued"] \
+                == c["step.train.shard_entries_issued"]
+            assert len(programs) == 1 \
+                and next(iter(programs)).startswith("step.train.program_mesh_")
+        return
+    slabs = int(np.sum(-(-np.count_nonzero(idx, axis=1) // 64)))
+    bucket = 1 << (slabs - 1).bit_length()
+    assert c["step.train.slab_flushes"] == 1
+    assert c["step.train.slabs"] == slabs
+    assert c["step.train.slabs_padded"] == bucket
+    assert c["step.train.entries_issued"] == bucket * 64
+    assert bucket * 64 * M._SLAB_GAIN <= 256 * k
+    assert programs == {f"step.train.program_slabs_{bucket}x64": 1}
+    assert c["step.train.upload_bytes"] == bucket * (64 * 8 + 8)
+    # and the model is the one the rows give
+    want = M.ClassifierDriver(AROW_CONF, dim_bits=14,
+                              train_mode="parallel")
+    gain, M._SLAB_GAIN = M._SLAB_GAIN, float("inf")
+    try:
+        want.train_hashed(names, idx, val)
+    finally:
+        M._SLAB_GAIN = gain
+    for a, w in zip(d.state, want.state):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                   rtol=1e-6, atol=1e-6)
+    assert d.classify_hashed(idx[:4], val[:4])[0][0][0] \
+        == want.classify_hashed(idx[:4], val[:4])[0][0][0]
 
 
 def test_diff_and_checkpoint_round_trip_in_the_tables_own_shape(rng):
@@ -595,8 +807,17 @@ def test_a_flush_is_counted_under_the_plan_its_shapes_settled_on(
                      f"step.train.width_{width}": 2}
     assert counters["step.train.entries"] == 2 * 20 * (width - width // 4)
     assert counters["step.train.entries_padded"] == 2 * 20 * width
-    # 20 rows run in the 32-row program: index, value and label arrays
-    assert counters["step.train.upload_bytes"] == 2 * 32 * (width * 8 + 4)
+    # 20 rows run in the 32-row program: index, value and label arrays;
+    # at 1,024 wide their 240 slabs of 64 entries in the 256-slab program
+    # (half the entries the rows issue in their bucket of 32), each slab
+    # with its label and its document's number
+    if width == 1024:
+        assert counters["step.train.program_slabs_256x64"] == 2
+        assert counters["step.train.upload_bytes"] == 2 * 256 * (64 * 8 + 8)
+    else:
+        assert counters[f"step.train.program_rows_32x{width}"] == 2
+        assert counters["step.train.upload_bytes"] \
+            == 2 * 32 * (width * 8 + 4)
 
 
 # -- the compiled programs, for the chip that is described and not attached --
@@ -728,3 +949,31 @@ def test_the_step_at_32_label_rows_fits_the_chip(rows, one_chip):
     assert 2 * table <= m.temp_size_in_bytes \
         < 2 * table + 4 * rows * k * 64 * 1.1
     assert m.temp_size_in_bytes + m.argument_size_in_bytes < 9e9
+
+
+@pytest.mark.parametrize("slabs", [1024, 16384])
+def test_the_slab_step_at_32_label_rows_fits_the_chip(slabs, one_chip):
+    """The text deployment's train programs since PR 35 (news20_arow: D =
+    2^23, label capacity 32): a lone 500-document call's 1,024 slabs of 64
+    entries and the window's flush of 8,000 documents in 16,384. The
+    v5e's compiler takes the packed plan, scatters in place, and the
+    temporaries are the packed copy and the gathered entries, an eighth
+    of what the rows at 8,192 x 1,024 gather; the sums over a document's
+    slabs make nothing table-sized."""
+    cap, dim, w = 32, 1 << 23, 64
+    table = cap * dim * 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = C.ClassifierState(*[sds((cap, dim), jnp.float32)] * 4)
+    assert C.gather_plan(cap, dim, slabs * w) == "packed"
+    train = C.train_batch_parallel.lower(
+        state, sds((slabs, w), jnp.int32), sds((slabs, w), jnp.float32),
+        sds((slabs,), jnp.int32), sds((cap,), jnp.bool_), 1.0,
+        sds((slabs,), jnp.int32), method="AROW").compile()
+    m = train.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * table
+    assert 2 * table <= m.temp_size_in_bytes \
+        < 2 * table + 4 * slabs * w * 64 * 1.2
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 7e9

@@ -257,6 +257,19 @@ def test_mixed_requests_and_the_quality_planes_rows_run_at_powers_of_two():
     classify_w = {int(k.rsplit("_", 1)[1]): v for k, v in counters.items()
                   if k.startswith("step.classify.width_")}
     assert train_w and all(w & (w - 1) == 0 for w in train_w)
+    # and each is handed to the device in the form its rows show: every
+    # flush counts one program, and the wide ones (a 300- or 500-document
+    # call among them) go as slabs, fewer entries than they arrived with
+    programs = {k: v for k, v in counters.items()
+                if k.startswith("step.train.program_")}
+    assert sum(programs.values()) == sum(train_w.values())
+    assert all(k.startswith(("step.train.program_slabs_",
+                             "step.train.program_rows_")) for k in programs)
+    assert 1 <= counters["step.train.slab_flushes"] <= sum(train_w.values())
+    assert counters["step.train.slabs"] <= counters["step.train.slabs_padded"]
+    assert counters["step.train.entries"] \
+        <= counters["step.train.entries_issued"] \
+        < counters["step.train.entries_padded"]
     assert classify_w and all(w & (w - 1) == 0 for w in classify_w)
     assert sum(train_w.values()) == status["microbatch.train_raw.flush_count"]
     # five scorings of 8 rows at their calls' widths (the first call
@@ -276,6 +289,104 @@ def test_mixed_requests_and_the_quality_planes_rows_run_at_powers_of_two():
         == (20, 32)
     assert (gauges["model.labels_live"], gauges["model.label_capacity"]) \
         == (20, 32)
+
+
+def _rows_of(counts, k, rng=None):
+    """A flush ``[len(counts), k]``: row i has ``counts[i]`` entries packed
+    from column 0 (any column but 0; one value)."""
+    counts = np.asarray(counts)
+    live = np.arange(k)[None, :] < counts[:, None]
+    idx = np.where(live, 7 if rng is None else rng.integers(
+        1, 1 << 20, size=live.shape), 0).astype(np.int32)
+    return idx, live.astype(np.float32), np.zeros(len(counts), np.int32)
+
+
+def _text_counts(rng, n):
+    """Distinct words of ``n`` documents as news20_arow's generator has
+    them (89.8 in the mean, the fullest near 984)."""
+    return np.clip(np.floor(np.exp(rng.normal(4.5, 0.8, size=n)) * 0.73),
+                   1, 984).astype(int)
+
+
+@pytest.mark.parametrize("what,rows,k,counts,cut", [
+    # criteo_arow: 39 of 40: under one slab's width, nothing read
+    ("T", 8000, 40, 39, False),
+    # criteo_arow_cross: 780 of 832 is 13 slabs of 64 a row; 104,000 slabs
+    # in the bucket of 131,072 issue more than the rows do, and the buckets'
+    # chance (4,500 rows in 8,192 against 58,500 slabs in 65,536) brings
+    # the rows no further than 1.625 times the slabs
+    ("X", 8000, 832, 780, False),
+    ("X short", 7000, 832, 780, False),
+    ("X, 9 calls", 4500, 832, 780, False),
+    ("X, one call", 500, 832, 780, False),
+    # news20_arow: 8,000 documents at 1,024 are 15,000 slabs in 16,384: an
+    # eighth of the entries; a lone call of 500 likewise
+    ("N", 8000, 1024, "text", True),
+    ("N, one call", 500, 1024, "text", True),
+    ("N, 9 calls", 4500, 1024, "text", True),
+    # full rows can never be cut, whatever the two buckets' chance
+    ("full", 4097, 512, 512, False),
+    ("full", 17, 1024, 1024, False),
+    # at the slab's own width a row is a slab; a multiple of it is counted
+    ("one slab wide", 100, 64, 5, False),
+    ("two slabs wide", 100, 128, 5, True),
+    ("no multiple of a slab", 100, 160, 5, False),
+    ("half full", 128, 128, 65, False),
+])
+def test_the_rule_that_cuts_a_flush_point_by_point(what, rows, k, counts, cut,
+                                                   rng):
+    """``S_b * W * c <= bsz * K``, from what the flush shows and nothing
+    else: the benchmark's three train cells on their side of the line at
+    every fill they meet, and what a cut flush is made of."""
+    from jubatus_tpu.models import classifier as M
+
+    assert (M._SLAB_WIDTH, M._SLAB_GAIN) == (64, 2)
+    if isinstance(counts, str):
+        counts = _text_counts(rng, rows)
+        counts[0] = 984
+    else:
+        counts = np.full(rows, counts)
+    idx, val, labels = _rows_of(counts, k)
+    labels[:] = np.arange(rows) % 20
+    bsz = _bucket(rows, 16)
+    got = M._cut_slabs(idx, val, labels, bsz)
+    slabs = int(np.sum(-(-counts // 64)))
+    bucket = _bucket(slabs, 16)
+    assert (got is not None) == cut, what
+    assert cut == (k > 64 and k % 64 == 0
+                   and bucket * 64 * 2 <= bsz * k), what
+    if not cut:
+        return
+    sidx, sval, slabels, owner, n, entries = got
+    assert n == slabs and entries == counts.sum()
+    assert sidx.shape == sval.shape == (bucket, 64)
+    assert slabels.shape == owner.shape == (bucket,)
+    assert np.count_nonzero(sidx[:n]) == np.count_nonzero(sval) == entries
+    assert not sidx[n:].any()
+    # a document's slabs in order, its label on each, documents numbered
+    # as they come; the padding slabs a document of their own, the last
+    per_doc = -(-counts // 64)
+    assert np.array_equal(owner[:n], np.repeat(np.arange(rows), per_doc))
+    assert np.array_equal(slabels[:n], np.repeat(labels, per_doc))
+    assert (owner[n:] == bucket - 1).all() and not slabels[n:].any()
+    if "N" in what:
+        assert bucket * 64 * 8 == bsz * k or bucket * 64 * 16 == bsz * k
+
+
+def test_every_flush_of_one_to_sixteen_calls_runs_a_warmed_program(rng):
+    """The program of a cut flush is its slab bucket's alone (a slab
+    carries its label; the row bucket is no part of the shape), so the
+    five lone calls of the benchmark's warm-up (500 to 8,000 documents)
+    compile every program a flush of 1 to 16 calls of 500 can meet."""
+    from jubatus_tpu.models import classifier as M
+
+    def bucket_of(docs):
+        idx, val, labels = _rows_of(_text_counts(rng, docs), 1024)
+        return len(M._cut_slabs(idx, val, labels, _bucket(docs, 16))[0])
+
+    warmed = {bucket_of(n) for n in (500, 1000, 2000, 4000, 8000)}
+    assert warmed == {1024, 2048, 4096, 8192, 16384}
+    assert {bucket_of(500 * calls) for calls in range(1, 17)} <= warmed
 
 
 def _criteo_like(n, seed, n_num=13, n_str=26):
