@@ -413,6 +413,18 @@ class ClassifierDriver(DriverBase):
             # highest live slot, beside those the tables reserve
             trace.count("step.train.label_rows_issued", mask.shape[0])
             trace.count("step.train.label_rows_reserved", self.capacity)
+            if parallel and not schema:
+                # the label groups the gather addresses a chip, and the
+                # precision cells it reads: at every row addressed, or at
+                # the correct label's and the rival's (ops.prec_rows)
+                num_labels, dim = self.state.w.shape
+                if sharded:
+                    dim //= n_shards
+                trace.count("step.train.label_groups_issued",
+                            ops.label_groups(num_labels, dim, mask.shape[0]))
+                prec = ops.prec_rows(self.method, num_labels, dim,
+                                     mask.shape[0])
+                trace.count("step.train.prec_gathered", prec * dval.size)
             shape = "x".join(map(str, dval.shape))
             trace.count(f"step.train.program_{form}_{shape}")
             if slabs is not None:

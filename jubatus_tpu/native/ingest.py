@@ -40,6 +40,7 @@ class Counts(NamedTuple):
     terms: int    # distinct terms of them that became entries
     pow2: bool    # uneven rows: packed at a power of two, not a rung
                   # (core/sparse.py _request_width)
+    codepoints: int  # code points the ngram rules slid over
 
 
 _lock = threading.Lock()
@@ -67,6 +68,7 @@ class _Out(ctypes.Structure):
         ("str_tokens", ctypes.c_int64),
         ("str_terms", ctypes.c_int64),
         ("pow2", ctypes.c_int32),
+        ("str_codepoints", ctypes.c_int64),
     ]
 
 
@@ -514,7 +516,8 @@ class IngestParser:
     def _extras(self, out: "_Out", cross: bool, counts: bool) -> tuple:
         """What a parse hands back behind its arrays."""
         return ((self._cross(out),) if cross else ()) + ((Counts(
-            int(out.str_tokens), int(out.str_terms), bool(out.pow2)),)
+            int(out.str_tokens), int(out.str_terms), bool(out.pow2),
+            int(out.str_codepoints)),)
             if counts else ())
 
     def parse_indexed(self, raw: bytes, weights=None, cross: bool = False,

@@ -190,6 +190,47 @@ def gather_plan(num_labels: int, dim: int, n_idx: int) -> str:
         > _PACK_UNDER * n_idx else "packed"
 
 
+def label_groups(num_labels: int, dim: int, rows: int) -> int:
+    """The label groups a step's gather addresses for the leading
+    ``rows`` of [num_labels, dim] tables: one descriptor an entry each
+    (_gather_sums)."""
+    return rows // _label_group(num_labels, dim)
+
+
+# Label groups past which the train step reads the precision at the two
+# rows an entry updates instead of gathering it beside the weights at
+# every row addressed. Measured on v5e, steps back to back
+# (tools/bench_prec_forms.py; PERF.md section 6, PR 38): beside the
+# weights 34.7 ms against 44.3 at two rows at 1 group (8,192 x 40, D =
+# 2^25), 132.5 against 151.3 at 3 (16,384 slabs of 64, D = 2^23); beside
+# the weights the precision rides in their descriptors, at two rows it
+# costs four gathers an entry. At 30 groups (32,768 slabs, D = 2^20) only
+# the two-row form fits the chip. The crossover lies between 3 and 30
+# groups, about 7 by the cost of a descriptor measured at both ends; no
+# cell runs 4 to 29.
+_PREC_FULL_UPTO = 4
+
+
+def prec_rows(method: str, num_labels: int, dim: int, rows: int) -> int:
+    """The precision rows train_rows reads for each entry, where
+    [num_labels, dim] tables are addressed over their leading ``rows``:
+    none for a method without a precision; every row addressed, gathered
+    beside the weights in the same descriptors, up to _PREC_FULL_UPTO
+    label groups; past them 2, the correct label's and the rival's, read
+    after the scores have named the rival. Beside the weights each group
+    carries its precision for the whole step (the packed copy and the
+    gathered entries twice the weights' alone, and a [rows, entries] sum
+    of it) for the 2 rows the update reads: at 30 groups and 32,768 slabs
+    of 64 the v5e's compiler asks 19.02 GiB of its 15.75 for that form
+    (tests/test_prec_gather.py). Static shapes only, so it is settled when
+    the program is traced."""
+    if method not in CONFIDENCE_METHODS:
+        return 0
+    if label_groups(num_labels, dim, rows) > _PREC_FULL_UPTO:
+        return 2
+    return rows
+
+
 def _by_group(table):
     """An [L, D] table of G groups of 8 label rows as [8, G * D]: group g's
     column c is column g * D + c. The tiles of a group's 8 rows lie one
@@ -234,6 +275,18 @@ def _gather_sums(pairs, idx, rows=None):
             for a in got]                  # [8, G, B, K] -> [G * 8, B, K]
 
 
+def _pack(pairs, cols):
+    """_gather_group's packed copy: the masters stacked, the diffs stacked,
+    the stacks added in one pass, cut to the first ``cols`` columns and
+    read as [cols, n * g], each column's rows one descriptor's fetch."""
+    with jax.named_scope("pack"):
+        packed = (jnp.concatenate([m for m, _ in pairs], axis=0)
+                  + jnp.concatenate([d for _, d in pairs], axis=0))
+        if cols < packed.shape[1]:
+            packed = packed[:, :cols]
+        return packed.T
+
+
 def _gather_group(pairs, idx, cols=None):
     """_gather_sums for tables of one label group: [g, D] tables, idx of
     any shape, the pairs' sums as [g, *idx.shape]. ``cols``: idx lies
@@ -259,16 +312,64 @@ def _gather_group(pairs, idx, cols=None):
         with jax.named_scope("gather"):
             return [(jnp.take(m, flat, axis=1) + jnp.take(d, flat, axis=1))
                     .reshape((num_labels,) + idx.shape) for m, d in pairs]
-    with jax.named_scope("pack"):
-        packed = (jnp.concatenate([m for m, _ in pairs], axis=0)
-                  + jnp.concatenate([d for _, d in pairs], axis=0))
-        if cols < dim:
-            packed = packed[:, :cols]
-        packed = packed.T
+    packed = _pack(pairs, cols)
     with jax.named_scope("gather"):
         g = jnp.take(packed, flat, axis=0).reshape(idx.shape + (-1,))
         return [jnp.moveaxis(g[..., i * num_labels:(i + 1) * num_labels], -1, 0)
                 for i in range(len(pairs))]
+
+
+def _gather_scores(pair, idx, val, rows):
+    """(master + diff)[:rows, idx] . val as [B, rows] for a (master, diff)
+    pair of [L, D] tables, on _gather_sums' plans: the scores train_rows
+    takes where it reads the precision at two rows. One label group at a
+    time, in a loop, each group's entries gathered and summed into its
+    [B, 8] scores before the next: what the step holds beside the packed
+    copy is one group's entries, not every group's (the gathered [G x E,
+    8] and their [rows, B, K] copy, 8e9 B at 240 rows and 2^22 entries).
+    The descriptors are _gather_sums', one a group and entry. The sum
+    over a row's entries runs in another order than the einsum over
+    _gather_sums': the same scores to float32 rounding, not to the bit."""
+    m, d = pair
+    num_labels, dim = m.shape
+    g = _label_group(num_labels, dim)
+    groups = rows // g
+    vm, vd = _by_group(m), _by_group(d)
+    cols = groups * dim
+    b = idx.shape[0]
+    if gather_plan(g, cols, groups * idx.size) == "columns":
+        def one(k):
+            at = (idx + k * dim).reshape(-1)
+            with jax.named_scope("gather"):
+                got = jnp.take(vm, at, axis=1) + jnp.take(vd, at, axis=1)
+            with jax.named_scope("margin"):                    # [g, B]
+                return jnp.sum(got.reshape((g,) + idx.shape) * val, axis=-1)
+        s = jax.lax.map(one, jnp.arange(groups, dtype=idx.dtype))
+        return s.transpose(2, 0, 1).reshape(b, rows)           # [G, g, B]
+    packed = _pack([(vm, vd)], cols)
+
+    def one(k):
+        with jax.named_scope("gather"):
+            got = jnp.take(packed, (idx + k * dim).reshape(-1), axis=0)
+        with jax.named_scope("margin"):                        # [B, g]
+            return jnp.sum(got.reshape(idx.shape + (g,))
+                           * val[:, :, None], axis=1)
+    s = jax.lax.map(one, jnp.arange(groups, dtype=idx.dtype))  # [G, B, g]
+    return s.transpose(1, 0, 2).reshape(b, rows)
+
+
+def _tiled(table, rows, idx):
+    """An f32 [L, D] table as the TPU lays it out, tiles of 8 rows x 128
+    columns one behind another, as a flat view (two bitcasts there), and
+    the positions in it of ``table[rows[n], idx[n, k]]``; rows [N], idx
+    [N, K]."""
+    num_labels, dim = table.shape
+    g = 8 if num_labels % 8 == 0 else num_labels
+    c = 128 if dim % 128 == 0 else dim
+    r = rows[:, None]
+    pos = (((r // g) * (dim // c) + idx // c) * g + r % g) * c + idx % c
+    flat = table.reshape(num_labels // g, g, dim // c, c)
+    return flat.transpose(0, 2, 1, 3).reshape(-1), pos
 
 
 def _scatter_add_tiled(table, rows, idx, up):
@@ -282,12 +383,26 @@ def _scatter_add_tiled(table, rows, idx, up):
     num_labels, dim = table.shape
     g = 8 if num_labels % 8 == 0 else num_labels
     c = 128 if dim % 128 == 0 else dim
-    r = rows[:, None]
-    pos = (((r // g) * (dim // c) + idx // c) * g + r % g) * c + idx % c
-    flat = table.reshape(num_labels // g, g, dim // c, c)
-    flat = flat.transpose(0, 2, 1, 3).reshape(-1).at[pos].add(up)
+    flat, pos = _tiled(table, rows, idx)
+    flat = flat.at[pos].add(up)
     return flat.reshape(num_labels // g, dim // c, g, c).transpose(
         0, 2, 1, 3).reshape(num_labels, dim)
+
+
+def _take_tiled(pair, rows, idx):
+    """_take_sum on the TPU: each table read through _tiled's view, where
+    it lies, one element a descriptor."""
+    (m, pos), (d, _) = (_tiled(t, rows, idx) for t in pair)
+    return jnp.take(m, pos) + jnp.take(d, pos)
+
+
+def _take_sum(pair, rows, idx):
+    """(master + diff)[rows[n], idx[n, k]] as [N, K] for a (master, diff)
+    pair of [L, D] tables: the same two floats _gather_sums adds, so the
+    same bits, at one row of each entry instead of every row."""
+    return jax.lax.platform_dependent(
+        pair, rows, idx, tpu=_take_tiled,
+        default=lambda p, r, i: p[0][r[:, None], i] + p[1][r[:, None], i])
 
 
 def _scatter_add(table, rows, idx, up):
@@ -443,25 +558,33 @@ def train_rows(w, dw, prec, dprec, idx, val, labels, label_mask, param, *,
     over those rows alone, and scatters into the whole tables, where
     every label and rival it writes lies below L'. Rows past L' are dead
     and their scores never win, so the tables come out the same bits.
+
+    The precision is read as prec_rows says: beside the weights at every
+    row addressed, or, past its groups, at the correct label's and the
+    rival's rows alone once the scores have named the rival (_take_sum,
+    the same bits). On a mesh each shard reads its own entries'.
     """
     confidence = method in CONFIDENCE_METHODS
     rows = label_mask.shape[0]
+    at_two = prec_rows(method, *w.shape, rows) == 2
 
     # The scopes (pack, gather, margin, scatter) are the phases' own
     # words beside XLA's op names in a device capture: metadata only.
-    if confidence:                                                # [L', B, K]
+    if at_two:
+        s = _gather_scores((w, dw), idx, val, rows)               # [B, L']
+    elif confidence:                                              # [L', B, K]
         eff_g, p_g = _gather_sums([(w, dw), (prec, dprec)], idx, rows)
     else:
         (eff_g,) = _gather_sums([(w, dw)], idx, rows)
     with jax.named_scope("margin"):
-        s = reduce(jnp.einsum("lbk,bk->bl", eff_g, val))
+        s = reduce(s if at_two else jnp.einsum("lbk,bk->bl", eff_g, val))
         x2_vec = val * val                                         # [B, K]
         x2 = reduce(jnp.sum(x2_vec, axis=1))                       # [B]
 
-        if confidence:
+        if confidence and not at_two:
             p_c = jnp.take_along_axis(p_g, labels[None, :, None], axis=0)[0]  # [B,K]
             sig_c = 1.0 / p_c
-        else:
+        elif not confidence:
             sig_c = jnp.ones_like(val)
 
         # v needs sigma of the *wrong* row, which needs the scores first; compute
@@ -473,7 +596,16 @@ def train_rows(w, dw, prec, dprec, idx, val, labels, label_mask, param, *,
                 s, labels, label_mask, x2, jnp.zeros_like(x2), x2_vec, param,
                 method=method,
             )
-            p_w = jnp.take_along_axis(p_g, wrong0[None, :, None], axis=0)[0]
+            if at_two:
+                with jax.named_scope("gather"):               # [2, B, K]
+                    p_c, p_w = _take_sum(
+                        (prec, dprec), jnp.concatenate([labels, wrong0]),
+                        jnp.concatenate([idx, idx])).reshape(
+                            (2,) + idx.shape)
+                sig_c = 1.0 / p_c
+            else:
+                p_w = jnp.take_along_axis(p_g, wrong0[None, :, None],
+                                          axis=0)[0]
             # no rival label → `wrong0` points at a dead/arbitrary row; the
             # nonexistent rival carries the unit precision prior, not that
             # row's (possibly trained) precision

@@ -347,9 +347,13 @@ def _register_train_raw(rpc: RpcServer, server: Any, numeric: bool) -> None:
 
     def _counted(counts) -> None:
         """What the parser counted of one request as it went: the tokens
-        its string rules cut, and whether its rows were uneven enough to
-        be packed at a power of two (core/sparse.py _request_width)."""
+        its string rules cut (an ngram rule's windows), the code points
+        its ngram rules slid over, and whether its rows were uneven
+        enough to be packed at a power of two (core/sparse.py
+        _request_width)."""
         trace.count("fv.tokens", counts.tokens)
+        if counts.codepoints:
+            trace.count("fv.codepoints", counts.codepoints)
         if counts.pow2:
             trace.count("fv.pack.pow2")
 

@@ -634,6 +634,7 @@ struct JtIngestOut {
   int64_t str_tokens;   // tokens the string rules cut (a whole value is one)
   int64_t str_terms;    // distinct terms of them that became entries
   int32_t pow2;         // 1: uneven rows, so width is a power of two
+  int64_t str_codepoints;  // code points the ngram rules slid over
 };
 
 void* jt_ingest_create(const char* spec) {
@@ -879,7 +880,7 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   std::vector<Base> base;  // one example's base features, in name order
   std::vector<uint8_t> lmatch, rmatch;
   int64_t cross_slots = 0, cross_ns = 0;
-  int64_t str_tokens = 0, str_terms = 0;
+  int64_t str_tokens = 0, str_terms = 0, str_codepoints = 0;
 
   auto add_named = [&](const std::string& nm, double v) {
     auto it = named_ix.find(nm);
@@ -1059,6 +1060,7 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
           }
           cps.push_back(txtn);
           size_t n_cp = cps.size() - 1;
+          str_codepoints += int64_t(n_cp);
           for (size_t a = 0; a + size_t(r.ngram_n) <= n_cp; ++a)
             terms.push_back(
                 {txt + cps[a], cps[a + size_t(r.ngram_n)] - cps[a]});
@@ -1354,6 +1356,7 @@ static int parse_impl(void* h, const uint8_t* buf, int64_t len,
   out->cross_ns = cross_ns;
   out->str_tokens = str_tokens;
   out->str_terms = str_terms;
+  out->str_codepoints = str_codepoints;
   int32_t base_pow2;
   bool packed = pack(feats, offsets, true, &out->width, &out->pow2, &out->idx,
                      &out->val);
