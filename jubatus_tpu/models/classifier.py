@@ -414,14 +414,21 @@ class ClassifierDriver(DriverBase):
             trace.count("step.train.label_rows_issued", mask.shape[0])
             trace.count("step.train.label_rows_reserved", self.capacity)
             if parallel and not schema:
-                # the label groups the gather addresses a chip, and the
+                # the label groups the gather addresses a chip, the
+                # fetches of weights the scores issue (a group at a time,
+                # or an entry's row of lanes: ops.weight_fetches), and the
                 # precision cells it reads: at every row addressed, or at
                 # the correct label's and the rival's (ops.prec_rows)
                 num_labels, dim = self.state.w.shape
+                n_idx = dval.size
                 if sharded:
                     dim //= n_shards
+                    n_idx = ridx[0].size
                 trace.count("step.train.label_groups_issued",
                             ops.label_groups(num_labels, dim, mask.shape[0]))
+                trace.count("step.train.weight_fetches", dval.size
+                            * ops.weight_fetches(self.method, num_labels, dim,
+                                                 mask.shape[0], n_idx))
                 prec = ops.prec_rows(self.method, num_labels, dim,
                                      mask.shape[0])
                 trace.count("step.train.prec_gathered", prec * dval.size)

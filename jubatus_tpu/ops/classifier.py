@@ -45,10 +45,12 @@ x2 = ||x||^2, v = x'(Sigma_c + Sigma_w)x, parameter r/C/phi =
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 METHODS = ("perceptron", "PA", "PA1", "PA2", "CW", "AROW", "NHERD")
 CONFIDENCE_METHODS = ("CW", "AROW", "NHERD")
@@ -199,15 +201,14 @@ def label_groups(num_labels: int, dim: int, rows: int) -> int:
 
 # Label groups past which the train step reads the precision at the two
 # rows an entry updates instead of gathering it beside the weights at
-# every row addressed. Measured on v5e, steps back to back
-# (tools/bench_prec_forms.py; PERF.md section 6, PR 38): beside the
-# weights 34.7 ms against 44.3 at two rows at 1 group (8,192 x 40, D =
-# 2^25), 132.5 against 151.3 at 3 (16,384 slabs of 64, D = 2^23); beside
-# the weights the precision rides in their descriptors, at two rows it
-# costs four gathers an entry. At 30 groups (32,768 slabs, D = 2^20) only
-# the two-row form fits the chip. The crossover lies between 3 and 30
-# groups, about 7 by the cost of a descriptor measured at both ends; no
-# cell runs 4 to 29.
+# every row addressed. Past the bound the scores come out of an
+# entry-major copy of the weights, its label rows padded to 128 lanes
+# (_gather_scores): D x 128 floats at the least, which at 1 group and D =
+# 2^25 (the Criteo cells) does not fit the chip beside the four tables,
+# where at 30 groups (32,768 slabs, D = 2^20) the two-row form is the only
+# one that fits. Between those ends both fit; up to the bound the step
+# keeps the full form's smaller temporaries (times on v5e from
+# tools/bench_prec_forms.py: PERF.md sections 6 and 7).
 _PREC_FULL_UPTO = 4
 
 
@@ -319,43 +320,138 @@ def _gather_group(pairs, idx, cols=None):
                 for i in range(len(pairs))]
 
 
+_LANES = 128
+
+# Entries the entry-major scores gather at a time: their rows, 2^19 x 256
+# lanes x 4 B = 0.54e9 B at 240 label rows, live beside the copy (a flush
+# of 32,768 slabs of 64 is four blocks).
+_SCORE_BLOCK = 1 << 19
+
+# Elements of the block _entry_major_tpu writes a grid step: with the two
+# blocks it reads, twice over for the pipeline's double buffers, and the
+# sum and its transpose in the kernel, 8 MiB of the v5e's 16 MiB of
+# scoped VMEM whatever the label rows (at 256 rows, blocks of 1,024
+# columns; at 2,048 rows, 128).
+_PACK_BLOCK = 1 << 18
+
+
+def _entry_major_tpu(m, d, wide):
+    """_entry_major on the TPU: one Pallas pass that reads a block of
+    columns of both tables, adds them, pads the label rows with zeros and
+    writes the block transposed. Left to XLA the transpose is a copy of
+    its own behind the sum (and the pad a third pass), and the [rows, D]
+    sum lies in memory beside the copy (docs/PERF_NOTES.md)."""
+    num_labels, dim = m.shape
+    r = min(wide, num_labels)
+    per = _PACK_BLOCK // wide                   # columns, to a power of 2
+    cols = math.gcd(dim, max(_LANES, 1 << per.bit_length() - 1))
+
+    def kernel(m_ref, d_ref, o_ref):
+        x = m_ref[...] + d_ref[...]                            # [r, cols]
+        if r < wide:
+            x = jnp.concatenate(
+                [x, jnp.zeros((wide - r, cols), x.dtype)], axis=0)
+        o_ref[...] = x.T
+
+    return pl.pallas_call(
+        kernel, grid=(dim // cols,),
+        in_specs=[pl.BlockSpec((r, cols), lambda j: (0, j))] * 2,
+        out_specs=pl.BlockSpec((cols, wide), lambda j: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((dim, wide), m.dtype),
+        name="entry_major")(m, d)
+
+
+def _entry_major(pair, rows):
+    """_gather_scores' packed copy: master + diff as [D, n x 128], column
+    c's label rows along the lanes of row c, n = ceil(rows / 128), in one
+    pass over the tables. The rows past ``rows`` are zeros, or the
+    tables' own rows where the tables have them (dead labels, whose
+    scores are cut). Laid out lanes-minor, row c is n runs of 128
+    contiguous floats, one fetch of 512 B each, where in the tables
+    column c's rows lie across the sublanes of rows / 8 tiles."""
+    m, d = pair
+    wide = -(-rows // _LANES) * _LANES
+    r = min(wide, m.shape[0])
+
+    def default(m, d):
+        return jnp.pad(m[:r] + d[:r], ((0, wide - r), (0, 0))).T
+    with jax.named_scope("pack"):
+        return jax.lax.platform_dependent(
+            m, d, tpu=functools.partial(_entry_major_tpu, wide=wide),
+            default=default)
+
+
+def weight_fetches(method: str, num_labels: int, dim: int, rows: int,
+                   n_idx: int) -> int:
+    """The fetches of weights the step's scores issue for each of its
+    n_idx entries where [num_labels, dim] tables are addressed over their
+    leading ``rows``: one a label group (label_groups) where the
+    precision rides beside the weights, or on the column plan; past
+    _PREC_FULL_UPTO groups on the packed plan, ceil(rows / 128), an
+    entry's row of the entry-major copy in lanes of 128 (_gather_scores).
+    Static shapes only."""
+    if prec_rows(method, num_labels, dim, rows) == 2 \
+            and _scores_plan(num_labels, dim, rows, n_idx) == "packed":
+        return -(-rows // _LANES)
+    return label_groups(num_labels, dim, rows)
+
+
+def _scores_plan(num_labels: int, dim: int, rows: int, n_idx: int) -> str:
+    """_gather_scores' plan for n_idx entries: gather_plan's rule for one
+    label group, as _gather_sums takes it."""
+    g = _label_group(num_labels, dim)
+    groups = rows // g
+    return gather_plan(g, groups * dim, groups * n_idx)
+
+
 def _gather_scores(pair, idx, val, rows):
     """(master + diff)[:rows, idx] . val as [B, rows] for a (master, diff)
-    pair of [L, D] tables, on _gather_sums' plans: the scores train_rows
-    takes where it reads the precision at two rows. One label group at a
-    time, in a loop, each group's entries gathered and summed into its
-    [B, 8] scores before the next: what the step holds beside the packed
-    copy is one group's entries, not every group's (the gathered [G x E,
-    8] and their [rows, B, K] copy, 8e9 B at 240 rows and 2^22 entries).
-    The descriptors are _gather_sums', one a group and entry. The sum
-    over a row's entries runs in another order than the einsum over
-    _gather_sums': the same scores to float32 rounding, not to the bit."""
+    pair of [L, D] tables: the scores train_rows takes where it reads the
+    precision at two rows. The sum over a row's entries runs in another
+    order than the einsum over _gather_sums': the same scores to float32
+    rounding, not to the bit.
+
+    packed: each entry's weights at every row, ceil(rows / 128) fetches
+      of 512 B out of _entry_major's copy (2 at 240 rows, where a label
+      group at a time took 30 of 32 B), multiplied by its value and
+      summed over its row's K entries. _SCORE_BLOCK entries at a time, in
+      a loop over blocks of whole rows: what the step holds beside the
+      copy is one block's gathered rows.
+    columns (_scores_plan): one label group at a time, its entries
+      gathered out of each table where they lie and summed into its
+      [B, 8] scores before the next group; nothing table-sized is made.
+    """
     m, d = pair
     num_labels, dim = m.shape
     g = _label_group(num_labels, dim)
     groups = rows // g
-    vm, vd = _by_group(m), _by_group(d)
-    cols = groups * dim
-    b = idx.shape[0]
-    if gather_plan(g, cols, groups * idx.size) == "columns":
-        def one(k):
-            at = (idx + k * dim).reshape(-1)
+    b, k = idx.shape
+    if _scores_plan(num_labels, dim, rows, idx.size) == "columns":
+        vm, vd = _by_group(m), _by_group(d)
+
+        def one(grp):
+            at = (idx + grp * dim).reshape(-1)
             with jax.named_scope("gather"):
                 got = jnp.take(vm, at, axis=1) + jnp.take(vd, at, axis=1)
             with jax.named_scope("margin"):                    # [g, B]
                 return jnp.sum(got.reshape((g,) + idx.shape) * val, axis=-1)
         s = jax.lax.map(one, jnp.arange(groups, dtype=idx.dtype))
         return s.transpose(2, 0, 1).reshape(b, rows)           # [G, g, B]
-    packed = _pack([(vm, vd)], cols)
+    table = _entry_major(pair, rows)                     # [D, n x 128]
+    blocks = -(-idx.size // _SCORE_BLOCK)
+    while b % blocks:
+        blocks += 1
 
-    def one(k):
+    def block(iv):
+        i, v = iv                                      # [B / blocks, K]
         with jax.named_scope("gather"):
-            got = jnp.take(packed, (idx + k * dim).reshape(-1), axis=0)
-        with jax.named_scope("margin"):                        # [B, g]
-            return jnp.sum(got.reshape(idx.shape + (g,))
-                           * val[:, :, None], axis=1)
-    s = jax.lax.map(one, jnp.arange(groups, dtype=idx.dtype))  # [G, B, g]
-    return s.transpose(1, 0, 2).reshape(b, rows)
+            got = jnp.take(table, i.reshape(-1), axis=0)
+        with jax.named_scope("margin"):                # [B / blocks, n x 128]
+            return jnp.sum(got.reshape(i.shape + (-1,)) * v[..., None],
+                           axis=1)
+    s = jax.lax.map(block, (idx.reshape(blocks, -1, k),
+                            val.reshape(blocks, -1, k)))
+    return s.reshape(b, -1)[:, :rows]
 
 
 def _tiled(table, rows, idx):

@@ -4,8 +4,9 @@ precision read in either form, timed back to back on the chip.
 
 ``full``: gathered beside the weights at every label row addressed, 16
 lanes a descriptor (the form up to _PREC_FULL_UPTO label groups);
-``two``: the weights' 8 lanes a group at a time, and the precision read at
-the correct label's and the rival's rows after the scores. Each form is
+``two``: the scores out of an entry-major copy of the weights, an entry's
+row in runs of 128 lanes (_gather_scores), and the precision read at the
+correct label's and the rival's rows after the scores. Each form is
 forced around its calls by moving the rule's bound; the program has no
 option for it. The shapes, random entries on random columns:
 
